@@ -25,10 +25,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from rocket_tpu.utils.platform import honor_cpu_request  # noqa: E402
-
-honor_cpu_request()
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402

@@ -16,10 +16,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from rocket_tpu.utils.platform import honor_cpu_request  # noqa: E402
-
-honor_cpu_request()
-
 import rocket_tpu as rt
 from rocket_tpu.data.toys import synthetic_lm_tokens
 from rocket_tpu.models.lora import is_lora

@@ -19,10 +19,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from rocket_tpu.utils.platform import honor_cpu_request  # noqa: E402
-
-honor_cpu_request()
-
 import numpy as np
 import optax
 

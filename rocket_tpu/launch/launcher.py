@@ -159,17 +159,13 @@ class Launcher(Dispatcher):
         # Warm-start tier (ISSUE 15): arm the per-host persistent
         # compile cache before anything traces — a relaunch then pays
         # disk retrieval instead of XLA compilation for every executable
-        # a previous run built.  Unconditional (disable via
-        # $ROCKET_TPU_COMPILE_CACHE=off) and never fatal.
-        try:
-            from rocket_tpu.tune import compile_cache
+        # a previous run built.  The directory is
+        # $JAX_COMPILATION_CACHE_DIR or the in-checkout default; one that
+        # cannot be created fails the launch.
+        from rocket_tpu.tune import compile_cache
 
-            armed = compile_cache.enable_compile_cache()
-            if armed is not None:
-                self._logger.info("persistent compile cache: %s", armed)
-        except Exception:
-            self._logger.warning(
-                "persistent compile cache unavailable", exc_info=True)
+        self._logger.info("persistent compile cache: %s",
+                          compile_cache.enable_compile_cache())
         if getattr(runtime, "tracing", False):
             self._arm_flight_recorder(runtime)
         if self._goodput:

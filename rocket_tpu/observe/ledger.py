@@ -577,7 +577,8 @@ def emit_gauges(step_seconds: float,
     """Emit live MFU/MBU counters for one step given its wall seconds,
     dividing the installed :func:`set_step_cost` FLOPs/bytes by
     ``tune/cost_model``'s device peaks.  Returns the gauges emitted
-    (empty when no cost hint is installed or the step took no time)."""
+    (empty when no cost hint is installed, the step took no time, or the
+    device kind has no published peak)."""
     if step_seconds <= 0.0:
         return {}
     flops = _STEP_COST["flops"]
@@ -600,7 +601,9 @@ def emit_gauges(step_seconds: float,
             out["device/mbu"] = (
                 nbytes / step_seconds / device_peak_hbm_bytes(kind)
             )
-    except Exception:
+    except ValueError:
+        # No published peak for this device kind (a CPU run): no gauge at
+        # all rather than a utilization over some other chip's peak.
         return {}
     t = tracer if tracer is not None else get_tracer()
     for name, value in out.items():

@@ -21,9 +21,13 @@ the logsumexp (``[B, S, 128]``; the kernel reads lane 0) and the k-side ids
 in a sublane layout (``[B, 8, S]``; the kernel reads sublane 0), so both
 respect TPU tiling without reshapes inside the kernel.
 
-Falls back transparently (see :func:`flash_attention`) when shapes don't
-meet the tiling constraints or a CPU backend is active (interpret mode is
-used on CPU so the same tests cover the kernel logic everywhere).
+Shapes that do not meet the tiling constraints reroute to dot attention
+and are COUNTED (``attention/flash/fallback``, see :func:`flash_attention`);
+off-TPU the same kernels run in interpret mode, so the CPU tests cover the
+kernel logic.  Under a multi-device mesh the kernel call is wrapped in
+``shard_map`` over the batch and heads axes: GSPMD cannot partition a
+Mosaic custom call, and JAX refuses to lower one it would have to
+("Mosaic kernels cannot be automatically partitioned").
 """
 
 from __future__ import annotations
@@ -35,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -49,9 +54,10 @@ def auto_blocks(S: int) -> tuple:
     bench tune dict (VERDICT r4 next #5).  Picks the largest measured
     block sizes that tile ``S`` exactly; when none divide, falls back to
     ``min(256, S)`` / ``min(512, S)`` — the pre-round-5 config defaults,
-    so flash-eligible irregular shapes (ViT-B/16's S=197 runs the kernel
-    as one S-sized block) keep their measured execution path instead of
-    silently rerouting to dot attention."""
+    so flash-eligible irregular shapes keep the kernel instead of
+    rerouting to dot attention: ViT-B/16's S=197 runs it as one 197-row
+    block, which Mosaic compiles although 197 is not a multiple of 8 and
+    which matches ``dot_attention`` on a v5e (``chip_smoke.py``)."""
     bq = next((b for b in (512, 256) if S % b == 0), min(256, S))
     bk = next((b for b in (1024, 512, 256) if S % b == 0), min(512, S))
     return bq, bk
@@ -59,6 +65,17 @@ def auto_blocks(S: int) -> tuple:
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _note_fallback(reason: str, S: int, D: int, block_q: int,
+                   block_k: int) -> None:
+    """Count a reroute to dot attention the way ``ops.quant`` counts its
+    own.  Runs at TRACE time (the branch is static on shapes), so the
+    counter counts compiled programs that lack the kernel."""
+    from rocket_tpu.observe.trace import counter
+
+    counter("attention/flash/fallback", 1, reason=reason, S=S, D=D,
+            block_q=block_q, block_k=block_k)
 
 
 def _block_mask(causal: bool, has_seg: bool, qi, ki, sq_ref, sk_ref,
@@ -492,9 +509,10 @@ def flash_attention(
     kernel's work becomes O(S·window) instead of O(S²/2).
     ``block_q``/``block_k`` default to the shape-aware measured-best
     tiling (:func:`auto_blocks`); pass explicit sizes to override.
-    Falls back to :func:`rocket_tpu.ops.attention.dot_attention` when the
+    Reroutes to :func:`rocket_tpu.ops.attention.dot_attention` when the
     kernel's tiling constraints don't hold (S not a multiple of the block
-    sizes, tiny head_dim).
+    sizes, head_dim not a multiple of 8) and counts it under
+    ``attention/flash/fallback`` with the reason.
     """
     from rocket_tpu.ops.attention import _repeat_kv, dot_attention
 
@@ -508,6 +526,11 @@ def flash_attention(
     block_q = min(block_q if block_q is not None else auto_q, S)
     block_k = min(block_k if block_k is not None else auto_k, S)
     if S % block_q != 0 or S % block_k != 0 or D % 8 != 0:
+        _note_fallback(
+            f"D % 8 == {D % 8}" if D % 8 != 0
+            else f"S % blocks != 0 (S={S}, blocks {block_q}/{block_k})",
+            S, D, block_q, block_k,
+        )
         return dot_attention(
             q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
             window=window,
@@ -519,7 +542,50 @@ def flash_attention(
     k = k.astype(q.dtype)
     v = v.astype(q.dtype)
     seg = None if segment_ids is None else segment_ids.astype(jnp.float32)
-    # [B, S, H, D] -> [B, H, S, D] for the kernel
-    qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
-    o = _flash(qt, kt, vt, seg, causal, scale, block_q, block_k, window)
-    return o.swapaxes(1, 2)
+
+    def kernel(q, k, v, *seg):
+        # [B, S, H, D] -> [B, H, S, D] for the kernel
+        qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
+        o = _flash(qt, kt, vt, seg[0] if seg else None, causal, scale,
+                   block_q, block_k, window)
+        return o.swapaxes(1, 2)
+
+    operands = (q, k, v) if seg is None else (q, k, v, seg)
+    return _over_mesh(kernel, seg is not None)(*operands)
+
+
+def _over_mesh(kernel, has_seg: bool):
+    """``kernel`` run per shard of the active mesh's batch and heads axes
+    (the layout ``models.transformer`` constrains q/k/v to), or as-is when
+    no mesh is active, the mesh is one device, or an enclosing
+    ``shard_map`` already holds every axis.  Mosaic wants EVERY mesh axis
+    manual, so the map takes all that are still automatic; the sequence
+    stays whole per shard (sequence parallelism is ``ops.ring``)."""
+    from rocket_tpu.parallel.collectives import shard_map
+    from rocket_tpu.parallel.context import (
+        _manual_axes,
+        current_mesh,
+        current_rules,
+    )
+
+    mesh = current_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return kernel
+    auto = frozenset(mesh.axis_names) - _manual_axes()
+    if not auto:
+        return kernel
+
+    def free(entry):
+        axes = (entry,) if isinstance(entry, str) else tuple(entry or ())
+        return tuple(a for a in axes if a in auto) or None
+
+    batch, heads = (free(e) for e in current_rules().spec("batch", "heads"))
+    spec = P(batch, None, heads, None)
+    return shard_map(
+        kernel,
+        mesh=mesh,
+        in_specs=(spec,) * 3 + ((P(batch, None),) if has_seg else ()),
+        out_specs=spec,
+        axis_names=auto,
+        check_vma=False,
+    )

@@ -103,9 +103,7 @@ def _matvec_kernel(x_ref, q_ref, s_ref, o_ref, *, nk_layout: bool):
         x_ref[...], w, (contract, ((), ())),
         preferred_element_type=jnp.float32,
     )
-    o_ref[...] = (acc * s_ref[...].astype(jnp.float32)[None, :]).astype(
-        o_ref.dtype
-    )
+    o_ref[...] = (acc * s_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -124,8 +122,11 @@ def _int8_matmul_kernel_call(x, q, scale, nk_layout: bool, block_n: int):
     Mp = max(16, M + (-M) % 16)  # bf16 sublane tile
     x = _pad_to(x, Mp, 0)
     q = _pad_to(q, block_n, 0 if nk_layout else 1)
-    scale = _pad_to(scale, block_n, 0)
-    Np = scale.shape[0]
+    # scale rides as a [1, N] row: Mosaic tiles a rank-1 f32 operand by
+    # its block (512) while XLA lays it out in 1024-element tiles, and the
+    # TPU compiler rejects the mismatch for every N.
+    scale = _pad_to(scale, block_n, 0)[None, :]
+    Np = scale.shape[1]
     grid = (Np // block_n,)
     if nk_layout:  # q is [N, K]
         q_spec = pl.BlockSpec((block_n, K), lambda n: (n, 0))
@@ -137,7 +138,7 @@ def _int8_matmul_kernel_call(x, q, scale, nk_layout: bool, block_n: int):
         in_specs=[
             pl.BlockSpec((Mp, K), lambda n: (0, 0)),
             q_spec,
-            pl.BlockSpec((block_n,), lambda n: (n,)),
+            pl.BlockSpec((1, block_n), lambda n: (0, n)),
         ],
         out_specs=pl.BlockSpec((Mp, block_n), lambda n: (0, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),

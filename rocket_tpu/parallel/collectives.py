@@ -39,26 +39,7 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec
 
-def _resolve_shard_map():
-    """``jax.shard_map`` moved: new jax exports it at the top level (with
-    a ``check_vma`` kwarg); 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` (spelled ``check_rep``).
-    Resolve whichever exists and normalize the kwarg so every call site
-    in the repo can use the one modern spelling."""
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        return native
-    from jax.experimental.shard_map import shard_map as legacy
-
-    @functools.wraps(legacy)
-    def compat(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        return legacy(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma, **kw)
-
-    return compat
-
-
-shard_map = _resolve_shard_map()
+shard_map = jax.shard_map
 
 AxisName = Union[str, Tuple[str, ...]]
 

@@ -46,23 +46,12 @@ def current_rules() -> ShardingRules:
 
 
 def _manual_axes() -> frozenset:
-    """Mesh axes currently under manual (shard_map) control at trace time.
-
-    New jax tracks this on the abstract mesh
-    (``jax.sharding.get_abstract_mesh``); 0.4.x has no abstract mesh, but
-    the trace-time axis env holds exactly the names the enclosing
-    shard_map bound — read those instead."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        am = get_am()
-        if am is None or am.empty:
-            return frozenset()
-        return frozenset(am.manual_axes)
-    try:
-        from jax._src.core import get_axis_env
-        return frozenset(get_axis_env().axis_sizes)
-    except Exception:
+    """Mesh axes currently under manual (shard_map) control at trace time,
+    read off the abstract mesh."""
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or am.empty:
         return frozenset()
+    return frozenset(am.manual_axes)
 
 
 def constrain(x: Any, *logical_axes: Optional[str]) -> Any:

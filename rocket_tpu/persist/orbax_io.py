@@ -19,21 +19,12 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, List, Optional
 
-import inspect
-
 import jax
 import numpy as np
 import orbax.checkpoint as ocp
 
 from rocket_tpu.persist import integrity
 from rocket_tpu.utils.retry import retry_call
-
-# ``partial_restore`` landed in newer Orbax; 0.7.x spells the same thing
-# as ``transforms={}`` (item keys absent from the target are dropped,
-# present ones restore from the saved original).
-_HAS_PARTIAL_RESTORE = "partial_restore" in inspect.signature(
-    ocp.args.PyTreeRestore.__init__
-).parameters
 
 
 def _to_saveable(tree: Any) -> Any:
@@ -193,18 +184,11 @@ class CheckpointIO:
                     )
 
                 restore_args = jax.tree_util.tree_map(_rarg, target)
-                if _HAS_PARTIAL_RESTORE:
-                    composite_args[key] = ocp.args.PyTreeRestore(
-                        item=target,
-                        restore_args=restore_args,
-                        partial_restore=True,
-                    )
-                else:
-                    composite_args[key] = ocp.args.PyTreeRestore(
-                        item=target,
-                        restore_args=restore_args,
-                        transforms={},
-                    )
+                composite_args[key] = ocp.args.PyTreeRestore(
+                    item=target,
+                    restore_args=restore_args,
+                    partial_restore=True,
+                )
             else:
                 composite_args[key] = ocp.args.StandardRestore(target)
         # Restores use a transient (sync) checkpointer: the shared async one

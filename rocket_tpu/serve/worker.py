@@ -272,14 +272,10 @@ def main(argv: Optional[list] = None) -> int:
         from rocket_tpu.observe.ledger import arm_ledgers, get_goodput
         from rocket_tpu.tune import compile_cache
 
-        cache_armed = None
-        try:
-            cache_armed = compile_cache.enable_compile_cache()
-        except Exception:
-            pass  # cold compiles still work; the tier is an accelerant
         arm_ledgers()
         t_build = time.perf_counter()
         try:
+            cache_armed = compile_cache.enable_compile_cache()
             loop = spec.build()
             if args.replica_id is not None:
                 loop.replica_id = args.replica_id

@@ -6,16 +6,18 @@ on the CPU proxy and by far the dominant one on real chips.  This module
 makes that cost a one-time event per (executable, topology):
 
 - :func:`cache_dir` resolves the per-host cache directory —
-  ``$ROCKET_TPU_COMPILE_CACHE`` if set (the values ``0``/``off``/``none``
-  disable the tier entirely), else the repo's
-  ``experiments/compile_cache/`` (mirroring ``tune.store.tune_dir``).
+  ``$JAX_COMPILATION_CACHE_DIR`` if set (JAX's own variable: whoever
+  launches the process places the cache), else the checkout's
+  ``experiments/compile_cache/``.  Never a temp name: the path is part
+  of the cache key, so a directory that moves never hits.
 - :func:`enable_compile_cache` arms JAX's persistent compilation cache
-  (``jax_compilation_cache_dir`` plus the min-entry-size /
-  min-compile-time knobs opened all the way, so even the tiny CPU-proxy
-  executables persist), installs the jax monitoring listeners that count
-  cache hits/misses and the trace-vs-compile time split, and registers a
-  ``compile_cache/*`` export source.  Idempotent; safe to call from the
-  Launcher, the serve worker, and tests in any order.
+  there (the min-entry-size / min-compile-time knobs opened all the
+  way, so even the tiny CPU-proxy executables persist), installs the
+  jax monitoring listeners that count cache hits/misses and the
+  trace-vs-compile time split, and registers a ``compile_cache/*``
+  export source.  Idempotent; safe to call from the Launcher, the serve
+  worker, and tests in any order.  A directory that cannot be created
+  or armed raises.
 - :func:`hit_count` is the cheap counter the
   :class:`~rocket_tpu.observe.ledger.RetraceLedger` samples around each
   dispatch to stamp ``CompileRecord.cache_hit`` — a compile that was
@@ -42,10 +44,9 @@ import jax
 
 logger = logging.getLogger("rocket_tpu.compile_cache")
 
-_ENV_DIR = "ROCKET_TPU_COMPILE_CACHE"
-_DISABLED = {"0", "off", "none", "disabled"}
+_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 
-# jax monitoring event names (stable across the 0.4.x line we pin).
+# jax monitoring event names (jax 0.9).
 _EV_HITS = "/jax/compilation_cache/cache_hits"
 _EV_REQUESTS = "/jax/compilation_cache/compile_requests_use_cache"
 _DUR_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -69,14 +70,11 @@ _state: Dict[str, Any] = {
 }
 
 
-def cache_dir() -> Optional[str]:
-    """The persistent cache directory: ``$ROCKET_TPU_COMPILE_CACHE`` if
-    set (``0``/``off`` → ``None``, tier disabled), else the repo's
-    ``experiments/compile_cache/``."""
+def cache_dir() -> str:
+    """The persistent cache directory: ``$JAX_COMPILATION_CACHE_DIR`` if
+    set, else the checkout's ``experiments/compile_cache/``."""
     env = os.environ.get(_ENV_DIR)
-    if env is not None:
-        if env.strip().lower() in _DISABLED:
-            return None
+    if env:
         return env
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -108,25 +106,18 @@ def _install_listeners() -> None:
     # would double-count.
     if _state["listeners"]:
         return
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        _state["listeners"] = True
-    except Exception:  # pragma: no cover - future jax moved the module
-        logger.warning("compile-cache monitoring unavailable", exc_info=True)
+    from jax._src import monitoring
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    _state["listeners"] = True
 
 
-def enable_compile_cache(directory: Optional[str] = None,
-                         *, register_export: bool = True) -> Optional[str]:
-    """Arm JAX's persistent compilation cache at ``directory`` (default
-    :func:`cache_dir`).  Returns the armed directory, or ``None`` when
-    the tier is disabled via env.  Idempotent — re-arming the same dir
-    is a no-op; a different dir re-points the cache."""
-    if directory is None:
-        directory = cache_dir()
-    if directory is None:
-        return None
+def enable_compile_cache() -> str:
+    """Arm JAX's persistent compilation cache at :func:`cache_dir` and
+    return that directory.  Idempotent — re-arming the same dir is a
+    no-op; a changed ``$JAX_COMPILATION_CACHE_DIR`` re-points the cache.
+    Raises when the directory cannot be created."""
+    directory = cache_dir()
     with _lock:
         _install_listeners()
         if _state["enabled_dir"] == directory:
@@ -137,35 +128,18 @@ def enable_compile_cache(directory: Optional[str] = None,
         # jax pins its cache backend at first use; a config update alone
         # leaves reads/writes on the OLD dir.  Drop the singleton so the
         # new dir actually takes effect.
-        try:
-            from jax._src import compilation_cache as _jcc
-            _jcc.reset_cache()
-        except Exception:
-            logger.debug("compilation_cache.reset_cache unavailable",
-                         exc_info=True)
-    # Each knob guarded on its own: the dir is the load-bearing one, the
-    # thresholds are best-effort tuning (names have moved across jax
-    # releases).
-    try:
-        jax.config.update("jax_compilation_cache_dir", directory)
-    except Exception:
-        logger.warning("jax_compilation_cache_dir unsupported; warm-start "
-                       "tier disabled", exc_info=True)
-        return None
-    for knob, value in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                        ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(knob, value)
-        except Exception:
-            logger.debug("compile-cache knob %s unsupported", knob)
+        from jax._src import compilation_cache as _jcc
+        _jcc.reset_cache()
+    # JAX read $JAX_COMPILATION_CACHE_DIR into this option at import; the
+    # update only matters for the in-checkout default (and for a variable
+    # set after import), and never names another directory.
+    jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     with _lock:
         _state["enabled_dir"] = directory
-    if register_export:
-        try:
-            from rocket_tpu.observe import export
-            export.register_source("compile_cache", snapshot)
-        except Exception:  # pragma: no cover - export must never gate this
-            pass
+    from rocket_tpu.observe import export
+    export.register_source("compile_cache", snapshot)
     logger.info("persistent compile cache armed at %s", directory)
     return directory
 

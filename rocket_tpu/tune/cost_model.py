@@ -19,8 +19,11 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-# bf16 peak FLOP/s per chip; more specific kinds ('v5 lite', 'v5p') must
-# precede bare 'v5' — dicts preserve insertion order.
+# Published per-chip peaks (Google Cloud TPU documentation, the page of
+# each generation: bf16 FLOP/s and HBM bytes/s).  More specific kinds
+# ('v5 lite', 'v5p') must precede bare 'v5' — dicts preserve insertion
+# order.  A kind that matches no key is an error, never a default: a CPU
+# run gets no MFU/MBU at all rather than one over some chip's peaks.
 PEAK_FLOPS_BY_KIND: Dict[str, float] = {
     "v5 lite": 197e12, "v5e": 197e12,
     "v4": 275e12,
@@ -44,28 +47,31 @@ PEAK_HBM_BY_KIND: Dict[str, float] = {
 def _local_device_kind() -> str:
     import jax
 
-    return jax.devices()[0].device_kind.lower()
+    return jax.devices()[0].device_kind
 
 
-def _peak(table: Dict[str, float], default: float,
-          device_kind: Optional[str] = None) -> float:
-    kind = (device_kind or _local_device_kind()).lower()
+def _peak(table: Dict[str, float], device_kind: Optional[str]) -> float:
+    kind = device_kind if device_kind is not None else _local_device_kind()
     for key, val in table.items():
-        if key in kind:
+        if key in kind.lower():
             return val
-    return default
+    raise ValueError(
+        f"no published peak for device kind {kind!r}; known kinds: "
+        f"{sorted(table)} — pass the device_kind of the chip being "
+        f"modelled, or add its peaks with their source"
+    )
 
 
 def device_peak_flops(device_kind: Optional[str] = None) -> float:
-    """bf16 peak for ``device_kind`` (default: the local accelerator;
-    fallback v5e)."""
-    return _peak(PEAK_FLOPS_BY_KIND, 197e12, device_kind)
+    """bf16 peak for ``device_kind`` (default: the local accelerator).
+    Raises ``ValueError`` naming a kind the table does not hold."""
+    return _peak(PEAK_FLOPS_BY_KIND, device_kind)
 
 
 def device_peak_hbm_bytes(device_kind: Optional[str] = None) -> float:
-    """HBM bandwidth peak for ``device_kind`` (default: local; fallback
-    v5e)."""
-    return _peak(PEAK_HBM_BY_KIND, 819e9, device_kind)
+    """HBM bandwidth peak for ``device_kind`` (default: local).  Raises
+    ``ValueError`` naming a kind the table does not hold."""
+    return _peak(PEAK_HBM_BY_KIND, device_kind)
 
 
 def gpt2_step_flops(cfg: Any, batch: int, seq: int) -> float:

@@ -16,9 +16,13 @@ Shape of a run (:func:`successive_halving`):
 Every probe is a FRESH subprocess running ``bench.bench_gpt2`` with the
 fully-merged point (explicit ``tune=`` — immune to env overrides and to
 previously-saved records), under a timeout: a miscompile, OOM, or hang
-costs one rung slot, never the run.  :func:`autotune` adds the zero
-re-search contract: an existing matching record short-circuits the whole
-search (``probes == 0``) unless ``force=True``.
+costs one rung slot, never the run.  A chip belongs to one process at a
+time, so the searching parent never initialises a JAX backend: the
+device kind and backend it needs come from one short identity child
+(:func:`device_identity`) that has exited before the first probe starts.
+:func:`autotune` adds the zero re-search contract: an existing matching
+record short-circuits the whole search (``probes == 0``) unless
+``force=True``.
 """
 
 from __future__ import annotations
@@ -53,9 +57,7 @@ def bench_probe(tune: Dict[str, Any], steps: int, warmup: int,
     point regardless of ambient state.
     """
     child = (
-        "import os, sys, json, jax\n"
-        "if os.environ.get('JAX_PLATFORMS') == 'cpu':\n"
-        "    jax.config.update('jax_platforms', 'cpu')\n"
+        "import sys, json\n"
         f"sys.path.insert(0, {_repo_root()!r})\n"
         "import bench\n"
         f"rec = bench.bench_gpt2({int(steps)}, {int(warmup)}, "
@@ -89,11 +91,26 @@ def bench_probe(tune: Dict[str, Any], steps: int, warmup: int,
             "error": tail[-1] if tail else f"exit {proc.returncode}"}
 
 
-def _device_identity() -> Dict[str, str]:
-    import jax
-
-    return {"device": jax.devices()[0].device_kind,
-            "backend": jax.default_backend()}
+def device_identity() -> Dict[str, str]:
+    """``{"device": device_kind, "backend": ...}`` of this machine, asked
+    of a short child process so the caller holds no chip while its probe
+    children need it."""
+    child = (
+        "import json, jax\n"
+        "print(json.dumps({'device': jax.devices()[0].device_kind,\n"
+        "                  'backend': jax.default_backend()}))\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", child],
+        timeout=300.0, capture_output=True, text=True,
+    )
+    if proc.returncode != 0:
+        tail = (proc.stderr or "").strip().splitlines()
+        raise RuntimeError(
+            f"device identity child failed (exit {proc.returncode}): "
+            f"{tail[-1] if tail else 'no output'}"
+        )
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def successive_halving(
@@ -109,14 +126,19 @@ def successive_halving(
     probe_timeout_s: float = 600.0,
     save: bool = True,
     log: Callable[[str], None] = print,
+    identity: Optional[Dict[str, str]] = None,
+    device_kind: Optional[str] = None,
 ) -> Dict[str, Any]:
     """Run the search; returns (and by default persists) the tune record.
 
     ``base`` pins tune keys across every candidate (e.g. a fixed batch,
     or the tiny CPU-proxy model dims).  ``rung_steps`` are the timed
     steps per rung — each rung keeps ``ceil(n / eta)`` survivors by
-    measured ``value``; suspect records (``mfu > 1`` miscompiles flagged
-    by ``run_config``) and failed probes are dropped before ranking.
+    measured ``value``; failed probes are dropped before ranking.
+    ``identity`` is the machine the record is stamped with (default: ask
+    :func:`device_identity`); ``device_kind`` is the chip whose published
+    peaks seed the ranking (default: the identity's) — a CPU-proxy search
+    has no peaks of its own and names the chip it models.
     """
     from rocket_tpu.observe.trace import get_tracer
 
@@ -124,6 +146,8 @@ def successive_halving(
     base = dict(base or {})
     probe = probe if probe is not None else bench_probe
     tracer = get_tracer()
+    identity = identity if identity is not None else device_identity()
+    device_kind = device_kind or identity["device"]
 
     # -- cost-model seeding -------------------------------------------
     seen: set = set()
@@ -134,7 +158,7 @@ def successive_halving(
         if key in seen:  # distinct fragments, same executable
             continue
         seen.add(key)
-        pred = predict_point(merged)
+        pred = predict_point(merged, device_kind)
         scored.append((pred["seconds"], merged, pred))
     scored.sort(key=lambda item: item[0])
     survivors = [
@@ -155,12 +179,12 @@ def successive_halving(
                             probe_timeout_s)
             probes += 1
             cand = dict(cand, measured=rec)
-            if rec.get("value") and "suspect" not in rec:
+            if rec.get("value"):
                 measured.append(cand)
             else:
                 tracer.counter("tune/probe/dead", 1, rung=rung)
                 log(f"tune: rung {rung} dropped point "
-                    f"({rec.get('error') or rec.get('suspect')})")
+                    f"({rec.get('error')})")
         if not measured:
             raise RuntimeError(
                 f"tune search: every probe in rung {rung} failed — "
@@ -185,7 +209,7 @@ def successive_halving(
     winner = survivors[0]
     record = {
         "model": model,
-        **_device_identity(),
+        **identity,
         "batch": winner["tune"].get("batch"),
         "tune": winner["tune"],
         "value": winner["measured"]["value"],
@@ -206,6 +230,7 @@ def autotune(
     *,
     base: Optional[Dict[str, Any]] = None,
     force: bool = False,
+    identity: Optional[Dict[str, str]] = None,
     **search_kw: Any,
 ) -> Dict[str, Any]:
     """Search only when no matching record exists.
@@ -215,10 +240,11 @@ def autotune(
     the zero re-search contract the smoke test pins.  ``force=True``
     always searches.
     """
+    identity = identity if identity is not None else device_identity()
     if not force:
-        ident = _device_identity()
-        hit = best_tune(model=model, device=ident["device"],
-                        backend=ident["backend"])
+        hit = best_tune(model=model, device=identity["device"],
+                        backend=identity["backend"])
         if hit is not None:
             return dict(hit, probes=0, reused=True)
-    return successive_halving(space, model=model, base=base, **search_kw)
+    return successive_halving(space, model=model, base=base,
+                              identity=identity, **search_kw)

@@ -274,7 +274,9 @@ class TestDeviceTelemetry:
         from rocket_tpu.observe.ledger import emit_gauges, set_step_cost
         from rocket_tpu.observe.trace import Tracer
 
-        set_step_cost(flops=1.0e12, bytes_accessed=2.0e9, device_kind=None)
+        # the CPU under test has no published peak: name the chip modelled
+        set_step_cost(flops=1.0e12, bytes_accessed=2.0e9,
+                      device_kind="TPU v5 lite")
         t = Tracer(capacity=64, enabled=True)
         gauges = emit_gauges(0.1, tracer=t)
         assert set(gauges) == {"device/mfu", "device/mbu"}

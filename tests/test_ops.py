@@ -170,12 +170,81 @@ def test_flash_segment_ids_gradients():
         )
 
 
-def test_flash_fallback_on_odd_shapes():
-    # S=100 not a block multiple -> transparently uses dot
-    q, k, v = _qkv(S=100)
-    out = flash_attention(q, k, v, causal=True)
+def _flash_fallbacks(tracer):
+    return [e[5] for e in tracer.events()
+            if e[1] == "attention/flash/fallback"]
+
+
+@pytest.mark.parametrize("kwargs, shape, reason", [
+    (dict(block_q=64, block_k=64), dict(S=100), "S % blocks"),
+    (dict(), dict(S=128, D=12), "D % 8 == 4"),
+])
+def test_flash_reroute_to_dot_is_counted(kwargs, shape, reason):
+    """A shape the kernel cannot tile still computes (dot attention), but
+    never quietly: ``attention/flash/fallback`` counts it with the reason,
+    the way ``quant/int8_matmul/fallback`` counts its own."""
+    from rocket_tpu.observe import trace
+
+    q, k, v = _qkv(**shape)
+    tracer = trace.arm(512)
+    try:
+        tracer.clear()
+        out = flash_attention(q, k, v, causal=True, **kwargs)
+        (event,) = _flash_fallbacks(tracer)
+    finally:
+        trace.disarm()
+    assert event["reason"].startswith(reason), event
+    assert event["S"] == q.shape[1] and event["D"] == q.shape[3]
     ref = dot_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+def test_flash_irregular_length_runs_the_kernel_uncounted():
+    # S=100 divides no measured block: auto_blocks offers one S-sized
+    # block, so the kernel itself runs (ViT-B/16's S=197 takes this path)
+    from rocket_tpu.observe import trace
+
+    q, k, v = _qkv(S=100)
+    tracer = trace.arm(512)
+    try:
+        tracer.clear()
+        out = flash_attention(q, k, v, causal=True)
+        assert _flash_fallbacks(tracer) == []
+    finally:
+        trace.disarm()
+    ref = dot_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
+                               rtol=2e-5)
+
+
+@pytest.mark.parametrize("spec", [MeshSpec(data=4, fsdp=2),
+                                  MeshSpec(data=4, tensor=2)])
+def test_flash_under_a_mesh_runs_per_shard(devices, spec):
+    """XLA cannot partition a Mosaic custom call: under a multi-device
+    mesh the kernel call is shard_mapped over the batch and heads axes,
+    values and gradients unchanged."""
+    mesh = spec.build(devices)
+    q, k, v = _qkv(B=8, S=128)
+    seg = _packed_segments(8, 128)
+
+    def loss(attention, q, k, v):
+        out = attention(q, k, v, causal=True, segment_ids=seg)
+        return jnp.sum(out ** 2), out
+
+    want_g, want = jax.grad(
+        functools.partial(loss, dot_attention), argnums=(0, 1, 2),
+        has_aux=True)(q, k, v)
+    with mesh_context(mesh):
+        sharded = jax.jit(jax.grad(
+            functools.partial(loss, flash_attention), argnums=(0, 1, 2),
+            has_aux=True))
+        assert "shard_map" in str(jax.make_jaxpr(sharded)(q, k, v))
+        got_g, got = sharded(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=5e-5, rtol=5e-4)
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -92,7 +92,7 @@ def test_library_emits_trace_events():
     # the lint is only meaningful if the scan actually sees the emitters
     names = {name for _p, _l, name in _all_sites()}
     assert {"serve/submit", "ledger/compile",
-            "quant/int8_matmul/fallback",
+            "quant/int8_matmul/fallback", "attention/flash/fallback",
             # multi-tenant serving: preemption lifecycle markers
             "serve/preempt", "serve/resume",
             # distributed request tracing: the stitched-timeline and

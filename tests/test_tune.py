@@ -44,11 +44,13 @@ def tune_dir(tmp_path, monkeypatch):
 # -- cost model ---------------------------------------------------------
 
 
-def test_peaks_positive(devices):
-    assert device_peak_flops() > 0
-    assert device_peak_hbm_bytes() > 0
-    # known silicon resolves to its table entry, not the default
-    assert device_peak_flops("TPU v4") != device_peak_flops("unknown-chip")
+V5E = "TPU v5 lite"  # the chip the CPU-proxy tests model
+
+
+def test_peaks_keyed_by_kind(devices):
+    assert device_peak_flops(V5E) == 197e12
+    assert device_peak_hbm_bytes(V5E) == 819e9
+    assert device_peak_flops("TPU v4") != device_peak_flops(V5E)
 
 
 def test_cost_model_orderings(devices):
@@ -56,12 +58,14 @@ def test_cost_model_orderings(devices):
     remat taxes FLOPs, fused_ce deletes the logits round-trip bytes,
     donate=False pays a params copy."""
     base = {"batch": 8, "seq": 1024}
-    p = predict_point(base)
+    p = predict_point(base, V5E)
     assert p["flops"] > 0 and p["bytes"] > 0 and p["seconds"] > 0
-    assert predict_point({**base, "remat": True})["flops"] > p["flops"]
-    assert predict_point({**base, "fused_ce": True})["bytes"] < p["bytes"]
-    assert predict_point({**base, "donate": False})["bytes"] > p["bytes"]
-    assert (predict_point({**base, "mu_dtype": "bf16"})["bytes"]
+    assert predict_point({**base, "remat": True}, V5E)["flops"] > p["flops"]
+    assert (predict_point({**base, "fused_ce": True}, V5E)["bytes"]
+            < p["bytes"])
+    assert (predict_point({**base, "donate": False}, V5E)["bytes"]
+            > p["bytes"])
+    assert (predict_point({**base, "mu_dtype": "bf16"}, V5E)["bytes"]
             < p["bytes"])
 
 
@@ -199,20 +203,9 @@ def test_resolve_gpt2_tune_precedence(devices, tune_dir, monkeypatch):
     assert merged["hidden"] == 768 and not survived
 
 
-def test_headline_match_is_canonical(devices, tune_dir, monkeypatch):
-    """A tune spelling out the library-default blocks still counts as
-    the headline config (canonical comparison, not literal)."""
-    import bench
-    from rocket_tpu.ops.flash import auto_blocks
-
-    monkeypatch.setenv("BENCH_NO_TUNE_STORE", "1")
-    bq, bk = auto_blocks(bench.GPT2_TUNE["seq"])
-    assert bench._tune_matches_headline({"block_q": bq, "block_k": bk})
-    assert not bench._tune_matches_headline({"batch": 999})
-    assert not bench._tune_matches_headline({"unknown_knob": 1})
-
-
 # -- successive halving (fake probe: deterministic, no subprocesses) ----
+
+CPU_IDENTITY = {"device": "cpu", "backend": "cpu"}
 
 
 def test_successive_halving_seeds_and_halves(devices, tune_dir):
@@ -228,6 +221,7 @@ def test_successive_halving_seeds_and_halves(devices, tune_dir):
     rec = successive_halving(
         space, base={"seq": 64}, seed_k=4, eta=2, rung_steps=(2, 5),
         probe=fake_probe, save=True, log=lambda s: None,
+        identity=CPU_IDENTITY, device_kind=V5E,
     )
     # rung 0 probes all 4 seeds at 2 steps, keeps ceil(4/2)=2;
     # rung 1 (last) probes 2 at 5 steps, keeps 1
@@ -253,6 +247,7 @@ def test_successive_halving_drops_dead_points(devices, tune_dir):
     rec = successive_halving(
         space, seed_k=3, eta=3, rung_steps=(2,), probe=fake_probe,
         save=False, log=lambda s: None,
+        identity=CPU_IDENTITY, device_kind=V5E,
     )
     assert rec["tune"]["batch"] == 2
 
@@ -264,6 +259,7 @@ def test_successive_halving_all_dead_raises(devices, tune_dir):
             space, seed_k=1, rung_steps=(2,),
             probe=lambda *a: {"value": None, "error": "x"},
             save=False, log=lambda s: None,
+            identity=CPU_IDENTITY, device_kind=V5E,
         )
 
 
@@ -279,6 +275,7 @@ def test_autotune_cpu_proxy_smoke(devices, tune_dir):
     rec = autotune(
         model="gpt2", space=space, seed_k=2, rung_steps=(2,),
         warmup=1, probe_timeout_s=240.0, log=lambda s: None,
+        device_kind=V5E,
     )
     assert rec["probes"] == 2
     assert rec["value"] and rec["value"] > 0

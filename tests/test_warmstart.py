@@ -36,24 +36,31 @@ import rocket_tpu.testing.workers as tw  # noqa: E402
 # -- cache dir resolution ---------------------------------------------------
 
 
-def test_cache_dir_env_override(monkeypatch, tmp_path):
-    monkeypatch.setenv("ROCKET_TPU_COMPILE_CACHE", str(tmp_path / "cc"))
-    assert compile_cache.cache_dir() == str(tmp_path / "cc")
+def test_cache_dir_is_jax_env_and_arming_keeps_it(monkeypatch, tmp_path):
+    import jax
+
+    placed = str(tmp_path / "cc")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    assert compile_cache.cache_dir() == placed
+    # armed from outside: the config names that directory and no other
+    assert compile_cache.enable_compile_cache() == placed
+    assert jax.config.jax_compilation_cache_dir == placed
+    assert compile_cache.enabled_dir() == placed
 
 
-@pytest.mark.parametrize("value", ["0", "off", "none", "disabled", " OFF "])
-def test_cache_dir_disable_values(monkeypatch, value):
-    monkeypatch.setenv("ROCKET_TPU_COMPILE_CACHE", value)
-    assert compile_cache.cache_dir() is None
-    # a disabled tier arms nothing and reports so
-    assert compile_cache.enable_compile_cache() is None
+def test_cache_dir_defaults_to_fixed_path_in_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.cache_dir() == os.path.join(
+        repo, "experiments", "compile_cache")
 
 
-def test_cache_dir_defaults_under_repo(monkeypatch):
-    monkeypatch.delenv("ROCKET_TPU_COMPILE_CACHE", raising=False)
-    d = compile_cache.cache_dir()
-    assert d is not None
-    assert d.endswith(os.path.join("experiments", "compile_cache"))
+def test_unarmable_cache_dir_raises(monkeypatch, tmp_path):
+    blocker = tmp_path / "file"
+    blocker.write_text("not a directory")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(blocker / "cc"))
+    with pytest.raises(OSError):
+        compile_cache.enable_compile_cache()
 
 
 def test_aot_key_is_deterministic_and_filesystem_safe():
@@ -97,12 +104,14 @@ def test_plan_for_batcher_dedupes_and_drops_nonpositive():
 
 @pytest.mark.goodput
 class TestCompileCache:
-    def test_enable_is_idempotent_and_registers_export(self, tmp_path):
+    def test_enable_is_idempotent_and_registers_export(self, tmp_path,
+                                                       monkeypatch):
         from rocket_tpu.observe import export
 
         d = str(tmp_path / "cc")
-        assert compile_cache.enable_compile_cache(d) == d
-        assert compile_cache.enable_compile_cache(d) == d
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        assert compile_cache.enable_compile_cache() == d
+        assert compile_cache.enable_compile_cache() == d
         assert compile_cache.enabled_dir() == d
         assert os.path.isdir(d)
         snap = export.collect()
@@ -110,7 +119,7 @@ class TestCompileCache:
         assert "compile_cache/bytes" in snap
 
     def test_compile_record_cache_hit_after_cache_retrieval(
-            self, tmp_path, devices):
+            self, tmp_path, devices, monkeypatch):
         """The per-edge visibility promise: a compile served from the
         persistent disk cache stamps ``CompileRecord.cache_hit=True``
         (``jax.clear_caches()`` drops the dispatch cache, so the second
@@ -125,7 +134,8 @@ class TestCompileCache:
             ledger_call,
         )
 
-        compile_cache.enable_compile_cache(str(tmp_path / "cc"))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+        compile_cache.enable_compile_cache()
         compile_cache.reset_stats()
         arm_ledgers()
         try:
@@ -151,11 +161,12 @@ class TestCompileCache:
             get_retrace_ledger().reset()
 
     def test_aot_save_load_roundtrip_and_fallthrough(self, tmp_path,
-                                                     devices):
+                                                     devices, monkeypatch):
         import jax
         import jax.numpy as jnp
 
-        compile_cache.enable_compile_cache(str(tmp_path / "cc"))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+        compile_cache.enable_compile_cache()
         compile_cache.reset_stats()
         fn = jax.jit(lambda x: x * 2.0 + 1.0)
         x = jnp.arange(8.0)
@@ -184,10 +195,11 @@ class TestCompileCache:
 @pytest.mark.warmstart
 class TestWarmBatcher:
     def test_warm_batcher_compiles_edges_then_aot_hits(self, tmp_path,
-                                                       devices):
+                                                       devices, monkeypatch):
         from rocket_tpu.models.generate import ContinuousBatcher
 
-        compile_cache.enable_compile_cache(str(tmp_path / "cc"))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+        compile_cache.enable_compile_cache()
         compile_cache.reset_stats()
         model, draft, params, dparams = tw.tiny_models()
         bat = ContinuousBatcher(model, draft, params, dparams,
@@ -471,7 +483,7 @@ def test_standby_promotion_real_worker_serves_without_compile(tmp_path):
                       prompt_lens=(tw.P,))
     spec = WorkerSpec(builder="rocket_tpu.testing.workers:build_tiny_loop",
                       kwargs={"warmup": plan.to_wire()})
-    env = {"ROCKET_TPU_COMPILE_CACHE": str(tmp_path / "cc"),
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc"),
            "JAX_PLATFORMS": "cpu"}
 
     def spawn(rid):
