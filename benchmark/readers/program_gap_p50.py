@@ -1,0 +1,13 @@
+"""Median idle gap on the device before each run of a program, in
+milliseconds: the host's share of a step or a round."""
+
+from benchmark.readers._common import find_program
+from benchmark.trace_reduce import p50
+
+
+def read(ctx, program):
+    name = find_program(ctx["trace"], program)
+    if name is None:
+        return None
+    value = p50(ctx["trace"].program_gaps(name))
+    return None if value is None else value * 1e3
