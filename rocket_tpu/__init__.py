@@ -9,6 +9,10 @@ The public surface is flattened here the same way the reference flattens
 ``rocket.core`` into ``rocket.*`` (``rocket/__init__.py:1``).
 """
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter_ns()  # start-up record: startup/import
+
 from rocket_tpu.core import (
     Attributes,
     Capsule,
@@ -83,3 +87,8 @@ __all__ = [
     "Tracker",
     "__version__",
 ]
+
+from rocket_tpu.observe.trace import get_startup as _get_startup  # noqa: E402
+
+_get_startup().mark("startup/import", _IMPORT_T0, _time.perf_counter_ns(),
+                    package=__name__)

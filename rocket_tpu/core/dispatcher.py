@@ -19,19 +19,19 @@ from typing import Any, Iterable, List, Optional
 from rocket_tpu.core.attributes import Attributes
 from rocket_tpu.core.capsule import Capsule
 
-# Lazy handle to observe.trace.get_tracer — resolved on first traced
-# dispatch, NOT at import (rocket_tpu.observe imports core capsules, so a
-# top-level import here would be circular).
-_GET_TRACER = None
+# Lazy handle to observe.trace.span — resolved on first dispatch, NOT at
+# import (rocket_tpu.observe imports core capsules, so a top-level import
+# here would be circular).
+_SPAN = None
 
 
-def _tracer():
-    global _GET_TRACER
-    if _GET_TRACER is None:
-        from rocket_tpu.observe.trace import get_tracer
+def _span(name: str, **fields: Any):
+    global _SPAN
+    if _SPAN is None:
+        from rocket_tpu.observe.trace import span
 
-        _GET_TRACER = get_tracer
-    return _GET_TRACER()
+        _SPAN = span
+    return _SPAN(name, **fields)
 
 
 class Dispatcher(Capsule):
@@ -54,17 +54,18 @@ class Dispatcher(Capsule):
 
     def _event(self, capsule: Capsule, event: str,
                attrs: Optional[Attributes]) -> None:
-        """Dispatch one lifecycle event to one child, wrapped in a tracer
-        span when the bound runtime armed ``tracing`` (ISSUE 4: automatic
-        capsule instrumentation, zero cost when disarmed)."""
-        if self._runtime is not None and getattr(
-            self._runtime, "tracing", False
-        ):
-            name = f"{type(capsule).__name__}.{event}"
-            with _tracer().span(name, cat="capsule"):
-                getattr(capsule, event)(attrs)
-        else:
+        """Dispatch one lifecycle event to one child under its
+        ``<Capsule>.<event>`` span: a profiler annotation always, a ring
+        event when tracing is armed — one path either way."""
+        with _span(f"{type(capsule).__name__}.{event}", cat="capsule"):
             getattr(capsule, event)(attrs)
+
+    def _launch_children(self, attrs: Optional[Attributes]) -> None:
+        """The hot loops' fan-out (``Looper`` each iteration, ``Module``
+        to its Loss/Optimizer/Scheduler): every child's ``launch`` under
+        its own ``<Capsule>.launch`` span."""
+        for capsule in self._capsules:
+            self._event(capsule, "launch", attrs)
 
     def setup(self, attrs: Optional[Attributes] = None) -> None:
         super().setup(attrs)
@@ -88,8 +89,7 @@ class Dispatcher(Capsule):
 
     def launch(self, attrs: Optional[Attributes] = None) -> None:
         super().launch(attrs)
-        for capsule in self._capsules:
-            self._event(capsule, "launch", attrs)
+        self._launch_children(attrs)
 
     # -- runtime ------------------------------------------------------------
 

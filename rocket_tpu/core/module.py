@@ -51,7 +51,7 @@ from rocket_tpu.engine.step import (
     build_train_step,
     build_window_step,
 )
-from rocket_tpu.observe.trace import span as trace_span
+from rocket_tpu.observe.trace import span
 from rocket_tpu.parallel.sharding import (
     DEFAULT_PARTITION_RULES,
     specs_for_state,
@@ -457,7 +457,7 @@ class Module(Dispatcher):
         # happens at first dispatch, where the ledger attributes it —
         # :meth:`warm_start` moves that compile ahead of the first real
         # batch, against the persistent compile cache).
-        with trace_span("module/build_steps", fused=self._use_window):
+        with span("module/build_steps", fused=self._use_window):
             self._build_steps_inner(policy)
 
     def warm_start(self, batch: Any) -> Optional[dict]:
@@ -688,8 +688,7 @@ class Module(Dispatcher):
                 self._window_buffer.append(batch)
                 if len(self._window_buffer) < self._accum:
                     attrs.step_logs = None  # mid-window: nothing ran
-                    for capsule in self._capsules:
-                        capsule.launch(attrs)
+                    self._launch_children(attrs)
                     return
                 batches = tuple(self._window_buffer)
                 self._window_buffer = []
@@ -737,8 +736,7 @@ class Module(Dispatcher):
             attrs.step_logs = logs
 
         # Children (Loss/Optimizer/Scheduler) do host-side logging only.
-        for capsule in self._capsules:
-            capsule.launch(attrs)
+        self._launch_children(attrs)
 
     # -- resilience hooks (DivergenceSentinel) -------------------------------
 

@@ -45,8 +45,8 @@ import optax
 from rocket_tpu.engine.ema import find_params_ema
 from rocket_tpu.engine.precision import Policy
 from rocket_tpu.engine.state import TrainState
-from rocket_tpu.observe.ledger import ledger_call
-from rocket_tpu.observe.profile import annotate
+from rocket_tpu.observe.ledger import get_goodput, ledger_call
+from rocket_tpu.observe.trace import span
 
 # ``apply_fn(params, mutable, rng, batch, train)`` -> ``(batch_out, mutable)``
 # — the model rewrites the batch blackboard-style, the functional analogue of
@@ -69,38 +69,52 @@ def _resolve_donate(donate: Optional[bool]) -> bool:
     return bool(runtime_default("donate", default=True))
 
 
+STEP_SPAN = "train/step_dispatch"
+_GOODPUT = get_goodput()
+
+
 class _AnnotatedStep:
-    """Wrap a jitted step so each invocation runs inside a named
-    ``jax.profiler`` annotation (ISSUE 4: dispatch vs host-fetch
-    attribution).  The annotation covers the HOST-side dispatch — tracing
-    the args and enqueueing the async executable — which in a healthy
-    pipeline is microseconds; any host fetch shows up elsewhere
-    (``looper/host_fetch``).  Calls forward positionally, so donated
-    buffers donate exactly as before, and every other ``PjitFunction``
-    attribute (``lower``, ``_cache_size``, ...) delegates to the wrapped
-    function, which stays reachable as ``.jitted``.
+    """Wrap a jitted step so each invocation runs inside the
+    ``train/step_dispatch`` span (one name for every step variant; the
+    jit edge's own name rides as the ``edge`` field) — a profiler
+    annotation always, a ring event when the tracer is armed.  The span
+    covers the HOST-side dispatch — tracing the args and enqueueing the
+    async executable — which in a healthy pipeline is microseconds; any
+    host fetch shows up elsewhere (``looper/host_fetch``).  Calls forward
+    positionally, so donated buffers donate exactly as before, and every
+    other ``PjitFunction`` attribute (``lower``, ``_cache_size``, ...)
+    delegates to the wrapped function, which stays reachable as
+    ``.jitted``.
 
     Dispatch routes through :func:`~rocket_tpu.observe.ledger.ledger_call`
-    (ISSUE 9): when the retrace ledger is armed, every compile at this
-    edge is recorded and an unexpected post-warmup retrace escalates to a
-    flight-recorder dump; disarmed, the wrapper is one attribute check."""
+    (ISSUE 9): the edge's first call is recorded as start-up; when the
+    retrace ledger is armed, every compile at this edge is recorded and an
+    unexpected post-warmup retrace escalates to a flight-recorder dump.
+    The return of the dispatch is stamped on the goodput ledger: from
+    there the device has work."""
 
-    __slots__ = ("jitted", "_name")
+    __slots__ = ("jitted", "_name", "_span")
 
-    def __init__(self, fn: Callable, name: str) -> None:
+    def __init__(self, fn: Callable, name: str,
+                 span_name: str = STEP_SPAN) -> None:
         self.jitted = fn
         self._name = name
+        self._span = span_name
 
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
-        with annotate(self._name):
-            return ledger_call(self.jitted, self._name, *args, **kwargs)
+        with span(self._span, edge=self._name):
+            out = ledger_call(self.jitted, self._name, *args, **kwargs)
+        if _GOODPUT.armed:
+            _GOODPUT.mark_dispatch()
+        return out
 
     def __getattr__(self, attr: str) -> Any:
         return getattr(self.jitted, attr)
 
 
-def _annotated_dispatch(fn: Callable, name: str) -> Callable:
-    return _AnnotatedStep(fn, name)
+def _annotated_dispatch(fn: Callable, name: str,
+                        span_name: str = STEP_SPAN) -> Callable:
+    return _AnnotatedStep(fn, name, span_name)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -574,4 +588,5 @@ def build_eval_step(
             _, logs = _total_loss(objectives, batch_out)
         return batch_out, logs
 
-    return _annotated_dispatch(jax.jit(eval_step), "eval_step/dispatch")
+    return _annotated_dispatch(jax.jit(eval_step), "eval_step/dispatch",
+                               "eval/step_dispatch")

@@ -30,6 +30,7 @@ from typing import Any, Iterable, Optional, Union
 from rocket_tpu.core.attributes import Attributes
 from rocket_tpu.core.capsule import Capsule
 from rocket_tpu.core.dispatcher import Dispatcher
+from rocket_tpu.observe.trace import get_startup
 from rocket_tpu.parallel import multihost
 from rocket_tpu.runtime import Runtime
 
@@ -137,6 +138,15 @@ class Launcher(Dispatcher):
     # -- lifecycle -----------------------------------------------------------
 
     def setup(self, attrs: Optional[Attributes] = None) -> None:
+        startup = get_startup()
+        with startup.phase("startup/runtime"):
+            self._setup_runtime()
+        # Children's set-up: Module.materialize and module/build_steps do
+        # nearly all of it.
+        with startup.phase("startup/build"):
+            super().setup(attrs)
+
+    def _setup_runtime(self) -> None:
         multihost.initialize()
         runtime = self._external_runtime or Runtime(
             mesh=self._mesh,
@@ -164,6 +174,8 @@ class Launcher(Dispatcher):
         # cannot be created fails the launch.
         from rocket_tpu.tune import compile_cache
 
+        # (arming installs the hit/miss and trace/compile/retrieval
+        # listeners the start-up line and the exports read)
         self._logger.info("persistent compile cache: %s",
                           compile_cache.enable_compile_cache())
         if getattr(runtime, "tracing", False):
@@ -177,7 +189,6 @@ class Launcher(Dispatcher):
                     path=resolved,
                     load_capsules=self._resume_load_capsules,
                 )
-        super().setup(attrs)
 
     def _arm_flight_recorder(self, runtime: Runtime) -> None:
         """Tracing armed: stamp the cross-host merge anchor at a barrier
@@ -475,9 +486,9 @@ class Launcher(Dispatcher):
                 self._epoch_idx = epoch
                 attrs.launcher.epoch_idx = epoch
                 for capsule in self._capsules:
-                    capsule.set(attrs)
-                    capsule.launch(attrs)
-                    capsule.reset(attrs)
+                    self._event(capsule, "set", attrs)
+                    self._event(capsule, "launch", attrs)
+                    self._event(capsule, "reset", attrs)
                     if self._runtime.stop_training:
                         break  # skip sibling cycles; exit within the grace window
             if self._runtime.stop_training:

@@ -52,11 +52,8 @@ from typing import Any, Iterable, Optional
 from rocket_tpu.core.attributes import Attributes
 from rocket_tpu.core.capsule import Capsule
 from rocket_tpu.core.dispatcher import Dispatcher
-from rocket_tpu.observe.ledger import (
-    emit_gauges,
-    get_goodput,
-    memory_watermarks,
-)
+from rocket_tpu.observe.ledger import get_goodput, memory_watermarks
+from rocket_tpu.observe.trace import get_startup, span
 
 try:
     from termcolor import colored
@@ -64,6 +61,26 @@ except ImportError:  # pragma: no cover
 
     def colored(text: str, *args: Any, **kwargs: Any) -> str:
         return text
+
+
+def _first_array(logs: Any, default: Any = None) -> Any:
+    """A device leaf of ``step_logs`` whose readiness tells whether the
+    step that produced it has finished; ``default`` when no step ran."""
+    if logs is None:
+        return default
+    for value in dict(logs).values():
+        if hasattr(value, "is_ready"):
+            return value
+    return default
+
+
+def _is_ready(leaf: Any) -> bool:
+    """Has the step that produced ``leaf`` finished?  Never blocks
+    (``jax.Array.is_ready`` polls the buffer's event)."""
+    try:
+        return bool(leaf.is_ready())
+    except Exception:
+        return True  # a deleted buffer's step is long over
 
 
 class _LagWindow:
@@ -295,104 +312,114 @@ class Looper(Dispatcher):
             self.set(attrs)
         looper = attrs.looper
         bar = self._status_bar(looper.repeats)
-        # Hoisted per cycle: the per-iteration loop is the train hot path,
-        # so the tracing-armed check must not repeat per capsule per step.
-        traced = self._runtime is not None and getattr(
-            self._runtime, "tracing", False
-        )
-        if traced:
-            from rocket_tpu.core.dispatcher import _tracer
-
-            tracer = _tracer()
         window = self._lag_window
-        # Goodput accounting, hoisted like ``traced``: per iteration the
-        # armed path adds one clock read, one nested-seconds diff, and two
-        # bucket adds — bounded by the same <5% guard as tracing.
+        # Goodput accounting, hoisted per cycle: per iteration the armed
+        # path adds two clock reads, one non-blocking readiness probe, one
+        # nested-seconds diff and two bucket adds — bounded by the same
+        # <5% guard as tracing.
         goodput = get_goodput()
         gp_armed = goodput.armed
-        gp_wall = 0.0
         gp_iters = 0
         nested0 = 0.0
+        # A leaf of the previous iteration's step_logs: ready = that step
+        # has finished and the device has run dry.  None before the first
+        # step, when the device has nothing either.
+        prev_leaf = None
+        # The start-up line goes out when the first step's dispatch has
+        # returned (once a process: log_once sees to that).
+        startup = get_startup()
         try:
             # repeats=None: unbounded streaming cycle, ended by the child
             # Dataset's termination vote when the stream exhausts.
             while looper.repeats is None or self._iter_idx < looper.repeats:
-                gap_t0 = time.perf_counter()
-                if gp_armed:
-                    nested0 = goodput.nested_seconds()
-                attrs.batch = None
-                # Cleared WITH the batch: an iteration where no step runs
-                # (dataset exhausted on a resumed epoch) must not re-expose
-                # the previous iteration's logs to observers downstream
-                # (trackers, sentinels) as if a step had happened.
-                attrs.step_logs = None
-                if traced:
-                    with tracer.span(
-                        f"looper/{self._tag}/iter", iter=self._iter_idx
+                # The span is the whole iteration, bookkeeping included, so the
+                # iterations tile the host's timeline in a profiler trace.
+                with span(f"looper/{self._tag}/iter", iter=self._iter_idx):
+                    gap_t0 = time.perf_counter()
+                    if gp_armed:
+                        nested0 = goodput.nested_seconds()
+                        dry = prev_leaf is None or _is_ready(prev_leaf)
+                    attrs.batch = None
+                    # Cleared WITH the batch: an iteration where no step runs
+                    # (dataset exhausted on a resumed epoch) must not re-expose
+                    # the previous iteration's logs to observers downstream
+                    # (trackers, sentinels) as if a step had happened.
+                    attrs.step_logs = None
+                    self._launch_children(attrs)
+                    # Host dispatch gap: everything above ran without waiting
+                    # on the device (in async mode); the backpressure wait
+                    # below is device time and deliberately NOT counted.
+                    gap = time.perf_counter() - gap_t0
+                    self._gap_sum += gap
+                    self._gap_count += 1
+                    if not startup.logged and attrs.step_logs is not None:
+                        startup.log_once(self._logger)
+                    if window is not None:
+                        looper.lagged_logs = None
+                        if attrs.step_logs is not None:
+                            popped = window.push(attrs.step_logs)
+                            if popped is not None:
+                                # In-flight bound: materializing the snapshot
+                                # staged k iterations ago blocks only when the
+                                # host is > k steps ahead of the device.
+                                looper.lagged_logs = popped
+                                self._lagged_state = popped
+                    if gp_armed:
+                        cycle_wall = time.perf_counter() - gap_t0
+                        nested_delta = goodput.nested_seconds() - nested0
+                        if window is not None:
+                            # Lag mode: the dispatch gap is host-side (less
+                            # what the nested buckets — compile, data-
+                            # starved, checkpoint — already claimed in it);
+                            # the remainder is the backpressure wait, i.e.
+                            # the device productively stepping.
+                            blocked, nested_in = gap, nested_delta
+                        elif not dry:
+                            # The previous step was still running when this
+                            # iteration began: the device had work throughout.
+                            blocked, nested_in = 0.0, 0.0
+                        elif goodput.dispatched_at >= gap_t0:
+                            # The device had run dry and waited until this
+                            # iteration's step was handed to it.
+                            blocked = goodput.dispatched_at - gap_t0
+                            nested_in = goodput.nested_at_dispatch - nested0
+                        else:
+                            # Dry, and no step dispatched: it stayed dry.
+                            blocked, nested_in = cycle_wall, nested_delta
+                        goodput.add("host_blocked",
+                                    max(0.0, blocked - nested_in))
+                        goodput.add("productive", max(
+                            0.0, cycle_wall - blocked
+                            - (nested_delta - nested_in)))
+                        gp_iters += 1
+                        prev_leaf = _first_array(attrs.step_logs, prev_leaf)
+                    self._iter_idx += 1
+                    if looper.terminate or (
+                        self._runtime is not None
+                        and self._runtime.stop_training
                     ):
-                        for capsule in self._capsules:
-                            name = f"{type(capsule).__name__}.launch"
-                            with tracer.span(name, cat="capsule"):
-                                capsule.launch(attrs)
-                else:
-                    for capsule in self._capsules:
-                        capsule.launch(attrs)
-                # Host dispatch gap: everything above ran without waiting
-                # on the device (in async mode); the backpressure wait
-                # below is device time and deliberately NOT counted.
-                gap = time.perf_counter() - gap_t0
-                self._gap_sum += gap
-                self._gap_count += 1
-                if window is not None:
-                    looper.lagged_logs = None
-                    if attrs.step_logs is not None:
-                        popped = window.push(attrs.step_logs)
-                        if popped is not None:
-                            # In-flight bound: materializing the snapshot
-                            # staged k iterations ago blocks only when the
-                            # host is > k steps ahead of the device.
-                            looper.lagged_logs = popped
-                            self._lagged_state = popped
-                if gp_armed:
-                    # Bucket split for this iteration: the dispatch gap is
-                    # host-side (minus whatever nested buckets — compile,
-                    # data-starved, checkpoint — already claimed inside
-                    # it); the remainder to here is the backpressure wait,
-                    # i.e. the device productively stepping.
-                    cycle_wall = time.perf_counter() - gap_t0
-                    nested_delta = goodput.nested_seconds() - nested0
-                    goodput.add("productive", max(0.0, cycle_wall - gap))
-                    goodput.add("host_blocked",
-                                max(0.0, gap - nested_delta))
-                    gp_wall += cycle_wall
-                    gp_iters += 1
-                self._iter_idx += 1
-                if looper.terminate or (
-                    self._runtime is not None and self._runtime.stop_training
-                ):
-                    # cycle vote OR run-level stop (preemption/divergence
-                    # abort cast by a capsule outside this cycle's protocol)
-                    break
-                if bar is not None:
-                    bar.update(1)
-                    if self._iter_idx % self._refresh_every == 0:
-                        # Async mode: the postfix formats the k-lagged host
-                        # floats — a refresh must never sync mid-epoch.
-                        bar.set_postfix(
-                            self._format_state(looper.state)
-                            if window is None
-                            else self._format_lagged(looper.state)
-                        )
+                        # cycle vote OR run-level stop (preemption or
+                        # divergence abort cast by a capsule outside this
+                        # cycle's protocol)
+                        break
+                    if bar is not None:
+                        bar.update(1)
+                        if self._iter_idx % self._refresh_every == 0:
+                            # Async mode: the postfix formats the k-lagged host
+                            # floats — a refresh must never sync mid-epoch.
+                            bar.set_postfix(
+                                self._format_state(looper.state)
+                                if window is None
+                                else self._format_lagged(looper.state)
+                            )
         finally:
             if bar is not None:
                 bar.set_postfix(self._format_state(looper.state))
                 bar.close()
             if gp_armed and gp_iters:
                 # Cycle-boundary telemetry (already a sync point): device
-                # memory watermarks and — when a step-cost hint is
-                # installed — live MFU/MBU over the mean iteration wall.
+                # memory watermarks.
                 memory_watermarks()
-                emit_gauges(gp_wall / gp_iters)
         attrs.batch = None
         attrs.step_logs = None
 
@@ -439,13 +466,11 @@ class Looper(Dispatcher):
     def _format_state(state: Optional[Attributes]) -> dict:
         if not state:
             return {}
-        from rocket_tpu.observe.profile import annotate
-
         out = {}
         # The float() calls below are the loop's only host-fetch boundary;
-        # the annotation makes the (throttled) sync attributable in a
-        # profiler timeline instead of smearing into the next dispatch.
-        with annotate("looper/host_fetch"):
+        # the span makes the (throttled) sync attributable in a profiler
+        # timeline instead of smearing into the next dispatch.
+        with span("looper/host_fetch"):
             for key, value in state.items():
                 try:
                     out[key] = f"{float(value):.4g}"  # device sync, throttled

@@ -1,3 +1,7 @@
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter_ns()  # start-up record: startup/import
+
 from rocket_tpu.models import objectives
 from rocket_tpu.models.layers import Embed, PDense, RMSNorm, apply_rope, rotary_embedding
 from rocket_tpu.models.generate import (
@@ -52,3 +56,8 @@ __all__ = [
     "resnet50",
     "rotary_embedding",
 ]
+
+from rocket_tpu.observe.trace import get_startup as _get_startup  # noqa: E402
+
+_get_startup().mark("startup/import", _IMPORT_T0, _time.perf_counter_ns(),
+                    package=__name__)

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Any, Optional
 
 import jax
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from rocket_tpu.observe.ledger import get_retrace_ledger, ledger_call
+from rocket_tpu.observe.trace import get_tracer
 
 # The batcher's prefill/admit/import edges retrace BY DESIGN — every new
 # prompt length is a new signature (the one-dispatch batched paths pad to
@@ -1256,6 +1258,32 @@ def export_kv_row(state, row: int) -> KVHandoff:
     )
 
 
+class HostReads:
+    """Every blocking device→host read on the serving round path goes
+    through one of these: it opens a ``serve/fetch`` span (``what=``,
+    ``bytes=``) around the read, bumps ``counters.host_fetches`` and
+    stamps when the read returned, so the loop can tell how long the host
+    took from its last read to the next ``serve/dispatch``.  A bare
+    batcher has one of its own on the process tracer; a ``ServingLoop``
+    hands its batchers one with its tracer and its ``ServeCounters``."""
+
+    __slots__ = ("tracer", "counters", "returned_at")
+
+    def __init__(self, tracer: Any = None, counters: Any = None) -> None:
+        self.tracer = tracer if tracer is not None else get_tracer()
+        self.counters = counters
+        self.returned_at: Optional[float] = None  # perf_counter seconds
+
+    def __call__(self, x: Any, what: str, read: Any = np.asarray) -> Any:
+        with self.tracer.span("serve/fetch", what=what,
+                              bytes=int(getattr(x, "nbytes", 0))):
+            out = read(x)
+        if self.counters is not None:
+            self.counters.host_fetches += 1
+        self.returned_at = time.perf_counter()
+        return out
+
+
 class ContinuousBatcher:
     """Round-granular continuous batching over the batched speculative
     decoder — the serving-loop counterpart of the one-dispatch
@@ -1343,6 +1371,7 @@ class ContinuousBatcher:
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
         self._admits = 0
         self.state = None
+        self.reads = HostReads()
 
     def set_kv_cache_int8(self, enabled: bool) -> None:
         """Flip the int8 KV-cache knob on both decode models.
@@ -1408,13 +1437,15 @@ class ContinuousBatcher:
         ``(n_tok [B], done [B])`` as host numpy arrays."""
         if self.state is None:
             raise ValueError("call start() before step()")
-        self.state = ledger_call(
-            _spec_round, "generate/spec_round",
-            self._model, self._draft_model, self._params,
-            self._draft_params, self.state, self._temperature,
-            n_draft=self.n_draft, **self._kw(),
-        )
-        return np.asarray(self.state[1]), np.asarray(self.state[2])
+        with self.reads.tracer.span("serve/dispatch", n_draft=self.n_draft):
+            self.state = ledger_call(
+                _spec_round, "generate/spec_round",
+                self._model, self._draft_model, self._params,
+                self._draft_params, self.state, self._temperature,
+                n_draft=self.n_draft, **self._kw(),
+            )
+        return (self.reads(self.state[1], "n_tok"),
+                self.reads(self.state[2], "done"))
 
     def admit(self, row: int, prompt_row, *, preempt: bool = False) -> None:
         """Replace row ``row`` with a fresh request (``[1, P]`` or
@@ -1431,7 +1462,7 @@ class ContinuousBatcher:
             raise ValueError(
                 f"admit() row {row} out of range for batch of {B} rows"
             )
-        if not preempt and not bool(np.asarray(self.state[2])[row]):
+        if not preempt and not bool(self.reads(self.state[2], "done")[row]):
             raise ValueError(
                 f"admit() into row {row} which is still decoding — "
                 f"harvest it first (done flag unset), or pass "
@@ -1457,7 +1488,8 @@ class ContinuousBatcher:
             _spec_admit, "generate/spec_admit",
             self._model, self._draft_model, self._params,
             self._draft_params, self.state, jnp.int32(row), prompt_row,
-            key, self._temperature, **self._kw(),
+            key, self._temperature, _shape=int(prompt_row.shape[1]),
+            **self._kw(),
         )
 
     def prefill_handoff(self, prompt_row, *, key=None) -> "KVHandoff":
@@ -1596,7 +1628,7 @@ class ContinuousBatcher:
                 f"admit_prefilled() row {row} out of range for batch of "
                 f"{B} rows"
             )
-        if not preempt and not bool(np.asarray(self.state[2])[row]):
+        if not preempt and not bool(self.reads(self.state[2], "done")[row]):
             raise ValueError(
                 f"admit_prefilled() into row {row} which is still "
                 f"decoding — harvest it first (done flag unset), or pass "
@@ -1632,12 +1664,13 @@ class ContinuousBatcher:
         """Row indices whose requests are complete (eos or full buffer)."""
         if self.state is None:
             return []
-        return [int(r) for r in np.nonzero(np.asarray(self.state[2]))[0]]
+        return [int(r) for r in
+                np.nonzero(self.reads(self.state[2], "done"))[0]]
 
     @property
     def all_done(self) -> bool:
-        return self.state is not None and bool(np.all(np.asarray(
-            self.state[2])))
+        return self.state is not None and bool(np.all(
+            self.reads(self.state[2], "done")))
 
     def row_tokens(self, row: int):
         """``(tokens [total_len], n_tok)`` for one row, eos-tail-filled
@@ -1646,7 +1679,8 @@ class ContinuousBatcher:
             raise ValueError("call start() before row_tokens()")
         buf, n_tok = self.state[0], self.state[1]
         filled = _spec_eos_fill(buf, n_tok, self.eos_token)
-        return np.asarray(filled[row]), int(n_tok[row])
+        return (self.reads(filled[row], "row_tokens"),
+                int(self.reads(n_tok[row], "row_n_tok")))
 
     def stats(self):
         """``{"rounds": int, "drafted": [B], "accepted": [B]}`` — same

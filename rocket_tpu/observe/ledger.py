@@ -22,17 +22,21 @@ overhead; 2605.23066 does the same for checkpointing):
   the Launcher persists the snapshot as ``<project>/goodput.json`` and
   prints the table at launch end.
 
-Design constraints mirror the tracer's: the disarmed path is one global
-attribute check; the armed warm path adds two ``_cache_size()`` calls and
+Design constraints mirror the tracer's: the disarmed path is one set
+lookup and one attribute check; the armed warm path adds two ``_cache_size()`` calls and
 two clock reads per dispatch (<5% per train iter / serve round — enforced
 by ``TestGoodputGuard``); shape stringification happens only on the cold
 compile path.  Nothing here ever raises into the dispatch it wraps.
 
-Device telemetry lives here too: :func:`executable_cost` (per-executable
-``cost_analysis()`` FLOPs/bytes), :func:`emit_gauges` (MFU/MBU against
-``tune/cost_model.py``'s peak tables), and :func:`memory_watermarks`
+Device telemetry lives here too: :func:`memory_watermarks`
 (``device.memory_stats()`` counters — a guarded no-op on CPU, which has
-no memory stats to report).
+no memory stats to report).  Utilization is the benchmark's to compute
+(``train_step_mfu``), from a device trace and counted FLOPs.
+
+Start-up: the first call at each edge (trace + lower + compile or cache
+read) is recorded by :func:`ledger_call` as a ``startup/first_dispatch``
+phase of the process's start-up record (``observe/trace.py``), whether or
+not the sentinel is armed: one set lookup per call on the disarmed path.
 """
 
 from __future__ import annotations
@@ -46,7 +50,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from rocket_tpu.observe.trace import get_tracer
+from rocket_tpu.observe.trace import get_startup, get_tracer
 
 LOG = logging.getLogger("rocket_tpu.observe.ledger")
 
@@ -287,14 +291,47 @@ def get_retrace_ledger() -> RetraceLedger:
     return _RETRACE
 
 
-def ledger_call(fn: Callable, name: str, *args: Any, **kwargs: Any) -> Any:
+# Edges (or, for a shape-polymorphic edge, edge and shape) whose first
+# call this process made during start-up.
+_DISPATCHED: set = set()
+
+
+def ledger_call(fn: Callable, name: str, *args: Any, _shape: Any = None,
+                **kwargs: Any) -> Any:
     """The jit-edge chokepoint: dispatch ``fn`` under the retrace ledger.
 
-    Disarmed (the default), this is one attribute check on top of the
-    call; armed, it adds two cache-size reads and two clock reads on the
+    Until the start-up line is logged, the first call at an edge is
+    start-up (trace, lower, compile or a cache read) and is recorded as
+    ``startup/first_dispatch`` whether or not the ledger is armed; an
+    edge that legitimately compiles once per shape
+    (``generate/spec_admit``, per prompt length) passes that shape as
+    ``_shape`` and is recorded once per shape.  Once the line is logged
+    the record is closed: a later cold edge or shape is the ledger's
+    business alone, and neither the record nor ``_DISPATCHED`` grows with
+    the traffic.  Disarmed (the default) this costs one attribute check
+    on top of the call after start-up, and one set lookup more during
+    it; armed, it adds two cache-size reads and two clock reads on the
     warm path.  Every named dispatch edge in the repo routes through
     here.
     """
+    startup = get_startup()
+    if not startup.logged:
+        key = name if _shape is None else (name, _shape)
+        if key not in _DISPATCHED:
+            _DISPATCHED.add(key)
+            hits = _cc_hit_count()
+            shape = {} if _shape is None else {"shape": _shape}
+            phase = startup.phase("startup/first_dispatch", edge=name,
+                                  **shape)
+            with phase:
+                try:
+                    return _dispatch(fn, name, args, kwargs)
+                finally:
+                    phase.add(cache_hit=_cc_hit_count() > hits)
+    return _dispatch(fn, name, args, kwargs)
+
+
+def _dispatch(fn: Callable, name: str, args: tuple, kwargs: dict) -> Any:
     if not _RETRACE.armed:
         return fn(*args, **kwargs)
     return _RETRACE.call(fn, name, *args, **kwargs)
@@ -319,13 +356,23 @@ class GoodputLedger:
     the ISSUE's "buckets sum to wall time within 1%" check reduces to
     "unattributed stays small".
 
+    ``productive`` is wall time during which the device had work;
+    ``host_blocked`` is wall time during which it had run dry and waited
+    for the host to hand it the next step.  In the default loop the
+    ``Looper`` asks at the top of each iteration, without blocking,
+    whether the previous step has finished: if so, the time from there
+    until the step's dispatch returns (:meth:`mark_dispatch`) is
+    ``host_blocked``, and the rest of the iteration ``productive``; if
+    not, the whole iteration is ``productive``.  Under ``readback_lag``
+    the backpressure wait is ``productive`` and the dispatch gap
+    ``host_blocked``.
+
     Double-counting discipline: ``compile``, ``data_starved``,
     ``checkpoint``, and ``watchdog_rebuild`` seconds are *nested* inside
-    the looper's host-side dispatch gap.  Each nested add also bumps a
-    running ``nested_seconds`` counter; the Looper subtracts the per-cycle
-    delta of that counter from its measured gap before feeding
-    ``host_blocked``, so one second of compile is never also a second of
-    host-blocked.
+    the looper's iteration.  Each nested add also bumps a running
+    ``nested_seconds`` counter; the Looper subtracts the per-cycle delta
+    of that counter from what it books, so one second of compile is
+    never also a second of host-blocked or productive.
 
     ``preemption_loss`` is a *reported* bucket, not a measured one: the
     elastic-resume path calls :meth:`note_preemption_loss` with the
@@ -350,6 +397,10 @@ class GoodputLedger:
         self._t_end: Optional[float] = None
         self._buckets: Dict[str, float] = {b: 0.0 for b in self.BUCKETS}
         self._nested = 0.0
+        # perf_counter and nested_seconds at the return of the newest
+        # step dispatch (engine/step.py stamps it; the Looper reads it)
+        self.dispatched_at = 0.0
+        self.nested_at_dispatch = 0.0
 
     # -- run window -----------------------------------------------------
 
@@ -382,6 +433,12 @@ class GoodputLedger:
         """``with goodput.timed("checkpoint"): ...`` — times the body into
         ``bucket`` (no-op when disarmed; nested-ness follows ``NESTED``)."""
         return _TimedBucket(self, bucket, bucket in self.NESTED)
+
+    def mark_dispatch(self) -> None:
+        """A step's dispatch has just returned: from here the device has
+        work.  One clock read; called by every ``_AnnotatedStep``."""
+        self.dispatched_at = time.perf_counter()
+        self.nested_at_dispatch = self._nested
 
     def nested_seconds(self) -> float:
         """Running total of nested-bucket seconds — the Looper diffs this
@@ -485,6 +542,7 @@ def arm_ledgers(recorder: Optional[Any] = None) -> None:
     ``GoodputLedger.start_run`` resets its buckets for the same reason.
     """
     _RETRACE.reset()
+    _DISPATCHED.clear()
     _RETRACE.armed = True
     if recorder is not None:
         _RETRACE.set_recorder(recorder)
@@ -499,49 +557,8 @@ def disarm_ledgers() -> None:
 
 
 # ---------------------------------------------------------------------------
-# Device-cost and memory telemetry
+# Memory telemetry
 # ---------------------------------------------------------------------------
-
-# Per-run analytical step cost, set once by whoever knows the model
-# (bench/launcher via cost_model); consulted by emit_gauges each cycle.
-_STEP_COST: Dict[str, Optional[float]] = {
-    "flops": None, "bytes": None,
-}
-_STEP_COST_KIND: Dict[str, Optional[str]] = {"device_kind": None}
-
-
-def set_step_cost(flops: Optional[float] = None,
-                  bytes_accessed: Optional[float] = None,
-                  device_kind: Optional[str] = None) -> None:
-    """Install the per-step FLOPs/bytes the MFU/MBU gauges divide by
-    (from :func:`executable_cost` or ``tune/cost_model``'s analytical
-    formulas).  ``None`` leaves a component unset — its gauge is skipped."""
-    _STEP_COST["flops"] = flops
-    _STEP_COST["bytes"] = bytes_accessed
-    _STEP_COST_KIND["device_kind"] = device_kind
-
-
-def executable_cost(fn: Callable, *args: Any,
-                    **kwargs: Any) -> Optional[Dict[str, float]]:
-    """``fn.lower(*args).compile().cost_analysis()`` FLOPs/bytes.
-
-    COLD PATH ONLY: ``lower()`` may add executable-cache entries, so this
-    must never run on a per-step basis while the retrace guards are armed
-    — call it once at setup and feed :func:`set_step_cost`."""
-    try:
-        compiled = fn.lower(*args, **kwargs).compile()
-        costs = compiled.cost_analysis()
-    except Exception:
-        return None
-    if isinstance(costs, (list, tuple)):
-        costs = costs[0] if costs else {}
-    if not isinstance(costs, dict):
-        return None
-    return {
-        "flops": float(costs.get("flops", 0.0)),
-        "bytes_accessed": float(costs.get("bytes accessed", 0.0)),
-    }
-
 
 def memory_watermarks(tracer: Optional[Any] = None) -> Dict[str, float]:
     """Per-device ``memory_stats()`` watermarks as ``device/mem_*``
@@ -569,43 +586,4 @@ def memory_watermarks(tracer: Optional[Any] = None) -> Dict[str, float]:
         t = tracer if tracer is not None else get_tracer()
         for name, value in out.items():
             t.counter(name, value)
-    return out
-
-
-def emit_gauges(step_seconds: float,
-                tracer: Optional[Any] = None) -> Dict[str, float]:
-    """Emit live MFU/MBU counters for one step given its wall seconds,
-    dividing the installed :func:`set_step_cost` FLOPs/bytes by
-    ``tune/cost_model``'s device peaks.  Returns the gauges emitted
-    (empty when no cost hint is installed, the step took no time, or the
-    device kind has no published peak)."""
-    if step_seconds <= 0.0:
-        return {}
-    flops = _STEP_COST["flops"]
-    nbytes = _STEP_COST["bytes"]
-    if flops is None and nbytes is None:
-        return {}
-    from rocket_tpu.tune.cost_model import (
-        device_peak_flops,
-        device_peak_hbm_bytes,
-    )
-
-    kind = _STEP_COST_KIND["device_kind"]
-    out: Dict[str, float] = {}
-    try:
-        if flops is not None:
-            out["device/mfu"] = (
-                flops / step_seconds / device_peak_flops(kind)
-            )
-        if nbytes is not None:
-            out["device/mbu"] = (
-                nbytes / step_seconds / device_peak_hbm_bytes(kind)
-            )
-    except ValueError:
-        # No published peak for this device kind (a CPU run): no gauge at
-        # all rather than a utilization over some other chip's peak.
-        return {}
-    t = tracer if tracer is not None else get_tracer()
-    for name, value in out.items():
-        t.counter(name, value)
     return out

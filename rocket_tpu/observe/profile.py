@@ -26,11 +26,6 @@ from rocket_tpu.core.attributes import Attributes
 from rocket_tpu.core.capsule import Capsule
 
 
-def annotate(name: str):
-    """Named span in the profiler timeline (``jax.profiler.TraceAnnotation``)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
 class Profiler(Capsule):
     """Capture a profiler trace for iterations ``[start, start+count)`` of
     the first cycle it runs in.

@@ -16,11 +16,20 @@ Design constraints (ISSUE 4 tentpole):
   a single bytecode-atomic operation under CPython, so the serve loop's
   caller thread and the watchdog worker thread can both record without a
   mutex on the hot path;
-- **zero device syncs**: every stamp is ``time.perf_counter_ns()``; no
-  jax call appears anywhere on the recording path (``jax.process_index``
-  is consulted only at dump time, with a safe fallback);
-- **cheap when disarmed**: ``span()`` on a disabled tracer returns one
-  shared no-op context manager — no allocation, no clock read;
+- **zero device syncs**: every stamp is ``time.perf_counter_ns()``; the
+  only jax call on the recording path is the span's
+  ``jax.profiler.TraceAnnotation``, which touches no device
+  (``jax.process_index`` is consulted only at dump time, with a safe
+  fallback);
+- **one span primitive, on the profiler's clock**: :meth:`Tracer.span` is
+  the only way the program opens a span.  While it is open it holds a
+  ``TraceAnnotation`` of the same name, so every program span lands in
+  the ``.xplane.pb``'s host plane — on the device trace's own clock —
+  whenever a profiler session is running, and in the ring whenever the
+  tracer is armed;
+- **cheap when disarmed**: a span on a disabled tracer is the annotation
+  alone (well under a microsecond with no session) — no clock read, no
+  ring append;
 - **bounded**: the ring keeps the last ``capacity`` events; a flight
   recorder dump is therefore always a recent-history window, never an
   unbounded log.
@@ -39,12 +48,15 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import logging
 import os
 import threading
 import time
 import zlib
 from collections import deque
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # Event layout (plain tuples — cheapest thing CPython can append):
 #   (kind, name, ts_ns, dur_ns, tid, fields)
@@ -69,48 +81,46 @@ def _process_index() -> int:
         return 0
 
 
-class _NullSpan:
-    """Shared no-op context manager — the disarmed-tracer fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc: Any) -> bool:
-        return False
-
-    def add(self, **fields: Any) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    """One live span: stamps start at ``__enter__``, appends a completed
-    'X' event at ``__exit__``.  An exception escaping the body is recorded
-    in the span's fields (the flight recorder's most useful breadcrumb)."""
+    """One live span.  It holds a ``TraceAnnotation`` of its name while it
+    is open (the profiler's host plane); with a ring (``buf``, the armed
+    tracer's) it also stamps start at ``__enter__`` and appends a
+    completed 'X' event at ``__exit__``.  An exception escaping the body
+    is recorded in the span's fields (the flight recorder's most useful
+    breadcrumb).  ``on_close(name, start_ns, end_ns, fields)`` is called
+    at ``__exit__`` whether or not there is a ring: the start-up record's
+    phases are spans with such a hook."""
 
-    __slots__ = ("_buf", "_name", "_fields", "_t0")
+    __slots__ = ("_buf", "_name", "_fields", "_t0", "_ann", "_on_close")
 
-    def __init__(self, buf: deque, name: str, fields: Dict[str, Any]) -> None:
+    def __init__(self, buf: Optional[deque], name: str,
+                 fields: Dict[str, Any],
+                 on_close: Optional[Callable[..., None]] = None) -> None:
         self._buf = buf
         self._name = name
         self._fields = fields
+        self._on_close = on_close
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter_ns()
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
+        if self._buf is not None or self._on_close is not None:
+            self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
-        end = time.perf_counter_ns()
-        if exc_type is not None:
-            self._fields["error"] = repr(exc)
-        self._buf.append(
-            (SPAN, self._name, self._t0, end - self._t0,
-             threading.get_ident(), self._fields)
-        )
+        if self._buf is not None or self._on_close is not None:
+            end = time.perf_counter_ns()
+            if exc_type is not None:
+                self._fields["error"] = repr(exc)
+            if self._buf is not None:
+                self._buf.append(
+                    (SPAN, self._name, self._t0, end - self._t0,
+                     threading.get_ident(), self._fields)
+                )
+            if self._on_close is not None:
+                self._on_close(self._name, self._t0, end, self._fields)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
     def add(self, **fields: Any) -> None:
@@ -144,11 +154,10 @@ class Tracer:
     # -- recording (hot path) -------------------------------------------
 
     def span(self, name: str, **fields: Any):
-        """Context manager timing a code region.  Disabled tracers return
-        a shared no-op — callers never branch on ``enabled`` themselves."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self._buf, name, fields)
+        """Context manager over a code region: a profiler annotation
+        always, a ring event when armed — callers never branch on
+        ``enabled`` themselves."""
+        return _Span(self._buf if self.enabled else None, name, fields)
 
     def counter(self, name: str, value: float, **fields: Any) -> None:
         if not self.enabled:
@@ -264,6 +273,10 @@ class Tracer:
         if self.anchor is not None:
             meta["anchor_wall_s"] = self.anchor[0]
             meta["anchor_perf_us"] = self.anchor[1] / 1e3
+        if len(_STARTUP):
+            # The process's start-up record rides in the metadata, not
+            # among the events: a dump stays a recent-history window.
+            meta["startup"] = _STARTUP.to_meta()
         return {
             "traceEvents": out,
             "displayTimeUnit": "ms",
@@ -282,7 +295,7 @@ class Tracer:
     def tail_text(self, n: int = 48) -> str:
         """Human-readable last-``n`` events, newest last — the part of a
         flight-recorder dump you read before opening Perfetto."""
-        lines = []
+        lines = [_STARTUP.line()] if len(_STARTUP) else []
         for kind, name, ts_ns, dur_ns, tid, fields in self.events()[-n:]:
             stamp = f"{ts_ns / 1e9:14.6f}s"
             if kind == SPAN:
@@ -332,6 +345,159 @@ def counter(name: str, value: float = 1, **fields: Any) -> None:
     The convenience for library code that wants one line, not a
     ``get_tracer()`` dance — e.g. ``ops.quant``'s fallback telemetry."""
     _GLOBAL.counter(name, value, **fields)
+
+
+# -- start-up record ---------------------------------------------------------
+#
+# Start-up is a handful of events per process (imports, runtime, build, the
+# first call at each jit edge, the serving warm start), so it is recorded
+# whether or not any tracer is armed, in one small bounded process-wide
+# record: the same record whatever tracer a ServingLoop was handed.  Each
+# phase is also an ordinary span (annotation always, ring when armed).
+
+STARTUP_PHASES: Tuple[Tuple[str, str], ...] = (
+    ("startup/import", "import"),
+    ("startup/runtime", "runtime"),
+    ("startup/build", "build"),
+    ("startup/first_dispatch", "first dispatch"),
+    ("startup/serve_warm_start", "warm start"),
+)
+
+
+def _union_ns(intervals: Iterable[Tuple[int, int]]) -> int:
+    total, hi = 0, None
+    for lo, end in sorted(intervals):
+        if hi is None or lo > hi:
+            total += end - lo
+            hi = end
+        elif end > hi:
+            total += end - hi
+            hi = end
+    return total
+
+
+class StartupRecord:
+    """Bounded record of start-up phases: ``(name, start_ns, dur_ns,
+    fields)`` on ``perf_counter_ns``, oldest dropped first.  Start-up ends
+    when its line is logged (:meth:`log_once`): from then on the record
+    is closed and takes nothing more, so a compile in the middle of
+    service (a new prompt length at ``generate/spec_admit``) is the
+    retrace ledger's business and neither evicts nor inflates start-up."""
+
+    def __init__(self, capacity: int = 256) -> None:
+        self._events: deque = deque(maxlen=int(capacity))
+        self.logged = False
+        # persistent-compile-cache {"hits", "misses"} of the process when
+        # the line was logged; None until then, or if they cannot be read
+        self.cache: Optional[Dict[str, int]] = None
+
+    def __len__(self) -> int:
+        return len(self._events)
+
+    def mark(self, name: str, start_ns: int, end_ns: int,
+             **fields: Any) -> None:
+        """Record a phase whose ends the caller stamped itself (a package
+        ``__init__`` stamps its start before this module exists)."""
+        if self.logged:
+            return
+        self._events.append(
+            (name, int(start_ns), max(0, int(end_ns) - int(start_ns)),
+             fields))
+
+    def _close(self, name: str, start_ns: int, end_ns: int,
+               fields: Dict[str, Any]) -> None:
+        self.mark(name, start_ns, end_ns, **fields)
+
+    def phase(self, name: str, **fields: Any) -> _Span:
+        """``with startup.phase("startup/build"): ...`` — a span on the
+        global tracer like any other, recorded here too when it closes."""
+        return _Span(_GLOBAL._buf if _GLOBAL.enabled else None, name,
+                     fields, on_close=self._close)
+
+    def events(self) -> List[tuple]:
+        return list(self._events)
+
+    def clear(self) -> None:
+        self._events.clear()
+        self.logged = False
+        self.cache = None
+
+    def seconds(self, until_ns: Optional[int] = None) -> Dict[str, float]:
+        """Seconds by phase name, summed so that they add up: each name's
+        intervals count as their union (an import inside an import counts
+        once), less the phases of other names recorded inside them (the
+        warm start's seconds leave out the first dispatches it made).
+        ``until_ns`` leaves out phases that started at or after it."""
+        spans: Dict[str, List[Tuple[int, int]]] = {}
+        for name, ts, dur, _fields in self._events:
+            if until_ns is None or ts < until_ns:
+                spans.setdefault(name, []).append((ts, ts + dur))
+        out: Dict[str, float] = {}
+        for name, own in spans.items():
+            inside = [(lo, hi) for other, ivs in spans.items()
+                      if other != name for lo, hi in ivs
+                      if any(a <= lo and hi <= b for a, b in own)]
+            out[name] = (_union_ns(own) - _union_ns(inside)) / 1e9
+        return out
+
+    def line(self) -> str:
+        """The one line an operator reads: ``start-up: import 12.1 s,
+        build 7.0 s, first dispatch 17.2 s, 182 cache hits, 0 misses``
+        (the cache's counts as they stood when the line was logged)."""
+        secs = self.seconds()
+        parts = [f"{label} {secs[name]:.1f} s"
+                 for name, label in STARTUP_PHASES if name in secs]
+        parts += [f"{name} {s:.1f} s" for name, s in secs.items()
+                  if name not in dict(STARTUP_PHASES)]
+        cache = self.cache or _cache_counts()
+        if cache is not None:
+            parts.append(
+                f"{cache['hits']} cache hits, {cache['misses']} misses")
+        return "start-up: " + ", ".join(parts)
+
+    def log_once(self, logger: logging.Logger) -> Optional[str]:
+        """Log :meth:`line` the first time this is called in the process
+        (the ``Looper`` after a ``Launcher``'s first step, ``ServingLoop``
+        once it is built and warm) and close the record; later calls
+        do nothing."""
+        if self.logged:
+            return None
+        self.logged = True
+        self.cache = _cache_counts()
+        text = self.line()
+        logger.info("%s", text)
+        return text
+
+    def to_meta(self) -> Dict[str, Any]:
+        return {
+            "seconds": self.seconds(),
+            "cache": self.cache,
+            "events": [{"name": n, "ts": ts / 1e3, "dur": dur / 1e3,
+                        "args": f} for n, ts, dur, f in self._events],
+        }
+
+
+def _cache_counts() -> Optional[Dict[str, int]]:
+    """The persistent compile cache's hits and misses so far, or None
+    where nothing counts them (no ``Launcher`` or ``ServingLoop`` has
+    installed the listeners) — never a failure of the code that asks."""
+    try:
+        from rocket_tpu.tune import compile_cache
+
+        if not compile_cache.listening():
+            return None
+        hits, misses = compile_cache.hits_and_misses()
+    except Exception:
+        return None
+    return {"hits": hits, "misses": misses}
+
+
+_STARTUP = StartupRecord()
+
+
+def get_startup() -> StartupRecord:
+    """The process-wide start-up record."""
+    return _STARTUP
 
 
 # -- distributed request tracing --------------------------------------------

@@ -253,6 +253,7 @@ def _flash_fwd(q, k, v, seg, causal: bool, scale: float,
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*operands)
     return o, lse
 
@@ -408,6 +409,7 @@ def _flash_bwd(q, k, v, seg, o, lse, do, causal: bool, scale: float,
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_dq",
     )(*operands)
 
     kv_in = [
@@ -442,6 +444,7 @@ def _flash_bwd(q, k, v, seg, o, lse, do, causal: bool, scale: float,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_dkv",
     )(*kv_operands)
     return dq, dk, dv
 

@@ -143,6 +143,7 @@ def _int8_matmul_kernel_call(x, q, scale, nk_layout: bool, block_n: int):
         out_specs=pl.BlockSpec((Mp, block_n), lambda n: (0, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         interpret=_interpret(),
+        name="int8_matmul",
     )(x, q, scale)
     return out[:M, :N]
 

@@ -5,6 +5,10 @@ fault-free bit-equality contract; ``docs/reliability.md`` ("Serving
 reliability") for the operator view.
 """
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter_ns()  # start-up record: startup/import
+
 from rocket_tpu.serve.autoscale import (
     Autoscaler,
     AutoscaleCounters,
@@ -122,3 +126,8 @@ __all__ = [
     "synth_trace",
     "write_offsets",
 ]
+
+from rocket_tpu.observe.trace import get_startup as _get_startup  # noqa: E402
+
+_get_startup().mark("startup/import", _IMPORT_T0, _time.perf_counter_ns(),
+                    package=__name__)

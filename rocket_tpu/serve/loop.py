@@ -47,10 +47,11 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from rocket_tpu.models.generate import export_kv_row
+from rocket_tpu.models.generate import (HostReads, KVHandoff,
+                                         export_kv_row)
 from rocket_tpu.observe.ledger import expect_compile, get_goodput
 from rocket_tpu.observe.recorder import active_recorder
-from rocket_tpu.observe.trace import TraceContext, get_tracer
+from rocket_tpu.observe.trace import TraceContext, get_startup, get_tracer
 from rocket_tpu.serve.kvstore import page_hashes
 from rocket_tpu.serve.metrics import (
     ClassLatency,
@@ -179,6 +180,12 @@ class ServingLoop:
         # ``recorder`` overrides the process-global flight recorder for
         # crash dumps on trips/step errors.
         self._tracer = tracer if tracer is not None else get_tracer()
+        # Count compile-cache hits, misses and the trace / compile /
+        # retrieval seconds whoever armed the cache directory (the
+        # start-up line and the compile_cache/* export read them).
+        from rocket_tpu.tune import compile_cache
+
+        compile_cache.install_listeners()
         # Fleet identity: rides every typed result's ``meta`` and names
         # this loop's queue counters (``serve/queue/<replica>/...``).
         self.replica_id = replica_id
@@ -194,6 +201,9 @@ class ServingLoop:
         self.policy = policy if policy is not None else DegradationPolicy()
         self.watchdog = DispatchWatchdog(watchdog_timeout)
         self.counters = ServeCounters()
+        # Every blocking device→host read of this loop and its batchers:
+        # one serve/fetch span and one host_fetches bump each.
+        self._reads = HostReads(self._tracer, self.counters)
         self._recorder = recorder
         self.latency = ServeLatency()
         # Multi-tenant serving: per-SLO-class TTFT/e2e histograms (the
@@ -255,12 +265,16 @@ class ServingLoop:
                 "factory's models use decode_rolling_cache"
             )
         self._warm_start(self._bat)
+        # Built and warm, the loop reports SERVING: start-up is over, its
+        # line goes out (once a process) and its record closes.
+        get_startup().log_once(self._log)
 
     # -- lifecycle -----------------------------------------------------
 
     def _build_batcher(self) -> Any:
         """Factory call + the loop-level knobs every build must carry."""
         bat = self._factory()
+        bat.reads = self._reads
         if self._kv_cache_int8 is not None:
             bat.set_kv_cache_int8(self._kv_cache_int8)
         if self._live_params is not None:
@@ -284,6 +298,12 @@ class ServingLoop:
         jit DISPATCH cache (AOT does not populate it), so the inline
         ``expect_compile`` discipline is unchanged; on a warm host the
         "compile" it expects is a disk-cache retrieval."""
+        with get_startup().phase("startup/serve_warm_start"):
+            self._warm_start_inner(bat)
+        # the first served round's host gap starts at a read of its own
+        self._reads.returned_at = None
+
+    def _warm_start_inner(self, bat: Any) -> None:
         if self._warmup is not None:
             try:
                 from rocket_tpu.tune.warmup import (WarmupPlan,
@@ -306,7 +326,8 @@ class ServingLoop:
         with expect_compile("generate/spec_round"):
             bat.step()  # inline: compile, not serve
         self._compiled_drafts = {int(bat.n_draft)}
-        self._carry = (np.asarray(bat.state[0]), np.asarray(bat.state[1]))
+        self._carry = (self._reads(bat.state[0], "buf"),
+                       self._reads(bat.state[1], "n_tok"))
 
     @property
     def health(self) -> HealthState:
@@ -655,19 +676,24 @@ class ServingLoop:
         update the degradation ladder.  Returns ``True`` if any device
         work ran (False = completely idle)."""
         now = self._clock()
-        self._shed_hopeless(now)
-        self._preempt_batch(now)
+        with self._tracer.span("serve/shed"):
+            self._shed_hopeless(now)
+            self._preempt_batch(now)
         self._admit_pending(now)
         if not self._live_rows():
+            # idle: the time to the next dispatch is no host gap
+            self._reads.returned_at = None
             self._flush()
             return False
 
         ok = self._dispatch()
         if ok:
-            self._harvest(self._clock())
+            with self._tracer.span("serve/harvest"):
+                self._harvest(self._clock())
             if self._recover_in > 0:
                 self._recover_in -= 1
-        self._update_policy()
+        with self._tracer.span("serve/policy"):
+            self._update_policy()
         self._observe_health()
         self._flush()
         return True
@@ -729,7 +755,7 @@ class ServingLoop:
             return
         # Least progress first: the cheapest resume (fewest pages to
         # re-import) and the least decode work at risk of cache churn.
-        n_tok_h = np.asarray(self._bat.state[1])
+        n_tok_h = self._reads(self._bat.state[1], "n_tok")
         victims.sort(key=lambda pair: (int(n_tok_h[pair[0]]), pair[0]))
         for row, occ in victims[:need]:
             toks, nt = self._bat.row_tokens(row)
@@ -779,6 +805,8 @@ class ServingLoop:
                             req.rid, now, tokens=ticket.tokens,
                             n_tok=int(ticket.tokens.shape[0]),
                             stage="decode", meta=self._meta(),
+                            submitted_at=getattr(req, "_submit_ts", None),
+                            due_at=req.due_at,
                         ))
                     else:
                         self._results.append(
@@ -917,7 +945,8 @@ class ServingLoop:
         budget, _ = self._budget(req, req.prompt.shape[0])
         with self._tracer.span("serve/beam", rid=req.rid,
                                prompt_len=int(req.prompt.shape[0])):
-            toks = np.asarray(self._beam_fn(req.prompt[None, :], budget))
+            toks = self._reads(
+                self._beam_fn(req.prompt[None, :], budget), "beam")
         toks = toks[0] if toks.ndim == 2 else toks
         self.counters.admitted += 1
         self.counters.beam_served += 1
@@ -931,7 +960,8 @@ class ServingLoop:
         self._flow(req, "f", outcome="beam")
         self._results.append(Completed(
             req.rid, done, tokens=toks, n_tok=int(toks.shape[0]),
-            via_beam=True, meta=self._meta(),
+            via_beam=True, meta=self._meta(), submitted_at=submitted,
+            admitted_at=now, due_at=req.due_at,
         ))
 
     def _dispatch(self) -> bool:
@@ -941,9 +971,16 @@ class ServingLoop:
         bat = self._bat  # bind NOW: a zombie must not see a rebuilt self._bat
         n_draft = int(bat.n_draft)
 
+        reads = self._reads
+
         def _step():
+            # the host's own view of the gap between rounds: return of
+            # its last device read to this dispatch
+            last = reads.returned_at
+            gap_ms = None if last is None \
+                else (time.perf_counter() - last) * 1e3
             n_tok, done = bat.step()
-            return np.asarray(bat.state[0]), n_tok, done
+            return reads(bat.state[0], "buf"), n_tok, done, gap_ms
 
         t0 = time.monotonic()
         # The per-round decode span: it CLOSES when the with-block exits
@@ -986,10 +1023,12 @@ class ServingLoop:
             self._rebuild()
             return False
 
-        buf, n_tok, done = value
+        buf, n_tok, done, gap_ms = value
         self._carry = (buf, n_tok)
         round_ms = (time.monotonic() - t0) * 1e3
         self.counters.observe_round_ms(round_ms)
+        if gap_ms is not None:
+            self.counters.observe_round_gap_ms(gap_ms)
         self._round_ms = self.counters.round_ms_ema
         now = self._clock()
         for occ in self._rows.values():
@@ -1011,6 +1050,16 @@ class ServingLoop:
                             "serve/first_token", rid=occ.req.rid,
                             ttft_ms=ttft_ms, cls=occ.req.slo_class)
         return True
+
+    @staticmethod
+    def _stamps(occ: _Row) -> Dict[str, Any]:
+        """The row's instants (loop clock) and the request's ``due_at``,
+        for the typed result: enough to compute queue wait, TTFT and
+        TPOT from the result alone."""
+        return {"submitted_at": occ.submitted_at,
+                "admitted_at": occ.admitted_at,
+                "first_token_at": occ.first_tok_at,
+                "due_at": occ.req.due_at}
 
     def _inflight_requests(self) -> List[Request]:
         """Every request this loop currently owes a result for: queued,
@@ -1072,6 +1121,7 @@ class ServingLoop:
             self._results.append(Failed(
                 occ.req.rid, now, tokens=toks, n_tok=n, reason=reason,
                 dump_path=dump_path, meta=self._meta(),
+                **self._stamps(occ),
             ))
             self._rows[row] = None
 
@@ -1091,8 +1141,8 @@ class ServingLoop:
         """Round-boundary accounting: finished rows complete; rows past
         deadline evict with partials; rows at their (possibly degraded)
         budget complete as truncated."""
-        n_tok_h = np.asarray(self._bat.state[1])
-        done_h = np.asarray(self._bat.state[2])
+        n_tok_h = self._reads(self._bat.state[1], "n_tok")
+        done_h = self._reads(self._bat.state[2], "done")
         for row, occ in self._rows.items():
             if occ is None:
                 continue
@@ -1107,6 +1157,7 @@ class ServingLoop:
                 self._results.append(Completed(
                     occ.req.rid, now, tokens=toks, n_tok=nt,
                     beam_demoted=occ.demoted, meta=self._meta(),
+                    **self._stamps(occ),
                 ))
                 self._rows[row] = None
             elif occ.req.deadline is not None and occ.req.deadline <= now:
@@ -1119,6 +1170,7 @@ class ServingLoop:
                 self._results.append(DeadlineExceeded(
                     occ.req.rid, now, tokens=toks[:n], n_tok=n,
                     stage="decode", meta=self._meta(),
+                    **self._stamps(occ),
                 ))
                 self._rows[row] = None
             elif produced >= occ.budget:
@@ -1134,7 +1186,7 @@ class ServingLoop:
                 self._results.append(Completed(
                     occ.req.rid, now, tokens=toks, n_tok=nt,
                     truncated=truncated, beam_demoted=occ.demoted,
-                    meta=self._meta(),
+                    meta=self._meta(), **self._stamps(occ),
                 ))
                 self._rows[row] = None
 
@@ -1152,7 +1204,8 @@ class ServingLoop:
                 # Pool-armed path: split/hash ONCE, feed both tiers —
                 # local store for this replica's next hit, pool push so
                 # any other replica can import the chain.
-                host = export_kv_row(self._bat.state, row).to_host()
+                host = self._reads(export_kv_row(self._bat.state, row),
+                                   "kv_row", read=KVHandoff.to_host)
                 pt = self.kvstore.page_tokens
                 pages = host.split_pages(pt)
                 if not pages:

@@ -73,6 +73,15 @@ class ServeCounters:
         self.degrade_level = 0
         self.degrade_peak = 0
         self.round_ms_ema = 0.0
+        self.round_gap_ms_ema = 0.0  # last device read -> next dispatch
+        self.host_fetches = 0       # blocking device->host reads
+
+    def observe_round_gap_ms(self, gap_ms: float, decay: float = 0.8) -> None:
+        if self.round_gap_ms_ema == 0.0:
+            self.round_gap_ms_ema = gap_ms
+        else:
+            self.round_gap_ms_ema = decay * self.round_gap_ms_ema \
+                + (1.0 - decay) * gap_ms
 
     def observe_round_ms(self, round_ms: float, decay: float = 0.8) -> None:
         self.rounds += 1
@@ -130,6 +139,8 @@ class ServeCounters:
             "degrade_level": float(self.degrade_level),
             "degrade_peak": float(self.degrade_peak),
             "round_ms_ema": float(self.round_ms_ema),
+            "round_gap_ms_ema": float(self.round_gap_ms_ema),
+            "host_fetches": float(self.host_fetches),
         })
         return out
 

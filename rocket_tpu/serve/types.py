@@ -77,6 +77,13 @@ class Request:
     round-boundary preemption (evict-to-kvstore, resume later,
     bit-equal).  Both cross the RPC wire.
 
+    ``due_at`` is the caller's own stamp of when the request was due
+    (a load generator's schedule, on the caller's clock); the loop never
+    reads it and copies it through untouched onto the typed result, so
+    latency from when the request SHOULD have arrived — not from when a
+    late generator got round to submitting it — can be computed from the
+    result alone.
+
     Distributed tracing stamps a private
     :class:`~rocket_tpu.observe.trace.TraceContext` as ``_ctx`` at
     submit (same convention as the other lifecycle stamps ``_submit_ts``
@@ -92,6 +99,7 @@ class Request:
     session: Optional[Any] = None
     tenant: Optional[str] = None
     slo_class: str = "standard"
+    due_at: Optional[float] = None
 
     def __post_init__(self) -> None:
         if self.slo_class not in SLO_CLASSES:
@@ -143,6 +151,15 @@ class Completed(Result):
     via_beam: bool = False
     beam_demoted: bool = False
     truncated: bool = False
+    # the row's instants on the loop's clock (None where the request
+    # never got that far) and the request's own due_at, untouched:
+    # queue wait = admitted_at - submitted_at (or - due_at), TTFT =
+    # first_token_at - submitted_at, TPOT = (finished_at -
+    # first_token_at) / (generated tokens - 1)
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
+    due_at: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,6 +172,15 @@ class DeadlineExceeded(Result):
     tokens: Optional[np.ndarray] = None
     n_tok: int = 0
     stage: str = "queue"  # 'queue' = shed before prefill; 'decode' = evicted
+    # the row's instants on the loop's clock (None where the request
+    # never got that far) and the request's own due_at, untouched:
+    # queue wait = admitted_at - submitted_at (or - due_at), TTFT =
+    # first_token_at - submitted_at, TPOT = (finished_at -
+    # first_token_at) / (generated tokens - 1)
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
+    due_at: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -187,3 +213,12 @@ class Failed(Result):
     n_tok: int = 0
     reason: str = "step failure"
     dump_path: Optional[str] = None
+    # the row's instants on the loop's clock (None where the request
+    # never got that far) and the request's own due_at, untouched:
+    # queue wait = admitted_at - submitted_at (or - due_at), TTFT =
+    # first_token_at - submitted_at, TPOT = (finished_at -
+    # first_token_at) / (generated tokens - 1)
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
+    due_at: Optional[float] = None

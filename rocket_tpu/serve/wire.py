@@ -220,6 +220,7 @@ def pack_request(req: Request, *,
         "session": req.session,
         "tenant": req.tenant,
         "slo_class": req.slo_class,
+        "due_at": req.due_at,
     }
     handoff = getattr(req, "_handoff", None)
     if handoff is not None:
@@ -242,6 +243,7 @@ def unpack_request(wire: Dict[str, Any], *,
         session=wire.get("session"),
         tenant=wire.get("tenant"),
         slo_class=wire.get("slo_class", "standard"),
+        due_at=wire.get("due_at"),
     )
     handoff = wire.get("handoff")
     if handoff is not None:

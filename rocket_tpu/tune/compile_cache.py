@@ -12,10 +12,11 @@ makes that cost a one-time event per (executable, topology):
   of the cache key, so a directory that moves never hits.
 - :func:`enable_compile_cache` arms JAX's persistent compilation cache
   there (the min-entry-size / min-compile-time knobs opened all the
-  way, so even the tiny CPU-proxy executables persist), installs the
-  jax monitoring listeners that count cache hits/misses and the
-  trace-vs-compile time split, and registers a ``compile_cache/*``
-  export source.  Idempotent; safe to call from the Launcher, the serve
+  way, so even the tiny CPU-proxy executables persist).  The jax
+  monitoring listeners that count cache hits/misses and the
+  trace-vs-compile time split, and the ``compile_cache/*`` export
+  source, are :func:`install_listeners`'s: every ``Launcher`` and
+  ``ServingLoop`` calls it, whoever armed the directory.  Idempotent; safe to call from the Launcher, the serve
   worker, and tests in any order.  A directory that cannot be created
   or armed raises.
 - :func:`hit_count` is the cheap counter the
@@ -101,15 +102,21 @@ def _on_duration(event: str, duration: float, **kwargs: Any) -> None:
             _state["saved_s"] += duration
 
 
-def _install_listeners() -> None:
-    # once per process — jax keeps listeners forever, a second install
-    # would double-count.
-    if _state["listeners"]:
-        return
-    from jax._src import monitoring
-    monitoring.register_event_listener(_on_event)
-    monitoring.register_event_duration_secs_listener(_on_duration)
-    _state["listeners"] = True
+def install_listeners() -> None:
+    """Count cache hits, requests and the trace / compile / retrieval
+    seconds from here on, whoever armed the cache directory: every
+    ``Launcher`` and ``ServingLoop`` calls this when it is built, so the
+    counters run also where the embedding program (a benchmark, a
+    service) set ``jax_compilation_cache_dir`` itself.  Once per process
+    — jax keeps listeners forever, a second install would double-count."""
+    with _lock:
+        if not _state["listeners"]:
+            from jax._src import monitoring
+            monitoring.register_event_listener(_on_event)
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            _state["listeners"] = True
+    from rocket_tpu.observe import export
+    export.register_source("compile_cache", snapshot)
 
 
 def enable_compile_cache() -> str:
@@ -118,8 +125,8 @@ def enable_compile_cache() -> str:
     no-op; a changed ``$JAX_COMPILATION_CACHE_DIR`` re-points the cache.
     Raises when the directory cannot be created."""
     directory = cache_dir()
+    install_listeners()
     with _lock:
-        _install_listeners()
         if _state["enabled_dir"] == directory:
             return directory
         repointing = _state["enabled_dir"] is not None
@@ -138,8 +145,6 @@ def enable_compile_cache() -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     with _lock:
         _state["enabled_dir"] = directory
-    from rocket_tpu.observe import export
-    export.register_source("compile_cache", snapshot)
     logger.info("persistent compile cache armed at %s", directory)
     return directory
 
@@ -154,6 +159,21 @@ def hit_count() -> int:
     the retrace ledger around each dispatch)."""
     with _lock:
         return int(_state["hits"])
+
+
+def listening() -> bool:
+    """Whether :func:`install_listeners` has run: before it, the counters
+    read zero because nothing counts, not because nothing compiled."""
+    with _lock:
+        return bool(_state["listeners"])
+
+
+def hits_and_misses() -> "tuple[int, int]":
+    """``(hits, misses)`` of the persistent cache so far (cheap: no walk
+    of the directory, unlike :func:`snapshot`)."""
+    with _lock:
+        hits, requests = int(_state["hits"]), int(_state["requests"])
+    return hits, max(0, requests - hits)
 
 
 def reset_stats() -> None:

@@ -85,19 +85,6 @@ def test_local_cpu_device_has_no_peak(devices):
         cost_model.device_peak_flops()
 
 
-def test_gauges_emit_nothing_over_an_unknown_kind():
-    from rocket_tpu.observe import ledger
-
-    ledger.set_step_cost(flops=1e12, bytes_accessed=1e9, device_kind="cpu")
-    try:
-        assert ledger.emit_gauges(0.1) == {}
-        ledger.set_step_cost(flops=1e12, device_kind="TPU v5 lite")
-        assert ledger.emit_gauges(0.1) == {
-            "device/mfu": pytest.approx(1e13 / 197e12)}
-    finally:
-        ledger.set_step_cost()
-
-
 # -- the tune search's parent stays off the chip ------------------------------
 
 

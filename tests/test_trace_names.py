@@ -23,13 +23,20 @@ PKG = os.path.join(REPO, "rocket_tpu")
 
 # The emitting calls whose first positional argument is an event name.
 # ``_instant`` is FleetRouter's tracer-guarded wrapper — same first-arg
-# contract, so its fleet/* names lint too.
-_EMITTERS = {"span", "counter", "instant", "health", "flow", "_instant"}
+# contract, so its fleet/* names lint too; ``_span`` is the Dispatcher's
+# lazy handle to ``trace.span``; ``phase`` / ``mark`` write the start-up
+# record.
+_EMITTERS = {"span", "counter", "instant", "health", "flow", "_instant",
+             "_span", "phase", "mark"}
 
 # lowercase slug segments joined by '/' — at least one slash (a bare
 # word has no category and collides with everything).  Dots are allowed
 # INSIDE a segment (e.g. a dotted metric suffix), never as the separator.
 _NAME_RE = re.compile(r"^[a-z0-9_]+(/[a-z0-9_.]+)+$")
+
+# The one other form: a capsule's lifecycle event, ``<Capsule>.<event>``
+# (``Optimizer.launch``), whose class name fills an f-string hole.
+_CAPSULE_RE = re.compile(r"^x\.x$")
 
 
 def _called_name(func):
@@ -101,7 +108,20 @@ def test_library_emits_trace_events():
             "serve/new_weights", "fleet/delivered", "fleet/requeued",
             "pool/fetch",
             # ZeRO host-offload round trip (engine/offload.py)
-            "offload/d2h", "offload/h2d"} <= names
+            "offload/d2h", "offload/h2d",
+            # ISSUE 24: the two hot loops' spans and the start-up record
+            # (stable names: the benchmark's readers and
+            # docs/observability.md's table key off them)
+            "looper/x/iter", "looper/host_fetch", "x.x",
+            "module/build_steps", "serve/round", "serve/dispatch",
+            "serve/fetch", "serve/harvest", "serve/admit", "serve/shed",
+            "serve/policy", "startup/import", "startup/runtime",
+            "startup/build", "startup/first_dispatch",
+            "startup/serve_warm_start"} <= names
+    # the train step's span takes its name from a constant
+    from rocket_tpu.engine.step import STEP_SPAN
+
+    assert STEP_SPAN == "train/step_dispatch" and _NAME_RE.match(STEP_SPAN)
 
 
 # -- jax.jit chokepoint lint (ISSUE 15 satellite) ----------------------------
@@ -235,7 +255,7 @@ def test_trace_names_follow_slash_convention():
     bad = [
         f"{os.path.relpath(path, REPO)}:{line}: {name!r}"
         for path, line, name in _all_sites()
-        if not _NAME_RE.match(name)
+        if not (_NAME_RE.match(name) or _CAPSULE_RE.match(name))
     ]
     assert not bad, (
         "trace event names must be lowercase 'cat/name' slugs "

@@ -105,11 +105,10 @@ class TestTracerRing:
         (ev,) = t.events()
         assert "boom" in ev[5]["error"]
 
-    def test_disabled_tracer_is_shared_noop(self):
+    def test_disabled_tracer_span_is_the_annotation_alone(self):
         t = Tracer(capacity=16, enabled=False)
-        a, b = t.span("x"), t.span("y", k=1)
-        assert a is b  # one shared null span — no per-call allocation
-        with a:
+        a = t.span("x/y", k=1)
+        with a:  # holds a TraceAnnotation, stamps no clock, fills no ring
             a.add(ignored=True)
         t.counter("c", 1.0)
         t.instant("i")
@@ -197,7 +196,10 @@ class TestChromeExport:
         txt = t.tail_text()
         assert "span  serve/round" in txt
         assert "health serve/health -> DEGRADED" in txt
-        assert Tracer(capacity=4).tail_text() == ""
+        # an empty ring prints no event lines (the process's start-up
+        # line, where a start-up was recorded, still heads the text)
+        empty = Tracer(capacity=4).tail_text()
+        assert "span" not in empty and "event" not in empty
 
 
 # -- units: histogram -------------------------------------------------------
@@ -393,15 +395,22 @@ class TestAutomaticInstrumentation:
         ]
         assert all(e[5] == {"cat": "capsule"} for e in armed_global.events())
 
-    def test_dispatcher_untraced_without_runtime_flag(self, devices,
-                                                      armed_global):
+    def test_dispatcher_spans_follow_the_tracer_not_a_runtime_flag(
+            self, devices, armed_global):
+        # one path through capsules: the span is always opened; whether
+        # it lands in the ring is the tracer's to say, armed or not
         runtime = Runtime(tracing=False)
         disp = Dispatcher(capsules=[_Probe()])
         disp.bind(runtime)
+        armed_global.enabled = False
         disp.setup(None)
         disp.launch(None)
-        disp.destroy(None)
         assert armed_global.events() == []
+        armed_global.enabled = True
+        disp.launch(None)
+        disp.destroy(None)
+        assert [e[1] for e in armed_global.events()] == [
+            "_Probe.launch", "_Probe.destroy"]
 
     def test_looper_iteration_spans(self, devices, armed_global):
         runtime = Runtime(tracing=True)
