@@ -12,9 +12,10 @@ with three interchangeable implementations behind one signature:
   (:mod:`rocket_tpu.ops.ring`): sequence/context parallelism for sequences
   too long for one chip, K/V blocks rotating over ICI via ``ppermute``.
 
-All take ``(q, k, v)`` shaped ``[batch, seq, heads, head_dim]`` (K/V may
-have fewer heads — grouped-query attention is handled by head repetition
-inside each impl).
+All take ``(q, k, v)`` shaped ``[batch, seq, heads, head_dim]``. K/V may
+have fewer heads (grouped-query attention): ``dot`` contracts each KV head
+against its group of query heads, so K and V are read once as they are;
+``flash`` and ``ring`` still repeat K/V up to the query heads first.
 """
 
 from __future__ import annotations
@@ -27,17 +28,20 @@ import jax.numpy as jnp
 Array = jax.Array
 
 
-def _repeat_kv(k: Array, v: Array, num_q_heads: int):
-    """Expand grouped K/V heads to match Q heads (GQA/MQA)."""
-    kv_heads = k.shape[2]
-    if kv_heads == num_q_heads:
-        return k, v
+def _group_size(num_q_heads: int, kv_heads: int) -> int:
+    """Query heads per K/V head (1 = plain multi-head attention)."""
     if num_q_heads % kv_heads != 0:
         raise ValueError(f"q heads {num_q_heads} not a multiple of kv heads {kv_heads}")
-    reps = num_q_heads // kv_heads
-    k = jnp.repeat(k, reps, axis=2)
-    v = jnp.repeat(v, reps, axis=2)
-    return k, v
+    return num_q_heads // kv_heads
+
+
+def _repeat_kv(k: Array, v: Array, num_q_heads: int):
+    """Expand grouped K/V heads to match Q heads (GQA/MQA) — for the flash
+    and ring impls; ``dot_attention`` contracts grouped instead."""
+    reps = _group_size(num_q_heads, k.shape[2])
+    if reps == 1:
+        return k, v
+    return jnp.repeat(k, reps, axis=2), jnp.repeat(v, reps, axis=2)
 
 
 def dot_attention(
@@ -56,6 +60,12 @@ def dot_attention(
     """Reference einsum attention. Computes logits in f32 for stability
     regardless of the compute dtype (bf16 inputs stay bf16 on the matmuls —
     MXU native — with an f32 softmax accumulator, XLA's preferred pattern).
+
+    K/V with ``KV`` heads serve ``H = G * KV`` query heads (query head ``h``
+    reads KV head ``h // G``): q is viewed as ``[B, S, KV, G, D]`` and each
+    KV head is contracted against its ``G`` query heads, so K and V are
+    never expanded — a decode step reads the cache once, not ``G`` times.
+    ``G = 1`` is plain multi-head attention through the same code.
 
     ``q_offset`` positions the queries at ``q_offset .. q_offset+S-1``
     within the key axis — the KV-cache decode case, where K/V span the
@@ -86,9 +96,15 @@ def dot_attention(
         raise ValueError(
             f"window={window} requires causal=True and window >= 1"
         )
-    k, v = _repeat_kv(k, v, H)
+    KV = k.shape[2]
+    G = _group_size(H, KV)
     scale = scale if scale is not None else D ** -0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
+    # logits are [B, KV, G, S, K]; every mask below is built without head
+    # axes and broadcasts over (KV, G)
+    logits = jnp.einsum(
+        "bqkgd,btkd->bkgqt", q.reshape(B, S, KV, G, D), k,
+        preferred_element_type=jnp.float32,
+    )
     logits = logits * scale
     neg = jnp.asarray(-0.7 * jnp.finfo(jnp.float32).max, logits.dtype)
     if k_positions is not None:
@@ -104,7 +120,7 @@ def dot_attention(
         mask = (kp >= 0) & (kp <= qp)
         if window is not None:
             mask &= (qp - kp) < window
-        logits = jnp.where(mask[:, None], logits, neg)
+        logits = jnp.where(mask[:, None, None], logits, neg)
     elif causal:
         k_pos = jnp.arange(k.shape[1])
         if q_offset is not None and jnp.ndim(q_offset) == 1:
@@ -113,7 +129,7 @@ def dot_attention(
             mask = q_pos[:, :, None] >= k_pos[None, None, :]
             if window is not None:
                 mask &= (q_pos[:, :, None] - k_pos[None, None, :]) < window
-            logits = jnp.where(mask[:, None], logits, neg)
+            logits = jnp.where(mask[:, None, None], logits, neg)
         else:
             q_pos = jnp.arange(S)[:, None]
             if q_offset is not None:
@@ -121,14 +137,17 @@ def dot_attention(
             mask = q_pos >= k_pos[None, :]
             if window is not None:
                 mask &= (q_pos - k_pos[None, :]) < window
-            logits = jnp.where(mask[None, None], logits, neg)
+            logits = jnp.where(mask, logits, neg)
     if segment_ids is not None:
         seg_mask = segment_ids[:, :, None] == segment_ids[:, None, :]
-        logits = jnp.where(seg_mask[:, None], logits, neg)
+        logits = jnp.where(seg_mask[:, None, None], logits, neg)
     if kv_mask is not None:
-        logits = jnp.where(kv_mask[:, None, None, :].astype(bool), logits, neg)
+        logits = jnp.where(
+            kv_mask[:, None, None, None, :].astype(bool), logits, neg
+        )
     weights = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+    out = jnp.einsum("bkgqt,btkd->bqkgd", weights, v)
+    return out.reshape(B, S, H, D)
 
 
 def attend(

@@ -246,3 +246,60 @@ def test_serving_loop_kv_cache_int8_knob(devices):
         assert rebuilt._model.config.kv_cache_int8
     finally:
         loop.close()
+
+
+@pytest.mark.parametrize("cache", ["bf16", "int8"])
+def test_gqa_per_row_decode_matches_full_forward(devices, cache):
+    """GQA (4 heads over 2 KV heads) through the per-row cache: rows sit
+    at different frontiers, chunks of 3, 2 and 1 tokens, and every
+    position's logits must match the full-sequence forward — the grouped
+    contraction over the whole cache, its per-row causal mask included."""
+    cfg = _cfg("llama", decode_per_row=True)
+    full = TransformerLM(cfg)
+    model = TransformerLM(
+        dataclasses.replace(cfg, kv_cache_int8=cache == "int8")
+    )
+    L = 12
+    toks = jnp.asarray(
+        np.random.default_rng(5).integers(0, 64, size=(2, L)), jnp.int32
+    )
+    params = jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16), _params(full, toks)
+    )
+    want = np.asarray(
+        full.apply({"params": params}, {"tokens": toks})["logits"],
+        np.float32,
+    )
+    state = {"cache": zero_cache(model, params, toks[:, :1])}
+    if cache == "bf16":
+        assert all(
+            leaf.dtype == jnp.bfloat16
+            for leaf in jax.tree_util.tree_leaves(state["cache"])
+            if leaf.ndim == 4
+        )
+    # share of the position's logit range: bf16 differs by summation order
+    # alone (0 on the CPU), int8 K/V add their rounding (0.013 here); a
+    # wrong head grouping reads above 0.5
+    tol = 0.01 if cache == "bf16" else 0.05
+
+    def feed(starts, S):
+        """Each row r decodes toks[r, starts[r] : starts[r] + S]."""
+        pos = jnp.asarray(starts, jnp.int32)[:, None] + jnp.arange(S)
+        out, mut = model.apply(
+            {"params": params, "cache": state["cache"]},
+            {"tokens": jnp.take_along_axis(toks, pos, axis=1),
+             "positions": pos},
+            decode=True, mutable=["cache"],
+        )
+        state["cache"] = mut["cache"]
+        got = np.asarray(out["logits"], np.float32)
+        for r, s in enumerate(starts):
+            np.testing.assert_allclose(
+                got[r], want[r, s:s + S], atol=tol * np.ptp(want[r, s:s + S]),
+                err_msg=f"row {r} positions {s}..{s + S - 1}",
+            )
+
+    feed([0, 0], 3)
+    feed([3, 1], 2)       # row 1 falls behind: it rewrites slots 1-2 in place
+    for i in range(L - 5):
+        feed([5 + i, 3 + i], 1)

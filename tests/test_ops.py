@@ -512,3 +512,180 @@ def test_sliding_window_with_segments_and_gqa(devices):
                           block_q=128, block_k=128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
+
+
+# -- grouped-query dot_attention: K/V contracted per KV head, never repeated --
+
+
+def _repeat_oracle(q, k, v, *, causal=True, q_offset=None, window=None,
+                   k_positions=None, segment_ids=None, kv_mask=None):
+    """Repeat-then-attend, written out without ``dot_attention``: K/V are
+    expanded to the query heads with ``jnp.repeat`` and one [B, S, T] mask
+    is built from explicit positions."""
+    B, S, H, D = q.shape
+    T = k.shape[1]
+    reps = H // k.shape[2]
+    k = jnp.repeat(k, reps, axis=2)
+    v = jnp.repeat(v, reps, axis=2)
+    logits = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) * D ** -0.5
+    mask = jnp.ones((B, S, T), bool)
+    if causal:
+        off = jnp.zeros((), jnp.int32) if q_offset is None else q_offset
+        q_pos = jnp.broadcast_to(jnp.asarray(off), (B,))[:, None] + jnp.arange(S)
+        k_pos = (
+            jnp.broadcast_to(jnp.arange(T), (B, T))
+            if k_positions is None else k_positions
+        )
+        qp, kp = q_pos[:, :, None], k_pos[:, None, :]
+        mask &= (kp >= 0) & (kp <= qp)
+        if window is not None:
+            mask &= (qp - kp) < window
+    if segment_ids is not None:
+        mask &= segment_ids[:, :, None] == segment_ids[:, None, :]
+    if kv_mask is not None:
+        mask &= kv_mask[:, None, :].astype(bool)
+    neg = -0.7 * jnp.finfo(jnp.float32).max
+    logits = jnp.where(mask[:, None], logits, neg)
+    weights = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+def _gqa_case(name):
+    """(S, T, kwargs) of one masking case, for B = 2 rows."""
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    if name == "causal":
+        return 16, 16, {}
+    if name == "scalar_offset":
+        return 4, 24, dict(q_offset=i32(7))
+    if name == "row_offset_s1":
+        return 1, 24, dict(q_offset=i32([3, 20]))
+    if name == "row_offset_s5":
+        return 5, 24, dict(q_offset=i32([0, 17]))
+    if name == "window":
+        return 16, 16, dict(window=5)
+    if name == "rolling":
+        # 12 slots hold positions out of order; row 1 has never-written
+        # (negative) slots and one stale slot ahead of its queries
+        k_pos = i32([[12, 13, 14, 3, 4, 5, 6, 7, 8, 9, 10, 11],
+                     [0, 1, 2, 3, 4, 5, 9, -1, -1, -1, -1, -1]])
+        return 3, 12, dict(q_offset=i32([12, 3]), window=8,
+                           k_positions=k_pos)
+    if name == "segment_ids":
+        seg = i32([[0] * 5 + [1] * 11, [0] * 9 + [1] * 4 + [2] * 3])
+        return 16, 16, dict(segment_ids=seg)
+    if name == "kv_mask":
+        lengths = i32([[4], [10]])
+        return 6, 10, dict(causal=False,
+                           kv_mask=jnp.arange(10)[None, :] < lengths)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [(8, 2), (8, 1), (4, 4)],
+                         ids=["h8kv2", "h8kv1", "h4kv4"])
+@pytest.mark.parametrize("case", [
+    "causal", "scalar_offset", "row_offset_s1", "row_offset_s5", "window",
+    "rolling", "segment_ids", "kv_mask",
+])
+def test_dot_grouped_matches_repeat_oracle(case, heads, dtype):
+    H, KV = heads
+    S, T, kw = _gqa_case(case)
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.normal(size=(2, S, H, 16)), dtype)
+    k = jnp.asarray(rng.normal(size=(2, T, KV, 16)), dtype)
+    v = jnp.asarray(rng.normal(size=(2, T, KV, 16)), dtype)
+    got = dot_attention(q, k, v, **kw)
+    want = _repeat_oracle(q, k, v, **kw)
+    assert got.shape == q.shape and got.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=tol, rtol=tol,
+    )
+
+
+def test_dot_grouped_gradients_match_repeat_oracle():
+    """The ``dot`` fallbacks of flash and ring train through this op."""
+    q, k, v = _qkv(S=32, H=8, D=16, kv_heads=2, seed=11)
+    seg = jnp.asarray([[0] * 20 + [1] * 12, [0] * 32], jnp.int32)
+
+    def loss(fn, q, k, v):
+        return jnp.sum(fn(q, k, v, causal=True, segment_ids=seg) ** 2)
+
+    got = jax.grad(functools.partial(loss, dot_attention), (0, 1, 2))(q, k, v)
+    want = jax.grad(functools.partial(loss, _repeat_oracle), (0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=5e-5, rtol=5e-4,
+            err_msg=f"d{name} mismatch",
+        )
+
+
+def _jaxpr_shapes(jaxpr):
+    """Shapes of every value a jaxpr computes, sub-jaxprs included."""
+    shapes = set()
+    for eqn in jaxpr.eqns:
+        shapes.update(tuple(v.aval.shape) for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            shapes |= _jaxpr_shapes(sub)
+    return shapes
+
+
+def _kv_expanded(shapes, B, T, H, KV, D):
+    """Shapes that hold K or V expanded to the H query heads over all T
+    key slots, in any axis order."""
+    expanded = (sorted((B, T, H, D)), sorted((B, T, KV, H // KV, D)))
+    return [s for s in shapes if sorted(s) in expanded]
+
+
+def test_dot_grouped_never_expands_kv():
+    """Structural: with KV < H nothing of shape [B, T, H, D] (or its
+    [B, T, KV, G, D] view) exists in ``dot_attention``'s jaxpr, nor in a
+    ``decode=True`` step of a GQA model over its cache."""
+    from flax import linen as nn
+
+    from rocket_tpu.models.generate import zero_cache
+    from rocket_tpu.models.transformer import TransformerConfig, TransformerLM
+
+    B, T, H, KV, D = 3, 40, 8, 2, 16
+    q = jnp.zeros((B, 1, H, D), jnp.bfloat16)
+    kv = jnp.zeros((B, T, KV, D), jnp.bfloat16)
+    off = jnp.asarray([3, 10, 30], jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v: dot_attention(q, k, v, q_offset=off)
+    )(q, kv, kv)
+    shapes = _jaxpr_shapes(jaxpr.jaxpr)
+    assert (B, KV, H // KV, 1, T) in shapes      # the grouped logits
+    assert not _kv_expanded(shapes, B, T, H, KV, D)
+
+    cfg = TransformerConfig(
+        vocab_size=64, hidden=H * D, n_layers=2, n_heads=H, n_kv_heads=KV,
+        max_seq=T, attention="dot", decode_per_row=True,
+    )
+    model = TransformerLM(cfg)
+    tok = jnp.zeros((B, 1), jnp.int32)
+    params = nn.meta.unbox(
+        model.init(jax.random.PRNGKey(0), {"tokens": tok})["params"]
+    )
+    cache = zero_cache(model, params, tok)
+
+    def step(params, cache):
+        return model.apply(
+            {"params": params, "cache": cache},
+            {"tokens": tok, "positions": off[:, None]},
+            decode=True, mutable=["cache"],
+        )
+
+    shapes = _jaxpr_shapes(jax.make_jaxpr(step)(params, cache).jaxpr)
+    assert (B, T, KV, D) in shapes               # the cache itself is there
+    assert not _kv_expanded(shapes, B, T, H, KV, D)
+
+
+def test_dot_rejects_ragged_head_groups():
+    q, k, v = _qkv(S=8, H=6, kv_heads=4)
+    with pytest.raises(ValueError, match="not a multiple"):
+        dot_attention(q, k, v)
