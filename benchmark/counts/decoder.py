@@ -1,10 +1,11 @@
-"""Operations and bytes the algorithms need, from shapes alone.
+"""Operations and bytes a dense decoder needs, from shapes alone: the
+``counts`` of ``archs/decoder.py``.
 
 Counted from the configuration and the live lengths, never from the HLO, so
 the same work reads the same whatever later implements it.  A multiply-add
 is two operations.  Causal attention is counted at the half a causal kernel
-needs; recomputation is never counted.  ``arch`` is the normalised
-architecture of :func:`benchmark.harness.arch_of`.
+needs; recomputation is never counted.  ``arch`` is what
+``archs/decoder.py``'s ``normalise`` returns.
 """
 
 from __future__ import annotations
@@ -112,10 +113,3 @@ def serve_flops(arch: Dict, prompt_tokens: float, output_tokens: float,
     attn = arch["layers"] * 4.0 * context_token_products \
         * arch["heads"] * arch["head_dim"]
     return dense + attn
-
-
-def roofline_seconds(cost: Dict, peak: Dict) -> float:
-    """The least time the chip could take: the larger of operations over
-    peak FLOP/s and bytes over peak bytes/s."""
-    return max(cost["flops"] / peak["bf16_flops_per_s"],
-               cost["bytes"] / peak["hbm_bytes_per_s"])

@@ -1,10 +1,13 @@
-"""The harness: driven by data, knows no cell.
+"""The harness: driven by data, knows no cell and no architecture.
 
 ``BENCHMARK.json`` names a cell's configuration and traffic mix; this module
 finds ``configs/<config>.json`` and ``traffic/<mix>.json`` by those names,
-hands the cell to ``kinds/<kind>.py`` (the traffic file's ``kind``), and
-turns what comes back into the result line.  Per-layer metrics are found the
-same way: ``metrics/<metric>.json`` names a module under ``readers/``.
+loads ``archs/<reference>.py`` (the configuration file's ``reference``: the
+sizes, the program's module, the leaves, the plain reference and the counts
+of that family of models), hands the cell to ``kinds/<kind>.py`` (the
+traffic file's ``kind``), and turns what comes back into the result line.
+Per-layer metrics are found the same way: ``metrics/<metric>.json`` names a
+module under ``readers/``.
 
 A later PR adds files and entries; it edits nothing here.
 """
@@ -13,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import hashlib
 import importlib
 import importlib.util
 import json
@@ -48,7 +52,10 @@ class BenchmarkError(RuntimeError):
 
 @dataclasses.dataclass
 class Cell:
-    """One entry of ``workloads`` with its files resolved."""
+    """One entry of ``workloads`` with its files resolved.  ``family`` is
+    the module ``archs/<reference>.py`` of the configuration's architecture
+    and ``arch`` its ``normalise(config)``: the sizes in the benchmark's own
+    keys (``vocab``, ``layers`` ...)."""
 
     name: str
     chips: int
@@ -57,6 +64,7 @@ class Cell:
     config: Dict[str, Any]
     traffic: Dict[str, Any]
     arch: Dict[str, Any]
+    family: Any
     manifest: Dict[str, Any]
     bench_dir: str
 
@@ -105,51 +113,41 @@ def resolve_cell(name: str, manifest: Optional[Dict] = None,
         if not os.path.isfile(path):
             raise BenchmarkError(f"workload {name!r}: no file {path}")
     config = load_json(config_path)
+    family = load_family(config, bench_dir, config_path)
     return Cell(name=name, chips=int(entry["chips"]),
                 config_name=entry["config"], traffic_name=entry["traffic"],
                 config=config, traffic=load_json(traffic_path),
-                arch=arch_of(config), manifest=manifest, bench_dir=bench_dir)
+                arch=family.normalise(config), family=family,
+                manifest=manifest, bench_dir=bench_dir)
+
+FAMILY_NAMES = ("normalise", "draft", "program", "leaf_shapes", "leaf_name",
+                "reference", "counts")
 
 
-def arch_of(config: Dict) -> Dict:
-    """The sizes the counts, the weights and the reference need, from a
-    configuration file written in its source's own keys (GPT-2's
-    ``n_embd`` dialect or the ``hidden_size`` dialect of Llama-like
-    configs).  A key the file lacks is an error."""
-    assumed = config.get("assumed", {})
-    if "n_embd" in config:
-        hidden, heads = config["n_embd"], config["n_head"]
-        arch = dict(
-            hidden=hidden, layers=config["n_layer"], heads=heads,
-            kv_heads=heads, head_dim=hidden // heads,
-            ffn=config.get("n_inner") or 4 * hidden,
-            vocab=config["vocab_size"], max_pos=config["n_positions"],
-            norm="layernorm", mlp="gelu", positions="learned", bias=True,
-            tie=True, window=None, rope_theta=None,
-            eps=config["layer_norm_epsilon"])
-    elif "hidden_size" in config:
-        hidden, heads = config["hidden_size"], config["num_attention_heads"]
-        arch = dict(
-            hidden=hidden, layers=config["num_hidden_layers"], heads=heads,
-            kv_heads=config.get("num_key_value_heads", heads),
-            head_dim=config.get("head_dim") or hidden // heads,
-            ffn=config["intermediate_size"], vocab=config["vocab_size"],
-            max_pos=config["max_position_embeddings"], norm="rmsnorm",
-            mlp="swiglu", positions="rope", bias=False,
-            tie=bool(config.get("tie_word_embeddings", False)),
-            window=config.get("sliding_window"),
-            rope_theta=config["rope_theta"], eps=config["rms_norm_eps"])
-    else:
-        raise BenchmarkError("configuration file is in no dialect arch_of "
-                             "knows (n_embd / hidden_size)")
-    arch["vocab_padded"] = int(assumed.get("vocab_padded_to", arch["vocab"]))
-    return arch
-
-
-def draft_arch(cell_arch: Dict, serving: Dict) -> Dict:
-    """The draft a speculative server needs: the same widths, its own
-    embeddings and head, ``draft_layers`` deep."""
-    return dict(cell_arch, layers=int(serving["draft_layers"]))
+def load_family(config: Dict, bench_dir: str = HERE,
+                config_path: str = "the configuration file"):
+    """``archs/<reference>.py`` of a configuration file: everything that
+    depends on the architecture.  A file without the key, a name with no
+    module, or a module without one of the seven names is an error, never
+    a default."""
+    name = config.get("reference")
+    if not isinstance(name, str) or not name:
+        raise BenchmarkError(
+            f"{config_path} names no architecture: it needs a 'reference' "
+            f"key, the name of a module under archs/")
+    try:
+        family = _load_module(bench_dir, "archs", name)
+    except ModuleNotFoundError as exc:
+        if exc.name != f"benchmark.archs.{name}":
+            raise                   # the module is there; an import of its is not
+        raise BenchmarkError(
+            f"{config_path}: 'reference' is {name!r}, and there is no "
+            f"archs/{name}.py") from exc
+    lacking = [n for n in FAMILY_NAMES if not hasattr(family, n)]
+    if lacking:
+        raise BenchmarkError(
+            f"archs/{name}.py lacks {lacking} of the architecture's names")
+    return family
 
 
 # -- the device ---------------------------------------------------------------
@@ -364,18 +362,35 @@ def read_per_layer(cell: Cell, ctx: Dict) -> Dict[str, Dict]:
 
 
 def _load_module(bench_dir: str, package: str, name: str):
-    """``<bench_dir>/<package>/<name>.py`` as a module: from the benchmark's
-    own package where the directory is the benchmark's, else by path (the
-    tests' toy benchmarks bring files of their own)."""
+    """``<bench_dir>/<package>/<name>.py`` as a module (an architecture, a
+    kind, a reader): from the benchmark's own package where the directory
+    is the benchmark's, else by path (the tests' toy benchmarks bring files
+    of their own) and, failing that, the benchmark's of that name."""
     if os.path.abspath(bench_dir) == HERE:
         return importlib.import_module(f"benchmark.{package}.{name}")
-    path = os.path.join(bench_dir, package, name + ".py")
+    path = os.path.abspath(os.path.join(bench_dir, package, name + ".py"))
     if not os.path.isfile(path):
         return importlib.import_module(f"benchmark.{package}.{name}")
-    spec = importlib.util.spec_from_file_location(
-        f"_bench_{package}_{name}", path)
+    # one module a file and a process, as an import gives: an architecture's
+    # flax module is a static argument of the program's jitted functions.
+    # The alias holds the whole path, so two directories that each bring an
+    # archs/x.py keep a module each; a file rewritten in place loads anew.
+    alias = "_bench_%s_%s_%s" % (
+        package, name, hashlib.sha1(path.encode()).hexdigest()[:12])
+    stat = os.stat(path)
+    stamp = (stat.st_mtime_ns, stat.st_size)
+    module = sys.modules.get(alias)
+    if module is not None and module.__dict__.get("_bench_stamp") == stamp:
+        return module
+    spec = importlib.util.spec_from_file_location(alias, path)
     module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
+    module._bench_stamp = stamp
+    sys.modules[alias] = module      # dataclasses look their module up
+    try:
+        spec.loader.exec_module(module)
+    except BaseException:
+        del sys.modules[alias]
+        raise
     return module
 
 

@@ -32,20 +32,15 @@ SAMPLE = 6          # finished requests compared with the reference a run
 
 def program_models(cell: harness.Cell):
     """Target and draft modules and their abstract bf16 parameter trees."""
-    import dataclasses
-
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
 
-    from rocket_tpu.models.transformer import TransformerLM
-
-    serving = cell.config["serving"]
+    family, serving = cell.family, cell.config["serving"]
     max_seq = int(serving["total_len"]) + int(serving["n_draft"])
-    cfg = train_kind.program_config(cell.arch, {"max_seq": max_seq})
-    draft_cfg = dataclasses.replace(
-        cfg, n_layers=int(serving["draft_layers"]))
-    model, draft = TransformerLM(cfg), TransformerLM(draft_cfg)
+    model = family.program(cell.arch, max_seq=max_seq, attention="auto")
+    draft = family.program(family.draft(cell.arch, serving),
+                           max_seq=max_seq, attention="auto")
     dtype = jnp.dtype(serving["weights_dtype"])
 
     def abstract(m):
@@ -61,10 +56,11 @@ def program_models(cell: harness.Cell):
 def make_params(cell: harness.Cell, seed: int, abstract_t, abstract_d):
     """Both parameter trees on the device from the seed's key, one jitted
     call a group (a layer), in the type they are served in."""
-    key = weights.base_key(seed)
-    d_arch = harness.draft_arch(cell.arch, cell.config["serving"])
-    return (train_kind.fill_tree(abstract_t, cell.arch, key),
-            train_kind.fill_tree(abstract_d, d_arch, key, prefix="draft."))
+    key, family = weights.base_key(seed), cell.family
+    d_arch = family.draft(cell.arch, cell.config["serving"])
+    return (train_kind.fill_tree(abstract_t, family, cell.arch, key),
+            train_kind.fill_tree(abstract_d, family, d_arch, key,
+                                 prefix="draft."))
 
 
 class Session:
@@ -373,12 +369,10 @@ def served_gaps(cell: harness.Cell, seed: int, sample: List[Dict],
     import jax
     import jax.numpy as jnp
 
-    from benchmark.reference import decoder
-
-    arch = cell.arch
+    arch, reference = cell.arch, cell.family.reference
     dtype = jnp.dtype(cell.config["serving"]["weights_dtype"])
     key = weights.base_key(seed)
-    shapes = weights.groups(arch)
+    shapes = weights.groups(cell.family.leaf_shapes(arch))
 
     def get(group):
         # the weights as served: rounded to the serving type
@@ -394,12 +388,12 @@ def served_gaps(cell: harness.Cell, seed: int, sample: List[Dict],
         count = len(tokens) - first
         if count < 1:
             continue
-        ref = decoder.served_logits(arch, prec, get, tokens, first, count,
-                                    **shape)
+        ref = reference.served_logits(arch, prec, get, tokens, first, count,
+                                      **shape)
         judged = jnp.asarray(tokens[first:])
         if altered is not None:
-            low = decoder.served_logits(arch, altered, get, tokens, first,
-                                        count, **shape)
+            low = reference.served_logits(arch, altered, get, tokens,
+                                          first, count, **shape)
             judged = jnp.argmax(low, axis=-1)
         best = jnp.max(ref, axis=-1)
         got = jnp.take_along_axis(ref, judged[:, None], axis=-1)[:, 0]

@@ -19,7 +19,7 @@ from __future__ import annotations
 import gc
 import shutil
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -28,60 +28,30 @@ from benchmark import harness, traffic, weights
 CHECK_STEPS = 3
 
 
-def program_config(arch: Dict, mix: Dict):
-    """The program's ``TransformerConfig`` for this architecture."""
-    from rocket_tpu.models.transformer import TransformerConfig
-
-    return TransformerConfig(
-        vocab_size=arch["vocab_padded"], hidden=arch["hidden"],
-        n_layers=arch["layers"], n_heads=arch["heads"],
-        n_kv_heads=arch["kv_heads"], ffn_dim=arch["ffn"],
-        max_seq=int(mix.get("max_seq", arch["max_pos"])), norm=arch["norm"],
-        mlp=arch["mlp"], positions=arch["positions"],
-        rope_theta=arch["rope_theta"] or 10000.0,
-        tie_embeddings=arch["tie"], use_bias=arch["bias"],
-        norm_eps=arch["eps"], attention=mix.get("attention", "auto"),
-        attention_window=arch["window"])
+def program(cell: harness.Cell, attention: Optional[str] = None):
+    """The program's module for the cell's architecture at the training
+    mix's sizes; ``attention`` overrides the mix's choice of kernel."""
+    mix = cell.traffic
+    return cell.family.program(
+        cell.arch, max_seq=int(mix.get("max_seq", cell.arch["max_pos"])),
+        attention=attention or mix.get("attention", "auto"))
 
 
-def leaf_name(path) -> str:
-    """The benchmark's name for a leaf of the program's parameter tree:
-    ``block_3/attn/q/kernel`` -> ``L3.q.w``."""
-    keys = [str(getattr(k, "key", getattr(k, "name", k))) for k in path]
-    keys = [k for k in keys if k != "value"
-            and not k.startswith(("LayerNorm_", "RMSNorm_"))]
-    kind = {"kernel": "w", "bias": "b", "scale": "scale"}
-    if keys[0] == "embed":
-        return "embed"
-    if keys[0] == "pos_embedding":
-        return "pos"
-    if keys[0] == "head":
-        return "head"
-    if keys[0] == "ln_f":
-        return "lnf." + ("bias" if keys[-1] == "bias" else "scale")
-    if keys[0].startswith("block_"):
-        layer = f"L{keys[0][len('block_'):]}"
-        if keys[1] in ("ln1", "ln2"):
-            return f"{layer}.{keys[1]}." + (
-                "bias" if keys[-1] == "bias" else "scale")
-        return f"{layer}.{keys[2]}.{kind[keys[-1]]}"
-    raise harness.BenchmarkError(f"no name for program leaf {keys}")
-
-
-def fill_tree(tree: Any, arch: Dict, key: Any, prefix: str = "",
-              dtype=None) -> Any:
+def fill_tree(tree: Any, family: Any, arch: Dict, key: Any,
+              prefix: str = "", dtype=None) -> Any:
     """The program's tree with every leaf replaced by the benchmark's leaf
     of that name, made on the device one group a call; a leaf the benchmark
-    does not know, or of another shape, is an error.  ``tree`` may hold
-    arrays or ``ShapeDtypeStruct``s."""
+    does not know, or of another shape, is an error.  ``family`` is the
+    architecture's module (its ``leaf_shapes`` and ``leaf_name``); ``tree``
+    may hold arrays or ``ShapeDtypeStruct``s."""
     import jax
 
     first = jax.tree_util.tree_leaves(tree)[0]
-    leaves = weights.all_leaves(key, arch, prefix,
-                                str(dtype or first.dtype))
+    leaves = weights.all_leaves(key, family.leaf_shapes(arch, prefix),
+                                prefix, str(dtype or first.dtype))
 
     def one(path, old):
-        name = prefix + leaf_name(path)
+        name = prefix + family.leaf_name(path)
         if name not in leaves or tuple(old.shape) != leaves[name].shape:
             raise harness.BenchmarkError(
                 f"program leaf {name} {tuple(old.shape)} is not the "
@@ -91,11 +61,11 @@ def fill_tree(tree: Any, arch: Dict, key: Any, prefix: str = "",
     return jax.tree_util.tree_map_with_path(one, tree)
 
 
-def named_leaves(tree: Any, prefix: str = "") -> Dict[str, Any]:
+def named_leaves(tree: Any, family: Any) -> Dict[str, Any]:
     import jax
 
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {prefix + leaf_name(path): leaf for path, leaf in flat}
+    return {family.leaf_name(path): leaf for path, leaf in flat}
 
 
 def _adam_mu(opt_state: Any):
@@ -118,7 +88,6 @@ def build(cell: harness.Cell, seed: int, window: "Window"):
 
     import rocket_tpu as rt
     from rocket_tpu.models.objectives import lm_cross_entropy
-    from rocket_tpu.models.transformer import TransformerLM
     from rocket_tpu.parallel.mesh import MeshSpec
 
     harness.log("program imported")
@@ -133,7 +102,7 @@ def build(cell: harness.Cell, seed: int, window: "Window"):
         warmup_steps=opt["warmup_steps"], decay_steps=opt["decay_steps"],
         end_value=opt["lr_end"])
     module = rt.Module(
-        TransformerLM(program_config(arch, mix)),
+        program(cell),
         capsules=[
             rt.Loss(lm_cross_entropy(), name="lm"),
             rt.Optimizer(tx_factory=optax.adamw, learning_rate=opt["lr_peak"],
@@ -200,8 +169,8 @@ def _window_class():
             super().setup(attrs)
             harness.log("trainer set up; making the seed's weights")
             state = self.module.state
-            made = fill_tree(state.params, self.cell.arch,
-                             weights.base_key(self.seed))
+            made = fill_tree(state.params, self.cell.family,
+                             self.cell.arch, weights.base_key(self.seed))
             # placed exactly as the program's own init places them, so the
             # step sees one signature (and compiles once), not one for the
             # first call and another for the outputs it then feeds back
@@ -267,15 +236,17 @@ def _window_class():
                     t))(_adam_mu(state.opt_state))
                 self.first_grad = {
                     k: float(v) / (1.0 - opt["b1"])
-                    for k, v in named_leaves(norms).items()}
+                    for k, v in named_leaves(
+                        norms, self.cell.family).items()}
             if i == CHECK_STEPS:
-                start = fill_tree(state.params, self.cell.arch,
+                start = fill_tree(state.params, self.cell.family,
+                                  self.cell.arch,
                                   weights.base_key(self.seed))
                 change = jax.jit(lambda now, then: jax.tree_util.tree_map(
                     lambda a, b: jnp.linalg.norm(
                         (a.astype(jnp.float32) - b).ravel()), now, then))
                 self.change = {k: float(v) for k, v in named_leaves(
-                    change(state.params, start)).items()}
+                    change(state.params, start), self.cell.family).items()}
                 del start
 
     return Window
@@ -320,7 +291,8 @@ def drive(cell: harness.Cell, *, seed: int, seconds: float, compiles,
 
 
 def reference_params(cell: harness.Cell, seed: int) -> Dict:
-    return weights.all_leaves(weights.base_key(seed), cell.arch)
+    return weights.all_leaves(weights.base_key(seed),
+                              cell.family.leaf_shapes(cell.arch))
 
 
 def gaps(recorded: Dict, ref: Dict) -> Dict[str, float]:
@@ -349,12 +321,11 @@ def gaps(recorded: Dict, ref: Dict) -> Dict[str, float]:
 
 
 def check(cell: harness.Cell, *, seed: int, run: Dict) -> Dict:
-    from benchmark.reference import decoder
-
     rec = run["recorded"]
     params = reference_params(cell, seed)
-    ref = decoder.train_steps(cell.arch, cell.traffic["optimizer"], params,
-                              rec["batches"][:CHECK_STEPS])
+    ref = cell.family.reference.train_steps(
+        cell.arch, cell.traffic["optimizer"], params,
+        rec["batches"][:CHECK_STEPS])
     del params
     gc.collect()
     return harness.judge(cell, gaps(rec, ref))

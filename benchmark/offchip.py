@@ -71,13 +71,11 @@ def compile_train_step(cell: harness.Cell, device, batch: int = None):
     from rocket_tpu.engine.state import TrainState
     from rocket_tpu.engine.step import Objective, build_train_step
     from rocket_tpu.models.objectives import lm_cross_entropy
-    from rocket_tpu.models.transformer import TransformerLM
 
-    mix, arch = cell.traffic, cell.arch
+    mix = cell.traffic
     opt = mix["optimizer"]
     batch = int(batch or mix["batch"])
-    cfg = train_kind.program_config(arch, dict(mix, attention="flash"))
-    adapter = FlaxModel(TransformerLM(cfg))
+    adapter = FlaxModel(train_kind.program(cell, attention="flash"))
     policy = Policy.from_string(mix["mixed_precision"])
     tx = optax.chain(
         optax.clip_by_global_norm(opt["clip_norm"]),

@@ -9,12 +9,14 @@ MOSAIC = r"tpu_custom_call"
 
 
 def read(ctx):
-    trace, peaks, mix = ctx["trace"], ctx["peaks"], ctx["cell"].traffic
+    trace, peaks, cell = ctx["trace"], ctx["peaks"], ctx["cell"]
     spent = sum(trace.op_seconds(MOSAIC))
     name = find_program(trace, "dominant")
-    if peaks is None or name is None or spent <= 0:
+    kernel_cost = getattr(cell.family.counts, "flash_kernel_cost", None)
+    if peaks is None or name is None or spent <= 0 or kernel_cost is None:
         return None
     steps = len(trace.program_seconds(name))
-    cost = counts.flash_kernel_cost(ctx["cell"].arch, mix["batch"], mix["seq"])
+    mix = cell.traffic
+    cost = kernel_cost(cell.arch, mix["batch"], mix["seq"])
     least = sum(counts.roofline_seconds(c, peaks) for c in cost.values())
     return 100.0 * least * steps / spent

@@ -1,5 +1,5 @@
 """One flash kernel's share of its roofline, in percent:
-``counts.flash_kernel_cost``'s own entry for it (``fwd``, ``dq`` or
+the architecture's ``flash_kernel_cost`` entry for it (``fwd``, ``dq`` or
 ``dkv``: max(FLOPs / peak, bytes / bandwidth) over all layers of a step)
 over the device time of the Mosaic calls of that name.  The name is the
 ``name=`` of the kernel's ``pl.pallas_call``, which XLA puts into the
@@ -14,8 +14,9 @@ from benchmark.trace_reduce import OPS
 
 
 def read(ctx, kernel, name):
-    trace, peaks, mix = ctx["trace"], ctx["peaks"], ctx["cell"].traffic
-    if peaks is None or not trace.planes:
+    trace, peaks, cell = ctx["trace"], ctx["peaks"], ctx["cell"]
+    kernel_cost = getattr(cell.family.counts, "flash_kernel_cost", None)
+    if peaks is None or not trace.planes or kernel_cost is None:
         return None
     rx = re.compile(r"^%?[\w.\-]*" + re.escape(name) + r"[\w.\-]* = ")
     spent = sum(e[4] / 1e9 for e in trace.events
@@ -25,6 +26,6 @@ def read(ctx, kernel, name):
     if step is None or spent <= 0:
         return None
     steps = len(trace.program_seconds(step))
-    cost = counts.flash_kernel_cost(ctx["cell"].arch, mix["batch"],
-                                    mix["seq"])[kernel]
+    mix = cell.traffic
+    cost = kernel_cost(cell.arch, mix["batch"], mix["seq"])[kernel]
     return 100.0 * counts.roofline_seconds(cost, peaks) * steps / spent

@@ -91,13 +91,13 @@ def train_controls(cell, seed):
 
 def train_readings(cell, seed, run, control, program=True):
     from benchmark.kinds import train
-    from benchmark.reference import decoder
 
+    reference = cell.family.reference
     rec = run["recorded"]
     opt = cell.traffic["optimizer"]
     batches = rec["batches"][:train.CHECK_STEPS]
     params = train.reference_params(cell, seed)
-    ref = decoder.train_steps(cell.arch, opt, params, batches)
+    ref = reference.train_steps(cell.arch, opt, params, batches)
     out = {"reference_losses": ref["losses"]}
     if program:
         out["program"] = train.gaps(rec, ref)
@@ -107,7 +107,8 @@ def train_readings(cell, seed, run, control, program=True):
         for name, kw in (("control_fp8", {"prec": "fp8"}),
                          ("control_bf16", {"prec": "bf16"}),
                          ("fault_half_batch", {"rows": rows})):
-            got = decoder.train_steps(cell.arch, opt, params, batches, **kw)
+            got = reference.train_steps(cell.arch, opt, params, batches,
+                                        **kw)
             out[name] = train.gaps(got, ref)
     return out
 

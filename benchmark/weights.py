@@ -4,8 +4,9 @@ Every leaf is a pure function of ``(base_key(seed), its group, its place in
 the group)``: the program's tree is filled from these inside one jitted call,
 and the plain reference asks for the same leaves one group (one layer) at a
 time, so neither side ever takes numbers the other has made.  Names are the benchmark's own
-(``L3.q.w``, ``embed``, ``draft.L0.up.w`` ...); ``arch`` is the normalised
-architecture of :func:`benchmark.harness.arch_of`.
+(``L3.q.w``, ``embed``, ``draft.L0.up.w`` ...), and which leaves a model has
+is its architecture's to say: ``shapes`` is the name -> shape of
+``cell.family.leaf_shapes(arch, prefix)``, in its order.
 """
 
 from __future__ import annotations
@@ -35,10 +36,11 @@ def group_of(name: str, prefix: str = "") -> str:
     return prefix + (short.split(".", 1)[0] if short.startswith("L") else "top")
 
 
-def groups(arch: Dict, prefix: str = "") -> Dict[str, Dict[str, Tuple[int, ...]]]:
-    """Group -> (leaf name -> shape), in a fixed order."""
+def groups(shapes: Dict[str, Tuple[int, ...]],
+           prefix: str = "") -> Dict[str, Dict[str, Tuple[int, ...]]]:
+    """Group -> (leaf name -> shape), in the order of ``shapes``."""
     out: Dict[str, Dict[str, Tuple[int, ...]]] = {}
-    for name, shape in leaf_shapes(arch, prefix).items():
+    for name, shape in shapes.items():
         out.setdefault(group_of(name, prefix), {})[name] = shape
     return out
 
@@ -103,51 +105,12 @@ def release() -> None:
     jax.clear_caches()
 
 
-def all_leaves(key: jax.Array, arch: Dict, prefix: str = "",
-               dtype: str = "float32") -> Dict[str, jax.Array]:
+def all_leaves(key: jax.Array, shapes: Dict[str, Tuple[int, ...]],
+               prefix: str = "", dtype: str = "float32"
+               ) -> Dict[str, jax.Array]:
     """Every leaf of a model, one jitted call a group."""
     out: Dict[str, jax.Array] = {}
-    for group, shapes in groups(arch, prefix).items():
-        out.update(make_group(key, group, shapes, dtype))
+    for group, members in groups(shapes, prefix).items():
+        out.update(make_group(key, group, members, dtype))
     return out
 
-
-def leaf_shapes(arch: Dict, prefix: str = "") -> Dict[str, Tuple[int, ...]]:
-    """Name -> shape of every leaf of a decoder of this architecture."""
-    H, F, V = arch["hidden"], arch["ffn"], arch["vocab_padded"]
-    q_dim = arch["heads"] * arch["head_dim"]
-    kv_dim = arch["kv_heads"] * arch["head_dim"]
-    bias, layernorm = arch["bias"], arch["norm"] == "layernorm"
-    shapes: Dict[str, Tuple[int, ...]] = {"embed": (V, H)}
-    if arch["positions"] == "learned":
-        shapes["pos"] = (arch["max_pos"], H)
-
-    def norm(name):
-        shapes[f"{name}.scale"] = (H,)
-        if layernorm and bias:
-            shapes[f"{name}.bias"] = (H,)
-
-    def dense(name, d_in, d_out, with_bias):
-        shapes[f"{name}.w"] = (d_in, d_out)
-        if with_bias:
-            shapes[f"{name}.b"] = (d_out,)
-
-    for i in range(arch["layers"]):
-        L = f"L{i}"
-        norm(f"{L}.ln1")
-        dense(f"{L}.q", H, q_dim, bias)
-        dense(f"{L}.k", H, kv_dim, bias)
-        dense(f"{L}.v", H, kv_dim, bias)
-        dense(f"{L}.o", q_dim, H, bias)
-        norm(f"{L}.ln2")
-        if arch["mlp"] == "swiglu":
-            dense(f"{L}.gate", H, F, False)
-            dense(f"{L}.up", H, F, False)
-            dense(f"{L}.down", F, H, False)
-        else:
-            dense(f"{L}.up", H, F, bias)
-            dense(f"{L}.down", F, H, bias)
-    norm("lnf")
-    if not arch["tie"]:
-        shapes["head"] = (H, V)
-    return {prefix + k: v for k, v in shapes.items()}

@@ -1,19 +1,23 @@
-"""``counts.py`` against FLOPs and bytes worked out by hand for both
-configurations, and the peaks table."""
+"""The decoder architecture's ``counts`` against FLOPs and bytes worked out
+by hand for both configurations, and the peaks table."""
 
 import json
 import os
 
 import pytest
 
-from benchmark import counts, harness
+from benchmark import harness
+from benchmark.archs import decoder as family
+from benchmark.counts import roofline_seconds
+
+counts = family.counts
 
 BENCH = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark")
 
 
 def arch(name):
     with open(os.path.join(BENCH, "configs", name + ".json")) as fh:
-        return harness.arch_of(json.load(fh))
+        return family.normalise(json.load(fh))
 
 
 def test_gpt2_medium_parameters_by_hand():
@@ -65,7 +69,7 @@ def test_mistral_l8_parameters_and_cache_by_hand():
 
 def test_decode_round_cost_by_hand():
     a = arch("mistral-7b-l8")
-    d = harness.draft_arch(a, {"draft_layers": 2})
+    d = family.draft(a, {"draft_layers": 2})
     live = 24 * 1000.0
     cost = counts.decode_round_cost(a, d, n_draft=4, live_tokens=live,
                                     rows=24)
@@ -81,7 +85,7 @@ def test_decode_round_cost_by_hand():
     # bandwidth bounds a decode round: several GB against a few TFLOP
     assert cost["bytes"] / peak["hbm_bytes_per_s"] \
         > cost["flops"] / peak["bf16_flops_per_s"]
-    assert counts.roofline_seconds(cost, peak) == pytest.approx(
+    assert roofline_seconds(cost, peak) == pytest.approx(
         cost["bytes"] / 819e9)
 
 

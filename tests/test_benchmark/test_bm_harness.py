@@ -143,7 +143,12 @@ def _wrap_train_step(monkeypatch, wrap):
     monkeypatch.setattr(module, "build_train_step", patched)
 
 
-def test_fault_state_returned_unchanged_is_not_correct(monkeypatch):
+TRAIN_CELLS = ["toy-train", "mix-train"]      # one of each architecture
+CLOSED_CELLS = ["toy-closed", "mix-closed"]
+
+
+@pytest.mark.parametrize("name", TRAIN_CELLS)
+def test_fault_state_returned_unchanged_is_not_correct(monkeypatch, name):
     def wrap(step):
         def broken(state, batch, *rest):
             _, logs = step(state, batch, *rest)
@@ -151,7 +156,7 @@ def test_fault_state_returned_unchanged_is_not_correct(monkeypatch):
         return broken
 
     _wrap_train_step(monkeypatch, wrap)
-    result = run("toy-train", seed=21)
+    result = run(name, seed=21)
     assert result["correct"] is False
     over = {k for k, p in result["compared"].items()
             if p["value"] > p["limit"]}
@@ -160,7 +165,8 @@ def test_fault_state_returned_unchanged_is_not_correct(monkeypatch):
     assert result["compared"]["grad_gap"]["value"] == pytest.approx(1.0)
 
 
-def test_fault_half_of_the_batch_left_out_is_not_correct(monkeypatch):
+@pytest.mark.parametrize("name", TRAIN_CELLS)
+def test_fault_half_of_the_batch_left_out_is_not_correct(monkeypatch, name):
     import jax
 
     def wrap(step):
@@ -171,11 +177,12 @@ def test_fault_half_of_the_batch_left_out_is_not_correct(monkeypatch):
         return broken
 
     _wrap_train_step(monkeypatch, wrap)
-    result = run("toy-train", seed=22)
+    result = run(name, seed=22)
     assert result["correct"] is False
 
 
-def test_fault_an_altered_token_is_not_correct(monkeypatch):
+@pytest.mark.parametrize("name", CLOSED_CELLS)
+def test_fault_an_altered_token_is_not_correct(monkeypatch, name):
     from rocket_tpu.models.generate import ContinuousBatcher
 
     original = ContinuousBatcher.step
@@ -192,7 +199,7 @@ def test_fault_an_altered_token_is_not_correct(monkeypatch):
         return out
 
     monkeypatch.setattr(ContinuousBatcher, "step", broken)
-    result = run("toy-closed", seed=23)
+    result = run(name, seed=23)
     assert result["correct"] is False
     assert result["compared"]["served_gap"]["value"] > 1.0
 
@@ -200,12 +207,13 @@ def test_fault_an_altered_token_is_not_correct(monkeypatch):
 # -- controls: the reference in the program's place, a precision lower -------
 
 
-def test_control_served_tokens_of_a_lower_precision_fail_the_limit():
+@pytest.mark.parametrize("name", CLOSED_CELLS)
+def test_control_served_tokens_of_a_lower_precision_fail_the_limit(name):
     from benchmark.kinds import serving
 
-    cell = toy_cell("toy-closed")
+    cell = toy_cell(name)
     limit = harness.load_json(os.path.join(
-        TOY, "limits", "toy-closed.json"))["served_gap"]
+        TOY, "limits", name + ".json"))["served_gap"]
     rng = np.random.default_rng(0)
     sample = []
     for _ in range(4):
@@ -220,12 +228,14 @@ def test_control_served_tokens_of_a_lower_precision_fail_the_limit():
     assert low["served_gap"] > 0.0
 
 
-def test_control_training_in_a_lower_precision_reads_wider_than_the_program():
+@pytest.mark.parametrize("name", TRAIN_CELLS)
+def test_control_training_in_a_lower_precision_reads_wider_than_the_program(
+        name):
     from benchmark.kinds import train
-    from benchmark.reference import decoder
     from benchmark import traffic
 
-    cell = toy_cell("toy-train")
+    cell = toy_cell(name)
+    decoder = cell.family.reference
     opt = cell.traffic["optimizer"]
     tokens = traffic.markov_tokens(12, 32, cell.arch["vocab"], 3)
     batches = [tokens[0:4], tokens[4:8], tokens[8:12]]

@@ -112,6 +112,75 @@ def test_every_cell_resolves_its_files_by_name(manifest):
         assert data["reduced"] == c["reduced"]
 
 
+def test_every_configuration_names_an_architecture_with_the_seven_names(
+        manifest):
+    """The contract has one definition, ``harness.FAMILY_NAMES``, which
+    ``load_family`` holds every module to.  What ``reference`` and ``counts``
+    hold is for the cell's kind and its listed readers to find."""
+    assert len(harness.FAMILY_NAMES) == 7
+    for c in manifest["configs"]:
+        config = harness.load_json(os.path.join(ROOT, c["file"]))
+        family = harness.load_family(config)
+        assert family.__name__ == "benchmark.archs." + config["reference"]
+        assert os.path.isfile(os.path.join(
+            ROOT, "benchmark", "archs", config["reference"] + ".py"))
+        arch = family.normalise(config)
+        assert arch["vocab"] <= arch["vocab_padded"] and arch["layers"] >= 1
+
+
+@pytest.mark.parametrize("reference,match", [
+    (None, "needs a 'reference' key"),
+    ("", "needs a 'reference' key"),
+    ("no-such-architecture", "no archs/no-such-architecture.py"),
+])
+def test_a_configuration_without_an_architecture_is_an_error(
+        manifest, tmp_path, reference, match):
+    entry = manifest["configs"][0]
+    config = harness.load_json(os.path.join(ROOT, entry["file"]))
+    config.pop("reference")
+    if reference is not None:
+        config["reference"] = reference
+    with pytest.raises(harness.BenchmarkError, match=match):
+        harness.load_family(config)
+    # and through the cell: the file as the manifest names it
+    path = tmp_path / "config.json"
+    path.write_text(json.dumps(config))
+    broken = dict(manifest, configs=[dict(entry, file=str(path))])
+    cell = next(w["name"] for w in manifest["workloads"]
+                if w["config"] == entry["name"])
+    with pytest.raises(harness.BenchmarkError, match=match):
+        harness.resolve_cell(cell, broken)
+
+
+def test_an_architecture_lacking_a_name_is_an_error(tmp_path):
+    (tmp_path / "archs").mkdir()
+    (tmp_path / "archs" / "half.py").write_text(
+        "def normalise(config):\n    return {}\n")
+    with pytest.raises(harness.BenchmarkError, match="lacks .*'counts'"):
+        harness.load_family({"reference": "half"}, bench_dir=str(tmp_path))
+
+
+def test_a_path_loaded_module_is_one_a_file_not_one_a_name(tmp_path):
+    """Two benchmark directories that each bring an ``archs/x.py`` keep a
+    module each, a second load of either is the same object (a flax module
+    of it is a static jit argument), and a file rewritten in place is
+    loaded anew."""
+    body = "\n".join(f"{n} = {i}" for i, n in
+                     enumerate(harness.FAMILY_NAMES)) + "\nWHO = {!r}\n"
+    dirs = []
+    for who in ("one", "two"):
+        d = tmp_path / who
+        (d / "archs").mkdir(parents=True)
+        (d / "archs" / "x.py").write_text(body.format(who))
+        dirs.append(str(d))
+    load = lambda d: harness.load_family({"reference": "x"}, bench_dir=d)  # noqa: E731
+    one, two = load(dirs[0]), load(dirs[1])
+    assert (one.WHO, two.WHO) == ("one", "two")
+    assert load(dirs[0]) is one and load(dirs[1]) is two
+    (tmp_path / "one" / "archs" / "x.py").write_text(body.format("again"))
+    assert load(dirs[0]).WHO == "again" and load(dirs[1]) is two
+
+
 def test_every_per_layer_metric_has_its_file_and_reader(manifest):
     e2e = {m["name"] for m in manifest["end_to_end"]}
     known = set(cells(manifest))

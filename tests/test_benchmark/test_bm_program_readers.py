@@ -12,6 +12,7 @@ import sys
 import pytest
 
 from benchmark import harness, trace_reduce
+from benchmark.archs import decoder
 from benchmark.readers import (_program, compile_cache_count, goodput_share,
                                host_gap_p50, idle_unattributed,
                                kernel_roofline, span_ratio, startup_seconds)
@@ -141,6 +142,7 @@ def test_fetches_per_round():
 
 
 class _Cell:
+    family = decoder
     arch = {"heads": 16, "head_dim": 64, "layers": 24}
     traffic = {"batch": 8, "seq": 1024}
 
@@ -164,7 +166,7 @@ def test_kernel_roofline_tells_the_three_kernels_apart():
     from benchmark import counts
 
     ctx = ctx_of(kernel_events(1e5), cell=_Cell)
-    cost = counts.flash_kernel_cost(_Cell.arch, 8, 1024)
+    cost = _Cell.family.counts.flash_kernel_cost(_Cell.arch, 8, 1024)
     got = {k: kernel_roofline.read(ctx, k, f"flash_{k}")
            for k in ("fwd", "dq", "dkv")}
     for i, k in enumerate(("fwd", "dq", "dkv")):

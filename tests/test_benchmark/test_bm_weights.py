@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import harness, weights
+from benchmark import weights
+from benchmark.archs import decoder as family
 from benchmark.reference import decoder
 
 TOY = os.path.join(os.path.dirname(__file__), "toy", "configs")
@@ -17,33 +18,38 @@ TOY = os.path.join(os.path.dirname(__file__), "toy", "configs")
 
 def arch(name):
     with open(os.path.join(TOY, name + ".json")) as fh:
-        return harness.arch_of(json.load(fh))
+        return family.normalise(json.load(fh))
+
+
+def leaves(seed, a, prefix="", dtype="float32"):
+    return weights.all_leaves(weights.base_key(seed),
+                              family.leaf_shapes(a, prefix), prefix, dtype)
 
 
 @pytest.mark.parametrize("seed", [0, 7, 2 ** 31 + 5, 2 ** 32 + 1])
 def test_same_seed_same_leaves_and_large_seeds_are_keys(seed):
-    a = weights.all_leaves(weights.base_key(seed), arch("toy-gpt2"))
-    b = weights.all_leaves(weights.base_key(seed), arch("toy-gpt2"))
-    assert set(a) == set(weights.leaf_shapes(arch("toy-gpt2")))
+    a = leaves(seed, arch("toy-gpt2"))
+    b = leaves(seed, arch("toy-gpt2"))
+    assert set(a) == set(family.leaf_shapes(arch("toy-gpt2")))
     for name in a:
         assert (np.asarray(a[name]) == np.asarray(b[name])).all()
 
 
 def test_other_seed_other_leaves_and_layers_differ():
-    a = weights.all_leaves(weights.base_key(1), arch("toy-gpt2"))
-    b = weights.all_leaves(weights.base_key(2), arch("toy-gpt2"))
+    a = leaves(1, arch("toy-gpt2"))
+    b = leaves(2, arch("toy-gpt2"))
     assert not np.allclose(a["L0.q.w"], b["L0.q.w"])
     assert not np.allclose(a["L0.q.w"], a["L1.q.w"])
     assert 2 ** 31 != 0 and not np.allclose(
-        weights.all_leaves(weights.base_key(2 ** 31), arch("toy-gpt2"))["embed"],
-        weights.all_leaves(weights.base_key(0), arch("toy-gpt2"))["embed"])
+        leaves(2 ** 31, arch("toy-gpt2"))["embed"],
+        leaves(0, arch("toy-gpt2"))["embed"])
 
 
 def test_one_group_alone_is_the_same_as_in_the_whole():
     a = arch("toy-mistral")
     key = weights.base_key(11)
-    whole = weights.all_leaves(key, a, dtype="bfloat16")
-    shapes = weights.groups(a)
+    whole = leaves(11, a, dtype="bfloat16")
+    shapes = weights.groups(family.leaf_shapes(a))
     assert list(shapes) == ["top", "L0", "L1"]
     alone = weights.make_group(key, "L1", shapes["L1"], "bfloat16")
     assert set(alone) == {k for k in whole if k.startswith("L1.")}
@@ -53,7 +59,7 @@ def test_one_group_alone_is_the_same_as_in_the_whole():
 
 
 def test_scales_sit_near_one_and_the_rest_near_nought():
-    a = weights.all_leaves(weights.base_key(3), arch("toy-gpt2"))
+    a = leaves(3, arch("toy-gpt2"))
     assert abs(float(a["lnf.scale"].mean()) - 1.0) < 0.02
     assert abs(float(a["L0.up.w"].mean())) < 0.005
     assert 0.015 < float(a["L0.up.w"].std()) < 0.025
@@ -62,10 +68,9 @@ def test_scales_sit_near_one_and_the_rest_near_nought():
 
 def test_draft_has_leaves_of_its_own():
     a = arch("toy-mistral")
-    d = harness.draft_arch(a, {"draft_layers": 1})
-    key = weights.base_key(5)
-    target = weights.all_leaves(key, a)
-    draft = weights.all_leaves(key, d, prefix="draft.")
+    d = family.draft(a, {"draft_layers": 1})
+    target = leaves(5, a)
+    draft = leaves(5, d, prefix="draft.")
     assert set(draft) == {"draft." + k for k in target
                           if not k.startswith("L1.")}
     assert not np.allclose(draft["draft.embed"], target["embed"])
@@ -73,7 +78,7 @@ def test_draft_has_leaves_of_its_own():
 
 def test_leaf_shapes_of_both_dialects():
     g, m = arch("toy-gpt2"), arch("toy-mistral")
-    sg, sm = weights.leaf_shapes(g), weights.leaf_shapes(m)
+    sg, sm = family.leaf_shapes(g), family.leaf_shapes(m)
     assert sg["embed"] == (256, 64) and sg["pos"] == (32, 64)
     assert "head" not in sg and sg["L0.up.b"] == (256,)
     assert sm["head"] == (64, 256) and "pos" not in sm
@@ -86,8 +91,7 @@ def test_leaf_shapes_of_both_dialects():
 
 def test_reference_is_causal_and_windowed():
     a = arch("toy-mistral")
-    key = weights.base_key(9)
-    w = weights.all_leaves(key, a)
+    w = leaves(9, a)
     rng = np.random.default_rng(0)
     tokens = rng.integers(0, 256, 64).astype(np.int32)
     get = lambda g: {k: v for k, v in w.items()  # noqa: E731
