@@ -89,7 +89,29 @@ def _sample(logits: jax.Array, rng: jax.Array, temperature: float,
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
-def decode_cache_shapes(model: Any, params: Any, prompt: jax.Array):
+def _is_cache_payload(leaf: Any) -> bool:
+    """A cache leaf that holds rows of tokens — K/V ``[B, slots, KV, D]``,
+    their int8 scales ``[B, slots, KV, 1]``, a latent ``[B, slots, C]`` —
+    as against the scalar ``cache_index``: what row scatters, exports and
+    beam gathers move."""
+    return getattr(leaf, "ndim", 0) >= 3
+
+
+def _latent(model: Any) -> bool:
+    """Whether ``model`` caches latents (``config.mla``): what handoffs,
+    pages, the prefix store and beam search cannot move yet."""
+    return getattr(model.config, "mla", None) is not None
+
+
+def _prefill_kw(model: Any) -> dict:
+    """``prefill=True`` for a model whose attention takes a path of its
+    own into an empty cache (latent attention expands the prompt's keys
+    where a decode round scores the cached latents)."""
+    return {"prefill": True} if _latent(model) else {}
+
+
+def decode_cache_shapes(model: Any, params: Any, prompt: jax.Array,
+                        extra: Optional[dict] = None):
     """Static KV-cache shapes/dtypes for decoding ``prompt`` with ``params``.
 
     Shapes derive from the CALLER's params (not a fresh f32 init): the
@@ -97,21 +119,25 @@ def decode_cache_shapes(model: Any, params: Any, prompt: jax.Array):
     with bf16-cast weights needs a bf16 cache — a fresh init would make
     an f32 one and ``dynamic_update_slice`` rejects the dtype mismatch.
     eval_shape costs nothing at runtime.  Also the bytes model for the
-    decode bench's MBU (``bench.bench_gpt2_decode``)."""
+    decode bench's MBU (``bench.bench_gpt2_decode``).  ``extra`` is what
+    else the model's batch holds (a hidden-state draft is given the
+    target's hidden states, embedding and head, and its cache takes their
+    type)."""
     return jax.eval_shape(
         lambda p: model.apply(
-            {"params": p}, {"tokens": prompt}, decode=True,
+            {"params": p}, {"tokens": prompt, **(extra or {})}, decode=True,
             mutable=["cache"],
         )[1]["cache"],
         params,
     )
 
 
-def zero_cache(model: Any, params: Any, prompt: jax.Array) -> Any:
+def zero_cache(model: Any, params: Any, prompt: jax.Array,
+               extra: Optional[dict] = None) -> Any:
     """A fresh all-zeros KV cache shaped by :func:`decode_cache_shapes`."""
     return jax.tree_util.tree_map(
         lambda s: jnp.zeros(s.shape, s.dtype),
-        decode_cache_shapes(model, params, prompt),
+        decode_cache_shapes(model, params, prompt, extra),
     )
 
 
@@ -138,7 +164,7 @@ def _chunked_prefill(model, params, cache, prompt):
         out, mutated = model.apply(
             {"params": params, "cache": cache},
             {"tokens": piece, "positions": pos},
-            decode=True, mutable=["cache"],
+            decode=True, mutable=["cache"], **_prefill_kw(model),
         )
         cache = mutated["cache"]
     return cache, out["logits"][:, -1].astype(jnp.float32)
@@ -491,6 +517,19 @@ def _accept_resample_rows(p_rows: jax.Array, q_rows: jax.Array,
     probs = jnp.where(total > 0.0, residual, p_j)  # degenerate: back to p
     tok = jax.random.categorical(kr, jnp.log(probs), axis=-1)
     return j, tok.astype(jnp.int32)
+
+
+def _scatter_row(batch_cache: Any, one_cache: Any, row) -> Any:
+    """Put a batch-1 cache into row ``row`` of a batch cache: payload
+    leaves (K/V ``[B, slots, KV, D]``, int8 scales, a latent ``[B, slots,
+    C]``) take the fresh row; the scalar ``cache_index`` is bookkeeping
+    only under per-row frontiers — kept monotone so rolling-cache chunk
+    math stays conservative."""
+    return jax.tree_util.tree_map(
+        lambda a, b: a.at[row].set(b[0]) if _is_cache_payload(a)
+        else jnp.maximum(a, b),
+        batch_cache, one_cache,
+    )
 
 
 def _spec_prefill_impl(model, draft_model, params, draft_params, prompt,
@@ -952,18 +991,8 @@ def _spec_admit(model, draft_model, params, draft_params, state, row,
         else jnp.asarray(False)
     done = done.at[row].set(row_done)
 
-    def scatter(batch_cache, one_cache):
-        # K/V leaves [B, slots, KV, D] take the fresh row; the scalar
-        # cache_index is bookkeeping only under per-row frontiers — keep
-        # it monotone so rolling-cache chunk math stays conservative
-        return jax.tree_util.tree_map(
-            lambda a, b: a.at[row].set(b[0]) if getattr(a, "ndim", 0) == 4
-            else jnp.maximum(a, b),
-            batch_cache, one_cache,
-        )
-
-    cache_t = scatter(cache_t, c1_t)
-    cache_d = scatter(cache_d, c1_d)
+    cache_t = _scatter_row(cache_t, c1_t, row)
+    cache_d = _scatter_row(cache_d, c1_d, row)
     drafted = drafted.at[row].set(0)
     accepted = accepted.at[row].set(0)
     return (buf, n_tok, done, cache_t, cache_d, key_st,
@@ -975,10 +1004,8 @@ def _spec_import_row(state, row, buf1, n1, d1, c1_t, c1_d):
     """Scatter a handed-off batch-1 row state into row ``row`` of a live
     batch state — the IMPORT half of the prefill/decode lane handoff.
 
-    Mirrors :func:`_spec_admit`'s scatter exactly (K/V payload leaves —
-    including int8 pages and their rank-4 scales — discriminate from the
-    scalar ``cache_index`` by ``ndim == 4``; the index stays monotone via
-    ``maximum``), minus the prefill: the handoff already carries the
+    The same :func:`_scatter_row` as :func:`_spec_admit`'s, minus the
+    prefill: the handoff already carries the
     prefilled cache rows, so importing a row is a cheap scatter dispatch
     instead of a full prompt forward.  Stale K/V the previous occupant
     left beyond the fresh prompt are hidden by the per-row causal mask,
@@ -989,15 +1016,8 @@ def _spec_import_row(state, row, buf1, n1, d1, c1_t, c1_d):
     n_tok = n_tok.at[row].set(n1[0])
     done = done.at[row].set(d1[0])
 
-    def scatter(batch_cache, one_cache):
-        return jax.tree_util.tree_map(
-            lambda a, b: a.at[row].set(b[0]) if getattr(a, "ndim", 0) == 4
-            else jnp.maximum(a, b),
-            batch_cache, one_cache,
-        )
-
-    cache_t = scatter(cache_t, c1_t)
-    cache_d = scatter(cache_d, c1_d)
+    cache_t = _scatter_row(cache_t, c1_t, row)
+    cache_d = _scatter_row(cache_d, c1_d, row)
     drafted = drafted.at[row].set(0)
     accepted = accepted.at[row].set(0)
     return (buf, n_tok, done, cache_t, cache_d, key_st,
@@ -1066,6 +1086,222 @@ def _spec_suffix_prefill(model, draft_model, params, draft_params, prompt,
     return buf, n_tok, done, cache_t, cache_d, key, stats0
 
 
+# -- a draft that reads the target's hidden state (MTPDraft) -----------------
+#
+# The round state of the functions above, with two more entries appended:
+# ``(buf, n_tok, done, cache_t, cache_d, key, (rounds, drafted, accepted),
+# draft_tok [B], counters)``.  ``draft_tok`` is each row's pending proposal
+# for the token after its frontier; ``counters`` are totals accumulated on
+# the device and fetched when someone asks (``ContinuousBatcher.stats``).
+
+
+def _draft_apply(draft_model, draft_params, params, cache_d, tokens,
+                 positions, hidden, **kw):
+    """One pass of the hidden-state draft over ``(hidden_i, tokens_i =
+    t_{i+1})`` pairs, with the target's embedding and head handed in."""
+    return draft_model.apply(
+        {"params": draft_params, "cache": cache_d},
+        {"tokens": tokens, "positions": positions, "hidden": hidden,
+         **draft_model.tied(params)},
+        decode=True, mutable=["cache", "routing"], **kw,
+    )
+
+
+def _routed_layers(model) -> int:
+    """Layers of ``model`` that sow their routing, by its config's own
+    account (``experts`` from ``first_k_dense`` on)."""
+    cfg = model.config
+    if getattr(cfg, "experts", None) is None:
+        return 0
+    return cfg.n_layers - getattr(cfg, "first_k_dense", 0)
+
+
+def _zero_counters(model, draft_model):
+    """Totals of the rounds since the last fetch: tokens each held expert
+    got (a row a routed layer, the target's then the draft's), top-k slots
+    routed in all and those that fell on a held expert, drafts proposed and
+    accepted, rounds."""
+    experts = [ex for ex in (getattr(m.config, "experts", None)
+                             for m in (model, draft_model)) if ex is not None]
+    held = max((ex.held for ex in experts), default=0)
+    layers = _routed_layers(model) + _routed_layers(draft_model)
+    zero = jnp.zeros((), jnp.int32)
+    return {"rounds": zero, "drafted": zero, "accepted": zero,
+            "routed_slots": zero, "held_slots": zero,
+            "expert_tokens": jnp.zeros((layers, held), jnp.int32)}
+
+
+def _count_routing(model, routing, live, held):
+    """``([layers, held] tokens a held expert, slots routed)`` of one pass:
+    ``routing`` is the pass's sown ``top_idx`` leaves, ``live`` the rows
+    ``[B]`` whose tokens count."""
+    ex = getattr(model.config, "experts", None)
+    if ex is None or not routing:
+        return jnp.zeros((0, held), jnp.int32), jnp.zeros((), jnp.int32)
+    rows, slots = [], jnp.zeros((), jnp.int32)
+    for name in sorted(routing, key=lambda k: int(k.rsplit("_", 1)[1])):
+        idx = routing[name]["experts"]["top_idx"][0]          # [B, S, K]
+        local = idx - ex.held_start
+        here = (local >= 0) & (local < ex.held) & live[:, None, None]
+        rows.append(jnp.zeros((ex.held,), jnp.int32).at[
+            jnp.where(here, local, ex.held)].add(1, mode="drop"))
+        slots = slots + jnp.sum(live) * idx.shape[1] * idx.shape[2]
+    return jnp.stack(rows), slots.astype(jnp.int32)
+
+
+def _mtp_prefill_rows(model, draft_model, params, draft_params, prompt):
+    """Prefill target and draft over ``prompt`` ``[B, P]`` from empty
+    caches: the target's pass gives the hidden states and the first
+    emitted token ``g``; the draft's pass over ``(h_i, t_{i+1})`` (``t_P =
+    g``) fills its cache and proposes the token after ``g``."""
+    B, P = prompt.shape
+    pos = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
+    out, mut = model.apply(
+        {"params": params, "cache": zero_cache(model, params, prompt)},
+        {"tokens": prompt, "positions": pos},
+        decode=True, mutable=["cache"], **_prefill_kw(model),
+    )
+    g = jnp.argmax(out["logits"][:, -1].astype(jnp.float32),
+                   axis=-1).astype(jnp.int32)
+    nxt = jnp.concatenate([prompt[:, 1:], g[:, None]], axis=1)
+    # the draft's empty cache takes its type from what the draft is given:
+    # from the tokens alone it would be float32, after the zeros that stand
+    # in for the hidden states
+    d_out, d_mut = _draft_apply(
+        draft_model, draft_params, params,
+        zero_cache(draft_model, draft_params, prompt,
+                   {"hidden": out["hidden"], **draft_model.tied(params)}),
+        nxt, pos, out["hidden"], **_prefill_kw(draft_model),
+    )
+    d_tok = jnp.argmax(d_out["logits"][:, -1].astype(jnp.float32),
+                       axis=-1).astype(jnp.int32)
+    return mut["cache"], d_mut["cache"], g, d_tok
+
+
+@functools.partial(
+    jax.jit, static_argnums=(0, 1),
+    static_argnames=("max_new_tokens", "eos_token"),
+)
+def _mtp_prefill(model, draft_model, params, draft_params, prompt, key=None,
+                 *, max_new_tokens, eos_token):
+    """:func:`_spec_prefill` for a hidden-state draft (greedy)."""
+    B, P = prompt.shape
+    total = P + max_new_tokens
+    if key is None:
+        key = jax.random.PRNGKey(0)
+    cache_t, cache_d, g, d_tok = _mtp_prefill_rows(
+        model, draft_model, params, draft_params, prompt)
+    buf = jnp.zeros((B, total), jnp.int32)
+    buf = jax.lax.dynamic_update_slice(buf, prompt, (0, 0))
+    buf = buf.at[:, P].set(g)
+    n_tok = jnp.full((B,), P + 1, jnp.int32)
+    done = (g == eos_token) if eos_token is not None \
+        else jnp.zeros((B,), bool)
+    stats0 = (jnp.zeros((), jnp.int32), jnp.zeros((B,), jnp.int32),
+              jnp.zeros((B,), jnp.int32))
+    return (buf, n_tok, done, cache_t, cache_d, key, stats0, d_tok,
+            _zero_counters(model, draft_model))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1),
+                   static_argnames=("eos_token",))
+def _mtp_round(model, draft_model, params, draft_params, state, *,
+               eos_token):
+    """ONE round with a hidden-state draft of depth one (greedy).
+
+    The target verifies ``[pending, d_1]`` per row at the row's frontier
+    (the same per-row positions and no-rewind argument as
+    :func:`_spec_round_impl`), accepts ``d_1`` iff it is the target's own
+    arg-max, and emits one or two tokens ``y`` — always the target's
+    arg-maxes, so the output is plain greedy decoding whatever the draft
+    proposed.  Then ONE pass of the draft over the chunk's ``(h_i, y_i)``
+    pairs fills its cache (the second slot is stale when ``d_1`` was
+    refused, and overwritten next round) and its proposal at the row's
+    last accepted position is the next ``d_1``."""
+    (buf, n_tok, done_in, cache_t, cache_d, key, (rounds, drafted, accepted),
+     d_tok, counters) = state
+    B, total = buf.shape
+    ar = jnp.arange(2, dtype=jnp.int32)[None, :]
+    pos = n_tok - 1                                     # [B] frontiers
+    pending = jnp.take_along_axis(buf, pos[:, None], axis=1)[:, 0]
+    positions = pos[:, None] + ar
+    out, mut = model.apply(
+        {"params": params, "cache": cache_t},
+        {"tokens": jnp.stack([pending, d_tok], axis=1),
+         "positions": positions},
+        decode=True, mutable=["cache", "routing"],
+    )
+    y = jnp.argmax(out["logits"].astype(jnp.float32), axis=-1) \
+        .astype(jnp.int32)                              # [B, 2]
+    j = (d_tok == y[:, 0]).astype(jnp.int32)            # [B], 0 or 1
+
+    keep = ar <= j[:, None]
+    if eos_token is not None:
+        keep = keep & jnp.stack(
+            [jnp.ones((B,), bool), y[:, 0] != eos_token], axis=1)
+    keep = keep & ((n_tok[:, None] + ar) < total) & ~done_in[:, None]
+    cols = jnp.where(keep, n_tok[:, None] + ar, total)  # OOB -> dropped
+    rows = jnp.broadcast_to(jnp.arange(B)[:, None], cols.shape)
+    buf = buf.at[rows, cols].set(y, mode="drop")
+    acc = keep.sum(axis=1).astype(jnp.int32)
+    n_new = n_tok + acc
+    done = done_in | (n_new >= total)
+    if eos_token is not None:
+        done = done | jnp.any((y == eos_token) & keep, axis=1)
+
+    d_out, d_mut = _draft_apply(draft_model, draft_params, params, cache_d,
+                                y, positions, out["hidden"])
+    proposals = jnp.argmax(d_out["logits"].astype(jnp.float32), axis=-1) \
+        .astype(jnp.int32)
+    d_next = jnp.take_along_axis(proposals, j[:, None], axis=1)[:, 0]
+
+    live = ~done_in
+    n_drafted = jnp.where(live, jnp.minimum(1, total - n_tok), 0)
+    n_accepted = jnp.where(live, jnp.minimum(j, acc), 0)
+    held = counters["expert_tokens"].shape[1]
+    t_tokens, t_slots = _count_routing(model, mut.get("routing"), live, held)
+    d_tokens, d_slots = _count_routing(draft_model, d_mut.get("routing"),
+                                       live, held)
+    tokens = jnp.concatenate([t_tokens, d_tokens], axis=0)
+    counters = {
+        "rounds": counters["rounds"] + 1,
+        "drafted": counters["drafted"] + jnp.sum(n_drafted),
+        "accepted": counters["accepted"] + jnp.sum(n_accepted),
+        "routed_slots": counters["routed_slots"] + t_slots + d_slots,
+        "held_slots": counters["held_slots"] + jnp.sum(tokens),
+        "expert_tokens": counters["expert_tokens"] + tokens,
+    }
+    stats = (rounds + 1, drafted + n_drafted, accepted + n_accepted)
+    return (buf, n_new, done, mut["cache"], d_mut["cache"], key, stats,
+            d_next, counters)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1),
+                   static_argnames=("eos_token",))
+def _mtp_admit(model, draft_model, params, draft_params, state, row,
+               prompt_row, *, eos_token):
+    """:func:`_spec_admit` for a hidden-state draft: the new row's target
+    prefill, the draft's prefill from its hidden states, both scattered
+    into the batch caches, and the row's first proposal."""
+    (buf, n_tok, done, cache_t, cache_d, key, (rounds, drafted, accepted),
+     d_tok, counters) = state
+    total = buf.shape[1]
+    P_new = prompt_row.shape[1]
+    c1_t, c1_d, g, d1 = _mtp_prefill_rows(
+        model, draft_model, params, draft_params, prompt_row)
+    row_buf = jnp.zeros((total,), jnp.int32)
+    row_buf = jax.lax.dynamic_update_slice(row_buf, prompt_row[0], (0,))
+    row_buf = row_buf.at[P_new].set(g[0])
+    buf = buf.at[row].set(row_buf)
+    n_tok = n_tok.at[row].set(P_new + 1)
+    done = done.at[row].set(
+        (g[0] == eos_token) if eos_token is not None else False)
+
+    return (buf, n_tok, done, _scatter_row(cache_t, c1_t, row),
+            _scatter_row(cache_d, c1_d, row), key, (rounds, drafted.at[row].set(0), accepted.at[row].set(0)),
+            d_tok.at[row].set(d1[0]), counters)
+
+
 @dataclasses.dataclass
 class KVPage:
     """One fixed-granularity slice of a prefilled row: ``page_tokens``
@@ -1113,7 +1349,7 @@ class KVHandoff:
     ``attention_window + decode_rolling_slack`` slots per row however
     long the prompt, and with ``kv_cache_int8`` the pages travel as int8
     payload WITH their rank-4 ``[1, slots, KV, 1]`` f32 scale leaves —
-    both are ``ndim == 4``, so export, transfer, and the import scatter
+    both are payload leaves, so export, transfer, and the import scatter
     treat them uniformly.  :meth:`to_host` materializes every leaf as
     numpy, the wire format a process-backed replica would ship.
     """
@@ -1167,7 +1403,7 @@ class KVHandoff:
         def page_slice(a, lo, hi):
             # owned copies: a view would retain the whole parent buffer
             # and break the store's byte accounting
-            if getattr(a, "ndim", 0) == 4:
+            if _is_cache_payload(a):
                 return np.ascontiguousarray(a[:, lo:hi])
             return np.asarray(a).copy()
 
@@ -1214,7 +1450,7 @@ class KVHandoff:
 
             def leaf_join(*leaves):
                 a0 = np.asarray(leaves[0])
-                if a0.ndim != 4:
+                if not _is_cache_payload(a0):
                     return np.asarray(covered, a0.dtype)  # cache_index
                 cat = np.concatenate(
                     [np.asarray(leaf) for leaf in leaves], axis=1)
@@ -1247,8 +1483,12 @@ def export_kv_row(state, row: int) -> KVHandoff:
     Used by :meth:`ContinuousBatcher.prefill_handoff` (row 0 of a fresh
     batch-1 prefill) and available for migrating a live row between
     replicas."""
+    if len(state) != 7:
+        raise ValueError(
+            "KVHandoff cannot carry the state of a hidden-state draft's "
+            "round (its pending draft token and counters) yet")
     (buf, n_tok, done, cache_t, cache_d, _key, _stats) = state
-    sl = lambda a: a[row:row + 1] if getattr(a, "ndim", 0) == 4 else a  # noqa: E731
+    sl = lambda a: a[row:row + 1] if _is_cache_payload(a) else a  # noqa: E731
     return KVHandoff(
         buf=buf[row:row + 1],
         n_tok=n_tok[row:row + 1],
@@ -1325,6 +1565,12 @@ class ContinuousBatcher:
 
         if n_draft < 1:
             raise ValueError(f"n_draft must be >= 1, got {n_draft}")
+        # A draft that declares ``reads_hidden`` (MTPDraft) is no language
+        # model to step k+1 times: its round is the target's verify pass,
+        # then one pass of the draft over the target's hidden states.
+        self._hidden_draft = bool(getattr(draft_model, "reads_hidden", False))
+        self.n_draft, self.sampled = int(n_draft), bool(sampled)
+        self._check_hidden_draft()
         if sampled and temperature <= 0.0:
             raise ValueError(
                 "sampled=True needs temperature > 0; use sampled=False "
@@ -1351,8 +1597,8 @@ class ContinuousBatcher:
         overrides = {"decode_per_row": True}
         if kv_cache_int8 is not None:
             overrides["kv_cache_int8"] = bool(kv_cache_int8)
-        per_row = lambda m: type(m)(  # noqa: E731
-            dataclasses.replace(m.config, **overrides)
+        per_row = lambda m: m.clone(  # noqa: E731
+            config=dataclasses.replace(m.config, **overrides)
         )
         self._model = per_row(model)
         self._draft_model = per_row(draft_model)
@@ -1360,9 +1606,7 @@ class ContinuousBatcher:
         self._params = params
         self._draft_params = draft_params
         self.total_len = int(total_len)
-        self.n_draft = int(n_draft)
         self.eos_token = eos_token
-        self.sampled = bool(sampled)
         self._temperature = (
             jnp.float32(temperature) if sampled else temperature
         )
@@ -1390,8 +1634,8 @@ class ContinuousBatcher:
                 "before changing it"
             )
         model, draft_model = self._base_models
-        rebuilt = lambda m: type(m)(  # noqa: E731
-            dataclasses.replace(
+        rebuilt = lambda m: m.clone(  # noqa: E731
+            config=dataclasses.replace(
                 m.config, decode_per_row=True,
                 kv_cache_int8=bool(enabled),
             )
@@ -1402,6 +1646,35 @@ class ContinuousBatcher:
     def _kw(self):
         return dict(eos_token=self.eos_token, sampled=self.sampled,
                     top_k=self._top_k, top_p=self._top_p)
+
+    def _check_hidden_draft(self) -> None:
+        """What a draft that reads the target's hidden state cannot do yet,
+        refused by name (``n_draft`` is the serving loop's to set between
+        rounds, so a round checks again)."""
+        if not self._hidden_draft:
+            return
+        refused = [what for what, on in (
+            (f"n_draft={self.n_draft} (a draft chain deeper than one)",
+             self.n_draft != 1),
+            ("sampled=True", self.sampled),
+        ) if on]
+        if refused:
+            raise ValueError(
+                f"a draft that reads the target's hidden state cannot run "
+                f"with {', '.join(refused)} yet")
+
+    def _movable(self) -> bool:
+        """Whether a row's state is what :class:`KVHandoff` and
+        :class:`KVPage` carry: K/V caches of two language models."""
+        return not (self._hidden_draft or _latent(self._model)
+                    or _latent(self._draft_model))
+
+    def _refuse_handoff(self, what: str) -> None:
+        if not self._movable():
+            raise ValueError(
+                f"{what}: KVHandoff and KVPage cannot carry a latent cache "
+                f"or the state of a draft that reads the target's hidden "
+                f"state yet")
 
     def start(self, prompts) -> None:
         """Prefill the first group (``[B, P]`` int32) and build the
@@ -1425,6 +1698,14 @@ class ContinuousBatcher:
                 f"({self.total_len}); the buffer needs room for at least "
                 f"one generated token"
             )
+        if self._hidden_draft:
+            self.state = ledger_call(
+                _mtp_prefill, "generate/spec_prefill",
+                self._model, self._draft_model, self._params,
+                self._draft_params, prompts, self._rng,
+                max_new_tokens=self.total_len - P, eos_token=self.eos_token,
+            )
+            return
         self.state = ledger_call(
             _spec_prefill, "generate/spec_prefill",
             self._model, self._draft_model, self._params,
@@ -1438,12 +1719,21 @@ class ContinuousBatcher:
         if self.state is None:
             raise ValueError("call start() before step()")
         with self.reads.tracer.span("serve/dispatch", n_draft=self.n_draft):
-            self.state = ledger_call(
-                _spec_round, "generate/spec_round",
-                self._model, self._draft_model, self._params,
-                self._draft_params, self.state, self._temperature,
-                n_draft=self.n_draft, **self._kw(),
-            )
+            if self._hidden_draft:
+                self._check_hidden_draft()
+                self.state = ledger_call(
+                    _mtp_round, "generate/spec_round",
+                    self._model, self._draft_model, self._params,
+                    self._draft_params, self.state,
+                    eos_token=self.eos_token,
+                )
+            else:
+                self.state = ledger_call(
+                    _spec_round, "generate/spec_round",
+                    self._model, self._draft_model, self._params,
+                    self._draft_params, self.state, self._temperature,
+                    n_draft=self.n_draft, **self._kw(),
+                )
         return (self.reads(self.state[1], "n_tok"),
                 self.reads(self.state[2], "done"))
 
@@ -1483,6 +1773,14 @@ class ContinuousBatcher:
                 f"total_len ({self.total_len})"
             )
         self._admits += 1
+        if self._hidden_draft:
+            self.state = ledger_call(
+                _mtp_admit, "generate/spec_admit",
+                self._model, self._draft_model, self._params,
+                self._draft_params, self.state, jnp.int32(row), prompt_row,
+                _shape=int(prompt_row.shape[1]), eos_token=self.eos_token,
+            )
+            return
         key = jax.random.fold_in(self._rng, self._admits)
         self.state = ledger_call(
             _spec_admit, "generate/spec_admit",
@@ -1507,6 +1805,7 @@ class ContinuousBatcher:
         the fleet bit-equality contract.  Sampled handoffs need the
         caller to coordinate keys across lanes via ``key=``.
         """
+        self._refuse_handoff("prefill_handoff()")
         prompt_row = jnp.asarray(prompt_row, jnp.int32)
         if prompt_row.ndim == 1:
             prompt_row = prompt_row[None, :]
@@ -1539,8 +1838,9 @@ class ContinuousBatcher:
         """Whether rows can be rebuilt from imported prefix pages: the
         page index assumes the position==slot cache layout, and a
         rolling cache remaps slots mod the window — its pages are not
-        content-addressable by token prefix."""
-        return not any(
+        content-addressable by token prefix.  Nor can pages carry a
+        latent cache or a hidden-state draft's state yet."""
+        return self._movable() and not any(
             getattr(m.config, "decode_rolling_cache", False)
             for m in (self._model, self._draft_model)
         )
@@ -1555,6 +1855,7 @@ class ContinuousBatcher:
         to :meth:`prefill_handoff` of the full prompt (the kvstore
         oracle); the admit counter advances exactly like
         :meth:`prefill_handoff`, so key discipline is unchanged."""
+        self._refuse_handoff("prefill_suffix_handoff()")
         prompt_row = jnp.asarray(prompt_row, jnp.int32)
         if prompt_row.ndim == 1:
             prompt_row = prompt_row[None, :]
@@ -1607,6 +1908,7 @@ class ContinuousBatcher:
         """Convenience over :meth:`prefill_suffix_handoff`: reassemble
         ``pages`` with THIS batcher's slot layout
         (:meth:`KVHandoff.from_pages`) and run the suffix prefill."""
+        self._refuse_handoff("prefill_from_pages()")
         prefix = KVHandoff.from_pages(
             pages, total_len=self.total_len,
             slots_t=int(self._model.config.max_seq),
@@ -1620,6 +1922,7 @@ class ContinuousBatcher:
         counterpart of :meth:`admit` minus the prefill: a cheap scatter
         dispatch, so long prompts prefilled elsewhere never stall the
         decode rounds here.  Same occupancy rules as :meth:`admit`."""
+        self._refuse_handoff("admit_prefilled()")
         if self.state is None:
             raise ValueError("call start() before admit_prefilled()")
         B = self.state[0].shape[0]
@@ -1656,9 +1959,8 @@ class ContinuousBatcher:
                 f"retire() row {row} out of range for batch of "
                 f"{self.state[0].shape[0]} rows"
             )
-        (buf, n_tok, done, cache_t, cache_d, key, stats) = self.state
-        self.state = (buf, n_tok, done.at[row].set(True), cache_t,
-                      cache_d, key, stats)
+        state = self.state
+        self.state = state[:2] + (state[2].at[row].set(True),) + state[3:]
 
     def finished_rows(self):
         """Row indices whose requests are complete (eos or full buffer)."""
@@ -1688,9 +1990,26 @@ class ContinuousBatcher:
         counters reset when a row is re-admitted."""
         if self.state is None:
             raise ValueError("call start() before stats()")
+        self.publish_counters()
         rounds, drafted, accepted = self.state[6]
         return {"rounds": int(rounds), "drafted": np.asarray(drafted),
                 "accepted": np.asarray(accepted)}
+
+    def publish_counters(self) -> None:
+        """Fetch the totals the rounds accumulated on the device (a
+        hidden-state draft's round keeps them: tokens each held expert got,
+        routed and held slots, drafted and accepted), add them to the
+        process-wide record (:func:`rocket_tpu.observe.trace.get_rounds`)
+        and start them again from nought.  One device-to-host read, made
+        when someone asks — :meth:`stats`, a closing ``ServingLoop`` — and
+        never by a round."""
+        if self.state is None or len(self.state) < 9:
+            return
+        from rocket_tpu.observe.trace import get_rounds
+
+        get_rounds().add(jax.device_get(self.state[8]))
+        self.state = self.state[:8] + (jax.tree_util.tree_map(
+            jnp.zeros_like, self.state[8]),)
 
 
 @functools.partial(jax.jit, static_argnums=0, static_argnames=("temperature",))
@@ -1841,6 +2160,11 @@ def _accept_resample(p_rows: "np.ndarray", q_rows: "np.ndarray",
 
 def _validate_beam_lm(model, P, max_new_tokens, beam_size):
     """Shared loud validation for the decoder-only beam entry points."""
+    if _latent(model):
+        raise ValueError(
+            "beam search cannot run a latent-attention (mla) model yet: "
+            "its cache is written at each row's positions, which the beam "
+            "gather does not track")
     if not model.config.causal:
         raise ValueError(
             "beam search requires a causal decoder "
@@ -1966,7 +2290,7 @@ def beam_search_cached(
     # tile [B, slots, KV, D] -> [B*K, ...] matching buf.reshape(B*K, ...)
     # row order; the scalar cache_index stays shared (uniform frontiers)
     cache = jax.tree_util.tree_map(
-        lambda a: jnp.repeat(a, K, axis=0) if getattr(a, "ndim", 0) == 4
+        lambda a: jnp.repeat(a, K, axis=0) if _is_cache_payload(a)
         else a,
         cache,
     )
@@ -1975,7 +2299,7 @@ def beam_search_cached(
     def gather_cache(cache, src_beam):
         flat = (row0 + src_beam).reshape(-1)
         return jax.tree_util.tree_map(
-            lambda a: a[flat] if getattr(a, "ndim", 0) == 4 else a, cache
+            lambda a: a[flat] if _is_cache_payload(a) else a, cache
         )
 
     scores = jnp.full((B, K), -jnp.inf).at[:, 0].set(0.0)

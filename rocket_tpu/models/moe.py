@@ -34,7 +34,9 @@ train against it (blackboard contract, reference ``module.py:139``).
 
 from __future__ import annotations
 
+import dataclasses
 import math
+from typing import Optional
 
 import flax.linen as nn
 import jax
@@ -206,6 +208,138 @@ class MoEMLP(nn.Module):
         expert_in = jnp.einsum("bsec,bsd->ebcd", dispatch, x)
         expert_out = self._experts(expert_in, w_up, w_down, b_up)
         return jnp.einsum("bsec,ebcd->bsd", combine.astype(x.dtype), expert_out)
+
+
+# Tokens at or below which RoutedExperts runs every held expert over every
+# token instead of grouping the routed slots.  A v5e multiplies 240 times
+# for each byte pair it streams (197 TFLOP/s over 819 GB/s), so below that
+# many tokens the held experts' weights, read once either way, cost more
+# than the products wasted on tokens an expert was not chosen for; and the
+# time no longer depends on where the router sent them.
+DENSE_BELOW = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertsConfig:
+    """A layer of gated experts as DeepSeek-V3-like models publish it
+    (:class:`RoutedExperts`), and the share of it held here.
+
+    ``n_routed`` is the router's width: every expert of the layer, on
+    whatever chip it lives.  ``n_held`` of them, from ``held_start`` on,
+    are this program's (``None``: all).  ``scale`` is the published
+    ``routed_scaling_factor``; ``n_shared`` shared experts of the same
+    width see every token."""
+
+    n_routed: int
+    top_k: int
+    expert_dim: int
+    n_shared: int = 1
+    scale: float = 1.0
+    norm_topk: bool = True
+    held_start: int = 0
+    n_held: Optional[int] = None
+
+    def __post_init__(self) -> None:
+        held = self.held
+        if not 0 < self.top_k <= self.n_routed:
+            raise ValueError(
+                f"top_k {self.top_k} must lie in 1..n_routed {self.n_routed}")
+        if held < 1 or self.held_start < 0 \
+                or self.held_start + held > self.n_routed:
+            raise ValueError(
+                f"held experts {self.held_start}..{self.held_start + held} "
+                f"are not among the {self.n_routed} routed ones")
+
+    @property
+    def held(self) -> int:
+        return self.n_routed if self.n_held is None else self.n_held
+
+
+class RoutedExperts(nn.Module):
+    """SwiGLU experts behind a sigmoid router, of which this program holds
+    a share; nothing is dropped.
+
+    The router scores every token against all ``n_routed`` experts in
+    float32 (``sigmoid``, no groups, no bias), keeps the ``top_k`` and
+    weighs them ``s / sum(s) * scale``.  Of a token's slots those that fell
+    on a held expert are computed here, ``sum_e w_e * Expert_e(x)``; what
+    the experts held elsewhere would add is left out (their chips add it
+    in a deployment; on one chip the layer runs without its exchange).
+    There is no capacity.  A prefill's ``tokens * top_k`` slots are sorted
+    by held expert (the rest last) and one grouped product a matrix
+    (``jax.lax.ragged_dot``) runs over them, so every slot of a held
+    expert is computed even when all tokens choose the same one.  A decode
+    round's few tokens (``DENSE_BELOW``) go through every held expert and
+    are weighed nought where the expert was not chosen: the same sums, the
+    weights read once, no sort and no gather.
+
+    The chosen experts are sown as ``routing/top_idx`` (``[B, S, top_k]``,
+    ids among all ``n_routed``) for the serving round's counters; the
+    shared experts are the caller's (:class:`Block` adds them)."""
+
+    config: ExpertsConfig
+
+    @nn.compact
+    def __call__(self, x, router_input=None):
+        """``router_input`` is what the router scores where it is not ``x``
+        itself: the same activations before they were cast for the experts'
+        matrix products (a float32 residual stream's norm), so that the
+        cast decides no near tie."""
+        cfg = self.config
+        B, S, D = x.shape
+        N, K, E, F = B * S, cfg.top_k, cfg.held, cfg.expert_dim
+        flat = x.reshape(N, D)
+        scored = flat if router_input is None else router_input.reshape(N, D)
+        router = self.param(
+            "router", _init(nn.initializers.lecun_normal(), "embed", None),
+            (D, cfg.n_routed))
+        # float32 end to end: a top-k over scores that a bf16 product
+        # rounded picks other experts for the near ties
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "nd,de->ne", scored.astype(jnp.float32),
+            router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        top_s, top_i = jax.lax.top_k(scores, K)                 # [N, K]
+        if cfg.norm_topk:
+            top_s = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+        top_s = top_s * cfg.scale
+        self.sow("routing", "top_idx", top_i.reshape(B, S, K))
+
+        def matrix(name, d_in, d_out, axes):
+            return self.param(
+                name, _init(nn.initializers.lecun_normal(), "expert", *axes),
+                (E, d_in, d_out)).astype(x.dtype)
+
+        w_gate = matrix("w_gate", D, F, ("embed", "mlp"))
+        w_up = matrix("w_up", D, F, ("embed", "mlp"))
+        w_down = matrix("w_down", F, D, ("mlp", "embed"))
+
+        local = top_i - cfg.held_start
+        held = (local >= 0) & (local < E)
+        if N <= DENSE_BELOW:
+            weight = jnp.sum(                              # [N, E]
+                jnp.where(held, top_s, 0.0)[..., None]
+                * jax.nn.one_hot(local, E, dtype=jnp.float32), axis=1)
+            h = nn.silu(jnp.einsum("nd,edf->enf", flat, w_gate)) \
+                * jnp.einsum("nd,edf->enf", flat, w_up)
+            y = jnp.einsum("enf,efd->end", h, w_down)
+            # weighed on the vector unit: a float32 matrix product would
+            # round the weights to bfloat16 on its way into the MXU
+            out = jnp.sum(weight.T[..., None] * y.astype(jnp.float32), axis=0)
+            return out.astype(x.dtype).reshape(B, S, D)
+        group = jnp.where(held, local, E).reshape(N * K)   # the rest: last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=E + 1)[:E].astype(jnp.int32)
+        rows = flat[order // K]                            # [N*K, D]
+        h = nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes)) \
+            * jax.lax.ragged_dot(rows, w_up, sizes)
+        y = jax.lax.ragged_dot(h, w_down, sizes)
+        # back in slot order; a slot of no held expert lies past the groups,
+        # and whatever the grouped product left there is not read
+        y = y[jnp.argsort(order)].reshape(N, K, D)
+        y = jnp.where(held[..., None], y, 0).astype(jnp.float32)
+        out = jnp.sum(y * top_s[..., None], axis=1)
+        return out.astype(x.dtype).reshape(B, S, D)
 
 
 def moe_aux_loss(key: str = "moe_aux"):

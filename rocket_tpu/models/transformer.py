@@ -24,9 +24,16 @@ TPU-first design notes:
 - attention logits accumulate in f32 on the MXU regardless of bf16 compute
   (``ops.attention``).
 
+- **DeepSeek-V3 style** (``mla=MLAConfig(...)``, ``experts=
+  ExpertsConfig(...)``, ``first_k_dense``, ``sandwich_norm``): latent
+  attention over one cached row a token, leading dense layers and then
+  gated experts with a shared one, and :class:`MTPDraft`, the
+  multi-token-prediction module that drafts from the target's hidden state.
+
 Batch contract (blackboard style, reference ``module.py:139``): reads
 ``batch['tokens']`` (int32 ``[B, S]``; optional ``positions``,
-``segment_ids``), writes ``batch['logits']``.
+``segment_ids``), writes ``batch['logits']`` and, when decoding,
+``batch['hidden']`` (the last block's output before the final norm).
 """
 
 from __future__ import annotations
@@ -43,11 +50,29 @@ from rocket_tpu.models.layers import (
     Embed,
     PDense,
     RMSNorm,
+    _init,
     apply_rope,
     rotary_embedding,
 )
-from rocket_tpu.ops.attention import attend
+from rocket_tpu.models.moe import ExpertsConfig, RoutedExperts
+from rocket_tpu.ops.attention import attend, dot_attention
 from rocket_tpu.parallel.context import constrain
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention's published sizes (:class:`LatentAttention`)."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def cache_width(self) -> int:
+        """Numbers cached a token and a layer: the latent and one rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,8 +196,49 @@ class TransformerConfig:
     # Interleaved chunk count v (layer chunks per stage); must be 1 for
     # the other schedules. Needs n_layers % (pipe * v) == 0.
     pipeline_chunks: int = 1
+    # Latent attention (None = the q/k/v heads above): queries and keys go
+    # through low-rank latents and the decode cache holds one
+    # ``[rows, slots, kv_lora_rank + qk_rope_head_dim]`` leaf a layer.
+    mla: Optional[MLAConfig] = None
+    # Gated experts with shared ones (rocket_tpu.models.moe.RoutedExperts)
+    # in every layer from ``first_k_dense`` on; the layers before keep the
+    # dense MLP of ``ffn_dim``.  None = the ``n_experts`` switch above.
+    experts: Optional[ExpertsConfig] = None
+    first_k_dense: int = 0
+    # Four norms a block: ``x + N(attn(N(x)))``, ``x + N(mlp(N(x)))``.
+    sandwich_norm: bool = False
+    # Keep the residual stream in float32 while the sublayers compute in the
+    # embedding's type: every norm reads float32 and its output is cast for
+    # the matrix products, a sublayer's output is added (and, with
+    # sandwich_norm, normalised) in float32.  A few elementwise passes over
+    # [tokens, hidden] more; the sums that decide a router's near ties stop
+    # being rounded to bfloat16 layer after layer.
+    residual_float32: bool = False
 
     def __post_init__(self) -> None:
+        if self.mla is not None or self.experts is not None:
+            what = "latent attention (mla)" if self.mla is not None \
+                else "routed experts (experts)"
+            refused = [name for name, on in (
+                ("kv_cache_int8", self.kv_cache_int8 and self.mla is not None),
+                ("decode_rolling_cache",
+                 self.decode_rolling_cache and self.mla is not None),
+                ("fused_qkv", self.fused_qkv and self.mla is not None),
+                ("scan_layers", self.scan_layers),
+                ("pipeline_microbatches", self.pipeline_microbatches > 0),
+                ("pipeline_microbatch_size",
+                 self.pipeline_microbatch_size > 0),
+                ("n_experts", self.n_experts > 0 and self.experts is not None),
+            ) if on]
+            if refused:
+                raise ValueError(
+                    f"{what} cannot run with {', '.join(refused)} yet")
+        if self.residual_float32 and (self.scan_layers or self.pipelined):
+            raise ValueError("residual_float32 cannot run with scan_layers "
+                             "or a pipeline yet")
+        if self.first_k_dense and self.experts is None:
+            raise ValueError("first_k_dense needs experts for the layers "
+                             "that follow")
         if self.pipeline_microbatches and self.pipeline_microbatch_size:
             raise ValueError(
                 "pipeline_microbatches and pipeline_microbatch_size are "
@@ -558,6 +624,120 @@ class Attention(nn.Module):
                              window=cfg.attention_window)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2's MLA) with its two paths.
+
+    ``c_q = norm(x W_qa)``, ``q = c_q W_qb`` -> per head ``[nope | rope]``;
+    ``[c_kv | k_rope] = x W_kva``, ``c_kv = norm(c_kv)``; RoPE on ``q_rope``
+    and on the one ``k_rope`` all heads share; scale ``(nope + rope)**-0.5``.
+
+    *Expanded* (training, and a prefill into an empty cache): keys and
+    values of every head are expanded from the latent,
+    ``[k_nope_h | v_h] = c_kv W_kvb``, and attended as heads of
+    ``nope + rope`` against values of ``v_head_dim``.  *Absorbed* (decode
+    against the cache): ``W_kvb``'s key half is folded into the query,
+    ``q~_h = q_nope_h W_kvb,h^K``, which then scores the cached rows
+    themselves, multi-query: one row ``[c_kv | k_rope]`` a token serves all
+    heads, its first ``kv_lora_rank`` numbers are the values, and
+    ``W_kvb``'s value half is applied to the context.  Both give the same
+    result; nothing per head is ever cached.
+
+    The cache is one ``[rows, slots, kv_lora_rank + rope]`` leaf, always
+    written at each row's own ``positions[:, 0]`` (contiguous positions a
+    row, as every caller in ``models.generate`` builds them); stale slots
+    past a row's frontier are hidden causally, as in
+    :meth:`Attention._decode_attend`."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids, train: bool,
+                 decode: bool = False, prefill: bool = False):
+        cfg, m = self.config, self.config.mla
+        B, S, _ = x.shape
+        H, C = cfg.n_heads, m.kv_lora_rank
+        dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+        scale = (dn + dr) ** -0.5
+        dense = lambda feat, axes, name: PDense(  # noqa: E731
+            feat, logical_axes=axes, name=name)
+        norm = lambda name: RMSNorm(eps=cfg.norm_eps, name=name)  # noqa: E731
+
+        c_q = norm("q_a_norm")(dense(m.q_lora_rank, ("embed", None), "q_a")(x))
+        q = dense(H * (dn + dr), (None, "heads"), "q_b")(c_q)
+        q = constrain(q.reshape(B, S, H, dn + dr),
+                      "batch", "sequence", "heads", None)
+        kv = dense(C + dr, ("embed", None), "kv_a")(x)
+        c_kv = norm("kv_a_norm")(kv[..., :C])
+        cos, sin = rotary_embedding(positions, dr, cfg.rope_theta, x.dtype)
+        q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
+        k_rope = apply_rope(kv[..., None, C:], cos, sin)        # [B,S,1,dr]
+        w_kvb = self.param(
+            "kv_b", _init(nn.initializers.lecun_normal(), None, "heads"),
+            (C, H * (dn + dv)),
+        ).astype(x.dtype).reshape(C, H, dn + dv)
+
+        def expanded():
+            heads = jnp.einsum("bsc,chd->bshd", c_kv, w_kvb)
+            k = jnp.concatenate(
+                [heads[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, dr))],
+                axis=-1)
+            return _causal_in_blocks(
+                jnp.concatenate([q_nope, q_rope], axis=-1), k,
+                heads[..., dn:], scale, cfg.causal, segment_ids)
+
+        if not decode:
+            out = expanded()
+        else:
+            filled = self.has_variable("cache", "cached_latent")
+            cached = self.variable(
+                "cache", "cached_latent", jnp.zeros,
+                (B, cfg.max_seq, C + dr), x.dtype)
+            cache_index = self.variable(
+                "cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
+            if filled:
+                starts = positions[:, 0].astype(jnp.int32)
+                cached.value = jax.vmap(
+                    lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (s, 0))
+                )(cached.value,
+                  jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1), starts)
+                cache_index.value = jnp.max(starts) + S
+            if not filled or prefill:
+                # the cache held nothing before this chunk: the chunk
+                # attends to itself, and expanding costs a third of
+                # scoring the prompt against every slot
+                out = expanded()
+            else:
+                q_lat = jnp.concatenate(
+                    [jnp.einsum("bshd,chd->bshc", q_nope, w_kvb[..., :dn]),
+                     q_rope], axis=-1)
+                ctx = dot_attention(
+                    q_lat, cached.value[:, :, None, :], v_width=C,
+                    causal=True, q_offset=starts, scale=scale)
+                out = jnp.einsum("bshc,chd->bshd", ctx, w_kvb[..., dn:])
+        out = dense(cfg.hidden, ("heads", "embed"), "o")(
+            out.reshape(B, S, H * dv))
+        if cfg.dropout and train:
+            out = nn.Dropout(cfg.dropout, deterministic=False)(out)
+        return out
+
+
+def _causal_in_blocks(q, k, v, scale, causal, segment_ids, block: int = 512):
+    """``dot_attention`` of a whole sequence against itself, a block of
+    queries at a time against the keys at or before it, so that the scores
+    of a 2048-token prompt over 128 heads are never all alive (2 GiB in
+    float32).  Packed sequences (``segment_ids``) and bidirectional
+    attention go in one piece."""
+    S = q.shape[1]
+    if not causal or segment_ids is not None or S <= block:
+        return dot_attention(q, k, v, causal=causal, segment_ids=segment_ids,
+                             scale=scale)
+    return jnp.concatenate([
+        dot_attention(q[:, lo:lo + block], k[:, :lo + block],
+                      v[:, :lo + block], causal=True, q_offset=lo,
+                      scale=scale)
+        for lo in range(0, S, block)], axis=1)
+
+
 class MLP(nn.Module):
     config: TransformerConfig
 
@@ -597,22 +777,56 @@ class MLP(nn.Module):
 
 class Block(nn.Module):
     """Returns ``(x, aux)`` — aux is the MoE load-balancing loss
-    contribution (0.0 for dense blocks)."""
+    contribution (0.0 for dense blocks).  ``routed`` makes this layer one
+    of ``config.experts`` (shared experts plus the routed ones held here)
+    where the stack's leading layers keep the dense MLP."""
 
     config: TransformerConfig
+    routed: bool = False
+    # with config.residual_float32: the type the sublayers compute in
+    act_dtype: Any = None
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, train: bool,
-                 decode: bool = False):
+                 decode: bool = False, prefill: bool = False):
         cfg = self.config
         x = constrain(x, "batch", "sequence", "act_embed")
-        x = x + Attention(cfg, name="attn")(
-            _Norm(cfg, name="ln1")(x), positions, segment_ids, train,
-            decode=decode,
-        )
+
+        def pre(name, t):
+            """The norm a sublayer reads through: cast for its matrix
+            products, and as it was (what a router scores)."""
+            wide = _Norm(cfg, name=name)(t)
+            return (wide if self.act_dtype is None
+                    else wide.astype(self.act_dtype)), wide
+
+        def post(name, t):          # a sublayer's output on its way back
+            if self.act_dtype is not None:
+                t = t.astype(x.dtype)
+            return _Norm(cfg, name=name)(t) if cfg.sandwich_norm else t
+
+        if cfg.mla is not None:
+            y = LatentAttention(cfg, name="attn")(
+                pre("ln1", x)[0], positions, segment_ids, train,
+                decode=decode, prefill=prefill,
+            )
+        else:
+            y = Attention(cfg, name="attn")(
+                pre("ln1", x)[0], positions, segment_ids, train,
+                decode=decode,
+            )
+        x = x + post("ln1_post", y)
         aux = jnp.zeros((), jnp.float32)
-        h = _Norm(cfg, name="ln2")(x)
-        if cfg.n_experts > 0:
+        h, h_wide = pre("ln2", x)
+        if self.routed:
+            ex = cfg.experts
+            y = RoutedExperts(ex, name="experts")(h, router_input=h_wide)
+            if ex.n_shared:
+                y = y + MLP(
+                    dataclasses.replace(
+                        cfg, mlp="swiglu",
+                        ffn_dim=ex.n_shared * ex.expert_dim),
+                    name="shared")(h, train)
+        elif cfg.n_experts > 0:
             from rocket_tpu.models.moe import MoEMLP
 
             y, aux = MoEMLP(
@@ -625,7 +839,7 @@ class Block(nn.Module):
             )(h, train)
         else:
             y = MLP(cfg, name="mlp")(h, train)
-        x = x + y
+        x = x + post("ln2_post", y)
         return constrain(x, "batch", "sequence", "act_embed"), aux
 
 
@@ -753,7 +967,12 @@ class TransformerLM(nn.Module):
     logits_key: str = "logits"
 
     @nn.compact
-    def __call__(self, batch, train: bool = False, decode: bool = False):
+    def __call__(self, batch, train: bool = False, decode: bool = False,
+                 prefill: bool = False):
+        """``prefill`` (with ``decode``) says the cache holds nothing before
+        this chunk and its positions start at 0: a latent-attention model
+        then expands keys and values over the chunk instead of scoring it
+        against every cache slot.  Other models ignore it."""
         cfg = self.config
         if decode and (cfg.scan_layers or cfg.remat or cfg.pipelined):
             raise ValueError(
@@ -792,6 +1011,10 @@ class TransformerLM(nn.Module):
         if cfg.dropout and train:
             x = nn.Dropout(cfg.dropout, deterministic=False)(x)
 
+        stream = {}
+        if cfg.residual_float32:
+            stream = {"act_dtype": x.dtype}
+            x = x.astype(jnp.float32)
         block_cls = Block
         if cfg.remat:
             # Validate the policy name up front for EVERY layout — the
@@ -825,14 +1048,26 @@ class TransformerLM(nn.Module):
             # the guard above rejects the combination — must not be passed
             # through a remat-wrapped block.
             extra = {} if cfg.remat else {"decode": decode}
+            if cfg.mla is not None and decode:
+                extra["prefill"] = prefill
             for i in range(cfg.n_layers):
-                x, aux = block_cls(cfg, name=f"block_{i}")(
+                pattern = {"routed": True} if (
+                    cfg.experts is not None and i >= cfg.first_k_dense) else {}
+                x, aux = block_cls(cfg, name=f"block_{i}", **pattern,
+                                   **stream)(
                     x, positions, segment_ids, train, **extra
                 )
                 moe_aux = moe_aux + aux
 
+        hidden = x
         x = _Norm(cfg, name="ln_f")(x)
+        if cfg.residual_float32:
+            x = x.astype(stream["act_dtype"])
         out = Attributes(batch)
+        if decode:
+            # what a draft that reads the target's state is given
+            # (:class:`MTPDraft`): the last block's output, before ln_f
+            out["hidden"] = hidden
         if cfg.fused_ce and not decode:
             if not cfg.tie_embeddings:
                 raise ValueError(
@@ -862,4 +1097,73 @@ class TransformerLM(nn.Module):
             # Blackboard contract: downstream Loss(moe_aux_loss()) trains
             # against it (rocket_tpu.models.moe).
             out["moe_aux"] = moe_aux
+        return out
+
+
+class MTPDraft(nn.Module):
+    """A multi-token-prediction module (DeepSeek-V3's, depth 1 a module)
+    as the draft of a speculative server: not a second language model but
+    one block that reads the target's hidden state.
+
+    At position ``i`` it is given ``hidden`` ``h_i`` (the target's last
+    block output before its final norm, ``batch['hidden']`` of a decode
+    pass) and ``tokens`` ``t_{i+1}`` (the token the target went on to
+    emit), computes ``h' = W_eh [norm_e(Emb(t_{i+1})) | norm_h(h_i)]``,
+    runs ``config.n_layers`` blocks of the target's kind over ``h'`` with
+    a cache of its own, and its ``logits`` (a final norm of its own, then
+    the head) propose ``t_{i+2}``.
+
+    Embedding and head are the target's arrays, handed in with the batch
+    (``embedding`` ``[V, H]``, ``head`` ``[H, V]``; :meth:`tied` takes them
+    out of a :class:`TransformerLM` tree), so this module's parameter tree
+    holds neither and initialises from ``{'tokens'}`` alone.
+    ``reads_hidden`` is what :class:`~rocket_tpu.models.generate.
+    ContinuousBatcher` looks for to run the round that feeds it."""
+
+    config: TransformerConfig
+    reads_hidden = True
+
+    @staticmethod
+    def tied(target_params):
+        """The target's embedding table and head, as this module's batch
+        takes them."""
+        table = target_params["embed"]["embedding"]
+        if "head" in target_params:
+            return {"embedding": table, "head": target_params["head"]["kernel"]}
+        return {"embedding": table, "head": table.T}
+
+    @nn.compact
+    def __call__(self, batch, train: bool = False, decode: bool = False,
+                 prefill: bool = False):
+        cfg = self.config
+        tokens = batch["tokens"]
+        B, S = tokens.shape
+        positions = batch.get("positions")
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        hidden = batch.get("hidden")
+        if hidden is None:                  # init: shapes alone
+            hidden = jnp.zeros((B, S, cfg.hidden), jnp.float32)
+        table = batch.get("embedding")
+        emb = jnp.zeros_like(hidden) if table is None \
+            else jnp.asarray(table)[tokens]
+        act = emb.dtype                     # what the sublayers compute in
+        stream = {"act_dtype": act} if cfg.residual_float32 else {}
+        wide = jnp.float32 if cfg.residual_float32 else act
+        x = PDense(cfg.hidden, logical_axes=(None, "embed"), name="eh_proj")(
+            jnp.concatenate(
+                [_Norm(cfg, name="enorm")(emb.astype(wide)),
+                 _Norm(cfg, name="hnorm")(hidden.astype(wide))],
+                axis=-1).astype(act)).astype(wide)
+        extra = {"prefill": prefill} if cfg.mla is not None and decode else {}
+        for i in range(cfg.n_layers):
+            x, _ = Block(cfg, routed=cfg.experts is not None,
+                         name=f"block_{i}", **stream)(
+                x, positions, None, train, decode=decode, **extra)
+        out = Attributes(batch)
+        out["hidden"] = x
+        final = _Norm(cfg, name="ln_f")(x).astype(act)
+        if batch.get("head") is not None:
+            out["logits"] = jnp.einsum(
+                "bsd,dv->bsv", final, jnp.asarray(batch["head"], act))
         return out

@@ -500,6 +500,47 @@ def get_startup() -> StartupRecord:
     return _STARTUP
 
 
+class RoundRecord:
+    """Process-wide totals of what the serving rounds count on the device:
+    ``rounds``, ``drafted``, ``accepted``, ``routed_slots`` (top-k slots of
+    the live rows' tokens, every routed layer), ``held_slots`` (those that
+    fell on an expert held here) and ``expert_tokens`` (``[layer][held
+    expert]`` tokens received; the target's routed layers, then the
+    draft's).  A batcher adds what it fetched
+    (``ContinuousBatcher.publish_counters``); a reader takes a
+    :meth:`snapshot` after the loop that served is gone."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._totals: Dict[str, Any] = {}
+
+    def add(self, counters: Dict[str, Any]) -> None:
+        import numpy as np
+
+        with self._lock:
+            for name, value in counters.items():
+                value = np.asarray(value, dtype=np.int64)
+                held = self._totals.get(name)
+                self._totals[name] = value if held is None \
+                    or held.shape != value.shape else held + value
+
+    def snapshot(self) -> Dict[str, Any]:
+        with self._lock:
+            return {k: v.tolist() for k, v in self._totals.items()}
+
+    def reset(self) -> None:
+        with self._lock:
+            self._totals = {}
+
+
+_ROUNDS = RoundRecord()
+
+
+def get_rounds() -> RoundRecord:
+    """The process-wide record of the serving rounds' device counters."""
+    return _ROUNDS
+
+
 # -- distributed request tracing --------------------------------------------
 #
 # A TraceContext is stamped on a Request at submit and crosses every

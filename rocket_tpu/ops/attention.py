@@ -47,8 +47,9 @@ def _repeat_kv(k: Array, v: Array, num_q_heads: int):
 def dot_attention(
     q: Array,
     k: Array,
-    v: Array,
+    v: Optional[Array] = None,
     *,
+    v_width: Optional[int] = None,
     causal: bool = True,
     segment_ids: Optional[Array] = None,
     scale: Optional[float] = None,
@@ -66,6 +67,14 @@ def dot_attention(
     KV head is contracted against its ``G`` query heads, so K and V are
     never expanded — a decode step reads the cache once, not ``G`` times.
     ``G = 1`` is plain multi-head attention through the same code.
+
+    V's head width may differ from K's (latent attention expands keys of
+    ``nope + rope`` numbers and values of fewer).  ``v=None`` with
+    ``v_width`` reads the values out of the key rows themselves: each
+    value is the first ``v_width`` numbers of its key (absorbed latent
+    attention, where one cached row a token is both).  The probabilities
+    are then contracted against the whole key row and the tail dropped,
+    so the cache is read as stored and no slice of it is written.
 
     ``q_offset`` positions the queries at ``q_offset .. q_offset+S-1``
     within the key axis — the KV-cache decode case, where K/V span the
@@ -145,9 +154,15 @@ def dot_attention(
         logits = jnp.where(
             kv_mask[:, None, None, None, :].astype(bool), logits, neg
         )
+    if v is None:
+        if v_width is None:
+            raise ValueError("v=None needs v_width (values read from k)")
+        weights = jax.nn.softmax(logits, axis=-1).astype(k.dtype)
+        out = jnp.einsum("bkgqt,btkd->bqkgd", weights, k)[..., :v_width]
+        return out.reshape(B, S, H, v_width)
     weights = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqt,btkd->bqkgd", weights, v)
-    return out.reshape(B, S, H, D)
+    return out.reshape(B, S, H, v.shape[-1])
 
 
 def attend(

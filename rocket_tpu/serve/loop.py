@@ -356,6 +356,11 @@ class ServingLoop:
 
     def close(self) -> None:
         self._flush(force=True)
+        # what the rounds counted on the device goes to the process-wide
+        # record now (observe.trace.get_rounds): readers come after us
+        publish = getattr(self._bat, "publish_counters", None)
+        if publish is not None:
+            publish()
         self.watchdog.close()
         if self.kvpool is not None:
             try:

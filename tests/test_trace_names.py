@@ -149,6 +149,12 @@ KNOWN_JIT_SITES = {
         "ledgered: ContinuousBatcher.step",
     ("models/generate.py", "_spec_admit"):
         "ledgered: ContinuousBatcher.admit",
+    ("models/generate.py", "_mtp_prefill"):
+        "ledgered: ContinuousBatcher.start with a hidden-state draft",
+    ("models/generate.py", "_mtp_round"):
+        "ledgered: ContinuousBatcher.step with a hidden-state draft",
+    ("models/generate.py", "_mtp_admit"):
+        "ledgered: ContinuousBatcher.admit with a hidden-state draft",
     ("models/generate.py", "_spec_import_row"):
         "ledgered: admit_prefilled / kvstore import",
     ("models/generate.py", "_spec_suffix_prefill"):
