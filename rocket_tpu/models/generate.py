@@ -923,7 +923,7 @@ def _spec_prefill(model, draft_model, params, draft_params, prompt,
 
 
 @functools.partial(
-    jax.jit, static_argnums=(0, 1),
+    jax.jit, static_argnums=(0, 1), donate_argnums=(4,),
     static_argnames=("n_draft", "eos_token", "sampled", "top_k"),
 )
 def _spec_round(model, draft_model, params, draft_params, state,
@@ -932,7 +932,14 @@ def _spec_round(model, draft_model, params, draft_params, state,
     """Jitted step-API entry: execute ONE speculative decode round on a
     :func:`_spec_prefill` state.  Module-level jit with the (hashable)
     flax modules static: a serving loop pays one compile per (model,
-    batch shape), then every round is a single cheap dispatch."""
+    batch shape), then every round is a single cheap dispatch.
+
+    ``state`` is donated, here and in every entry that takes a round
+    state and returns its successor (:func:`_spec_admit`,
+    :func:`_spec_import_row`, :func:`_mtp_round`, :func:`_mtp_admit`):
+    the caches are updated where they lie, and the arrays handed in are
+    deleted.  Nothing else is: not the parameters, not a prompt row, not
+    the caches of a :class:`KVHandoff`, which its owner may keep."""
     return _spec_round_impl(
         model, draft_model, params, draft_params, state, temperature,
         n_draft=n_draft, eos_token=eos_token, sampled=sampled,
@@ -941,7 +948,7 @@ def _spec_round(model, draft_model, params, draft_params, state,
 
 
 @functools.partial(
-    jax.jit, static_argnums=(0, 1),
+    jax.jit, static_argnums=(0, 1), donate_argnums=(4,),
     static_argnames=("eos_token", "sampled", "top_k"),
 )
 def _spec_admit(model, draft_model, params, draft_params, state, row,
@@ -999,7 +1006,7 @@ def _spec_admit(model, draft_model, params, draft_params, state, row,
             (rounds, drafted, accepted))
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _spec_import_row(state, row, buf1, n1, d1, c1_t, c1_d):
     """Scatter a handed-off batch-1 row state into row ``row`` of a live
     batch state — the IMPORT half of the prefill/decode lane handoff.
@@ -1203,7 +1210,7 @@ def _mtp_prefill(model, draft_model, params, draft_params, prompt, key=None,
             _zero_counters(model, draft_model))
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1),
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(4,),
                    static_argnames=("eos_token",))
 def _mtp_round(model, draft_model, params, draft_params, state, *,
                eos_token):
@@ -1276,7 +1283,7 @@ def _mtp_round(model, draft_model, params, draft_params, state, *,
             d_next, counters)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1),
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(4,),
                    static_argnames=("eos_token",))
 def _mtp_admit(model, draft_model, params, draft_params, state, row,
                prompt_row, *, eos_token):
@@ -1480,6 +1487,9 @@ def export_kv_row(state, row: int) -> KVHandoff:
     Rank-4 cache leaves (K/V payload and int8 scales alike) slice to
     batch 1; scalar leaves (``cache_index``) copy whole — the exact
     inverse discrimination :func:`_spec_import_row` applies on import.
+    Every leaf of the handoff is a buffer of its own: the next round or
+    admission donates ``state``, and a handoff may outlive it (the prefix
+    store, a parked preemption, a peer).
     Used by :meth:`ContinuousBatcher.prefill_handoff` (row 0 of a fresh
     batch-1 prefill) and available for migrating a live row between
     replicas."""
@@ -1488,7 +1498,8 @@ def export_kv_row(state, row: int) -> KVHandoff:
             "KVHandoff cannot carry the state of a hidden-state draft's "
             "round (its pending draft token and counters) yet")
     (buf, n_tok, done, cache_t, cache_d, _key, _stats) = state
-    sl = lambda a: a[row:row + 1] if _is_cache_payload(a) else a  # noqa: E731
+    sl = lambda a: (a[row:row + 1] if _is_cache_payload(a)  # noqa: E731
+                    else jnp.array(a, copy=True))
     return KVHandoff(
         buf=buf[row:row + 1],
         n_tok=n_tok[row:row + 1],
@@ -1540,6 +1551,12 @@ class ContinuousBatcher:
     reproduces the one-dispatch output bit for bit (tested): both paths
     run the same prefill and round computations in the same order with
     the same key threading.
+
+    ``state`` is DONATED to every round, admission and import: the call
+    writes the caches in place and the arrays of the state it was given
+    are deleted once it is dispatched.  The batcher rebinds ``self.state``
+    to each result; a caller that wants a leaf past the next call takes
+    a copy (:func:`export_kv_row` does, a host read does).
 
     Typical serving loop::
 

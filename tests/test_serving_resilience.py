@@ -583,6 +583,73 @@ class TestChaosTrio:
         assert np.array_equal(done.tokens, _oracle(models, prompts[7]))
         assert loop.health is HealthState.SERVING  # recover window elapsed
 
+    @pytest.mark.parametrize("watchdog_timeout", [None, 30.0],
+                             ids=["inline", "watched"])
+    def test_step_that_raises_after_donating_its_state(
+            self, models, prompts, caplog, watchdog_timeout):
+        """A round that donated its state and then raised leaves
+        ``bat.state`` naming deleted arrays.  The in-flight rows fail with
+        their partial tokens from the loop's host copies (``_carry``), the
+        batcher is rebuilt, the next request is served bit-correct, and no
+        ``Array has been deleted`` reaches a result or the log."""
+        instances = {"n": 0}
+        base_factory = _factory(models)
+        donated = []
+
+        def fault(bat):
+            before = bat.state
+            bat.step()                       # dispatched: ``before`` is gone
+            bat.state = before               # ...and never rebound
+            donated.append(before)
+            raise RuntimeError("device fault after dispatch")
+
+        def factory():
+            bat = base_factory()
+            instances["n"] += 1
+            if instances["n"] == 1:
+                # proxy step #0 is the loop's inline warm step; #1 the
+                # first served round; #2 raises where it would have hung
+                return StuckStepInjector(bat, hang_on=(2,),
+                                         sleep=lambda _s: fault(bat))
+            return bat
+
+        loop = ServingLoop(factory, max_batch=B, queue_capacity=4,
+                           watchdog_timeout=watchdog_timeout,
+                           recover_rounds=2)
+        with caplog.at_level("DEBUG"):
+            for i in range(2):
+                loop.submit(Request(rid=i, prompt=prompts[i]))
+            loop.run_round()                 # proxy step #1: fine
+            assert not loop.drain_results()
+            loop.run_round()                 # proxy step #2: raises
+            results = loop.drain_results()
+
+            (before,) = donated
+            caches = [leaf for leaf in jax.tree_util.tree_leaves(
+                (before[3], before[4])) if leaf.ndim >= 3]
+            assert caches and all(leaf.is_deleted() for leaf in caches)
+            assert instances["n"] == 2       # rebuilt from the factory
+            assert loop.health is HealthState.DEGRADED
+            assert sorted(r.rid for r in results) == [0, 1]
+            for r in results:
+                assert isinstance(r, Failed)
+                assert "device fault after dispatch" in r.reason
+                # one clean round ran first: the partials are the
+                # oracle's own first tokens, read from the host's copy
+                assert r.n_tok > P
+                assert np.array_equal(
+                    r.tokens, _oracle(models, prompts[r.rid])[:r.n_tok])
+
+            loop.submit(Request(rid=7, prompt=prompts[7]))
+            (done,) = loop.run_until_idle()
+            loop.close()
+        assert isinstance(done, Completed) and done.rid == 7
+        assert np.array_equal(done.tokens, _oracle(models, prompts[7]))
+        assert loop.health is HealthState.SERVING
+        assert "has been deleted" not in caplog.text
+        assert not any("has been deleted" in repr(r)
+                       for r in results + [done])
+
     def test_degradation_ladder_engages_and_restores(self, models, prompts):
         """(c) queue pressure engages the ladder (n_draft shrinks, beam
         demotes); draining restores full quality (base n_draft, beam
