@@ -52,8 +52,8 @@ def main():
     parser.add_argument("--accum", type=int, default=2)
     parser.add_argument(
         "--fused", action="store_true",
-        help="fused_qkv + fused_ce (logits-free loss) — the tuned "
-             "single-chip layout from bench.py",
+        help="fused_qkv + fused_ce (logits-free loss); no benchmark "
+             "cell runs either",
     )
     args = parser.parse_args()
 

@@ -493,13 +493,6 @@ class Module(Dispatcher):
         donate = self._donate
         if donate is None:
             donate = getattr(self._runtime, "donate_train_state", True)
-        if donate is None:
-            # Runtime "auto": a completed autotune search's ``donate``
-            # knob applies to real runs with zero re-search (ROADMAP
-            # item 5 feedback loop); no record -> the historical True.
-            from rocket_tpu.tune.store import runtime_default
-
-            donate = runtime_default("donate", default=True)
         donate = bool(donate)
         self._donate = donate  # resolved: later rebuilds stay consistent
         # Capability gate, applied at the jit edge (the resolved intent

@@ -26,9 +26,9 @@ The round trip is a pure memcpy pair (``jax.device_get`` →
 ``jax.device_put``): bitwise exact, and neither call is a ``jax.jit``
 site, so the offload path adds zero trace-cache entries per step.
 
-``synchronous=True`` is the pessimal baseline the bench compares against:
-the same round trip, run inline at ``fetch`` time, fully serialized with
-compute.  The measured gap between the two walls is the overlap win.
+``synchronous=True`` is the serialized baseline: the same round trip,
+run inline at ``fetch`` time.  No benchmark cell runs either (not
+measured).
 """
 
 from __future__ import annotations
@@ -55,7 +55,7 @@ class ZeroOffloader:
         expects it.
     synchronous:
         Run the round trip inline at ``fetch`` instead of on the worker
-        thread (serialized baseline for the overlap bench).
+        thread (the serialized baseline).
     """
 
     def __init__(self, opt_shardings: Any, synchronous: bool = False) -> None:

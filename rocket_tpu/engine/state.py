@@ -120,8 +120,7 @@ def memory_plan(
     Returns ``{'param_bytes', 'opt_bytes', 'other_bytes', 'total_bytes',
     'host_opt_bytes'}`` — what the sharding plan says each device holds at
     steady state (arguments only; activations/temps are the compiler's
-    side).  This is the number the bench ladder reports and the ZeRO guard
-    asserts on.  ``state_specs`` already encodes the ZeRO stage: at stage
+    side).  This is the number the ZeRO tests assert on.  ``state_specs`` already encodes the ZeRO stage: at stage
     2 the grad-accum buffers, and at stage 3 the params themselves, carry
     data-composed specs, so the per-stage memory formula (see the stage
     decision table in ``docs/performance.md``) falls out of the same spec

@@ -57,18 +57,6 @@ ApplyFn = Callable[[Any, Any, jax.Array, Any, bool], Tuple[Any, Any]]
 ObjectiveFn = Callable[[Any], Any]
 
 
-def _resolve_donate(donate: Optional[bool]) -> bool:
-    """``donate=None`` means "auto": consult the persisted autotune record
-    for this host's device/backend (``rocket_tpu.tune.store``), falling
-    back to the historical default of True.  Lazy import — engine.step is
-    imported by everything and must not pull the tune store eagerly."""
-    if donate is not None:
-        return bool(donate)
-    from rocket_tpu.tune.store import runtime_default
-
-    return bool(runtime_default("donate", default=True))
-
-
 STEP_SPAN = "train/step_dispatch"
 _GOODPUT = get_goodput()
 
@@ -244,9 +232,7 @@ def build_train_step(
     saves are safe because Orbax's D2H snapshot completes before ``save``
     returns.  ``donate=False`` (or ``Runtime(donate_train_state=False)``)
     is the escape hatch for callers that must keep consecutive states
-    alive at once.  ``donate=None`` resolves from the persisted autotune
-    record (``rocket_tpu.tune.store.runtime_default("donate")``), True
-    when no record exists.
+    alive at once.  ``donate=None`` is True.
     """
     if gradient_accumulation_steps < 1:
         raise ValueError("gradient_accumulation_steps must be >= 1")
@@ -398,7 +384,7 @@ def build_train_step(
             replacements["micro"] = jnp.zeros((), dtype=jnp.int32)
         return state.replace(**replacements), logs
 
-    donate_argnums = (0,) if _resolve_donate(donate) else ()
+    donate_argnums = (0,) if donate is None or donate else ()
     steps = {"sync": _annotated_dispatch(
         jax.jit(sync_step, donate_argnums=donate_argnums),
         "train_step/dispatch/sync",
@@ -532,7 +518,7 @@ def build_window_step(
             logs,
         )
 
-    donate_argnums = (0,) if _resolve_donate(donate) else ()
+    donate_argnums = (0,) if donate is None or donate else ()
     edge = "train_step/dispatch/window"
     if pipeline_schedule != "gpipe":
         edge = f"{edge}_{pipeline_schedule}"

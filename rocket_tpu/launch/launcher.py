@@ -55,8 +55,9 @@ class Launcher(Dispatcher):
         Parent of experiment dirs (default ``./experiments``).
     goodput:
         Arm the goodput + retrace ledgers for the run (default True —
-        the disarmed-equivalent cost is one branch per dispatch, and the
-        armed overhead is bounded by the bench guard).  The bucket table
+        the disarmed-equivalent cost is one branch per dispatch; armed,
+        it adds no jit trace and no blocking read,
+        ``tests/test_overhead_counts.py::TestGoodputGuard``).  The bucket table
         is logged at launch end and persisted as ``<project>/goodput.json``.
     metrics_port:
         Opt-in: serve the Prometheus-text ``/metrics`` endpoint on this

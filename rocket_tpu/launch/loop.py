@@ -39,8 +39,7 @@ point) and published as ``attrs.looper.drained_logs`` so the final k
 steps' logs reach observers, and Throughput credits its remaining
 in-flight steps off it instead of under-counting k steps per cycle.  The per-iteration **host dispatch gap** (host time spent outside
 the backpressure wait — the time the chip could sit idle between steps) is
-measured every iteration and exposed as :attr:`Looper.last_dispatch_gap_ms`
-for the bench ladder and the async-loop regression guard.
+measured every iteration and exposed as :attr:`Looper.last_dispatch_gap_ms`.
 """
 
 from __future__ import annotations

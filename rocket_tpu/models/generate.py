@@ -118,8 +118,7 @@ def decode_cache_shapes(model: Any, params: Any, prompt: jax.Array,
     cache variables take their dtype from the computed k/v, so decoding
     with bf16-cast weights needs a bf16 cache — a fresh init would make
     an f32 one and ``dynamic_update_slice`` rejects the dtype mismatch.
-    eval_shape costs nothing at runtime.  Also the bytes model for the
-    decode bench's MBU (``bench.bench_gpt2_decode``).  ``extra`` is what
+    eval_shape costs nothing at runtime.  ``extra`` is what
     else the model's batch holds (a hidden-state draft is given the
     target's hidden states, embedding and head, and its cache takes their
     type)."""
@@ -425,8 +424,9 @@ def speculative_generate(
     in ONE forward — the output is EXACTLY ``generate(model, params,
     prompt, ..., temperature=0.0)``, but the target's weights are read
     once per accepted block instead of once per token.  Decode is
-    bandwidth-bound (``bench.bench_gpt2_decode``'s MBU), so accepted
-    blocks of ``j`` tokens cut the dominant HBM term by ``~j×``.
+    bandwidth-bound (the serving cells' ``decode_round_roofline.*`` is
+    bound by bytes), so accepted blocks of ``j`` tokens cut the
+    dominant HBM term by ``~j×``.
 
     Batch size must be 1 (acceptance length is data-dependent per row,
     and the KV caches keep one scalar frontier).  Both models must share
@@ -2288,7 +2288,7 @@ def beam_search_cached(
     Decode work per output token drops from one ``P + T``-long forward
     to one single-token forward: the prompt's K/V are computed once and
     read T times, which is the whole point of serving from a cache
-    (decode is bandwidth-bound — see ``bench.bench_gpt2_decode``).
+    (decode is bandwidth-bound).
 
     Returns ``(tokens [B, P + T], scores [B])``, matching
     :func:`beam_search` on the same inputs (tested bit-for-bit on the

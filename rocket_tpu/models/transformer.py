@@ -99,9 +99,8 @@ class TransformerConfig:
     # (O(S*window) work at long S) and the decode cache masks by
     # position, so generation beyond the window works unchanged.
     attention_window: Optional[int] = None
-    # None = shape-aware measured-best flash tiling (ops.flash.auto_blocks:
-    # 512/1024 at S>=1024, shrinking with S) — the round-4 silicon sweep's
-    # optimum, now the library default rather than a bench-only tune.
+    # None = shape-aware flash tiling (ops.flash.auto_blocks: 512/1024
+    # at S>=1024, shrinking with S).
     attention_block_q: Optional[int] = None
     attention_block_k: Optional[int] = None
     # One [hidden, (H+2*KV)*D] projection instead of three separate q/k/v
@@ -116,8 +115,7 @@ class TransformerConfig:
     weights_int8: bool = False
     # Int8 KV cache for decode (ops.quant.quantize_kv_page): cache pages
     # are stored int8 with a per-(row, slot, kv-head) f32 scale, halving
-    # the bytes the bandwidth-bound decode loop re-reads per token (the
-    # MBU denominator in bench_gpt2_decode shrinks accordingly).  Keys
+    # the bytes the bandwidth-bound decode loop re-reads per token.  Keys
     # and values are quantized on cache WRITE and dequantized to the
     # query dtype on read, so attention math is unchanged bf16; the
     # scale rides the cache as a rank-4 ``[B, slots, KV, 1]`` leaf, so
@@ -149,9 +147,8 @@ class TransformerConfig:
     # row's write offset) instead of the shared scalar ``cache_index``.
     # Batched speculative decoding needs this — rows accept different
     # draft counts, so their frontiers diverge.  Off by default: the
-    # uniform-frontier path lowers to ONE dynamic_update_slice (the
-    # measured decode-bench path) where per-row writes become a vmapped
-    # scatter.  The param tree and cache shapes are identical either
+    # uniform-frontier path lowers to ONE dynamic_update_slice where
+    # per-row writes become a vmapped scatter.  The param tree and cache shapes are identical either
     # way, so the same params/cache work under both settings.
     decode_per_row: bool = False
     causal: bool = True  # False -> bidirectional encoder (ViT)

@@ -14,7 +14,7 @@ overhead; 2605.23066 does the same for checkpointing):
   edge has gone warm — an UNEXPECTED retrace escalates into one
   :class:`~rocket_tpu.observe.recorder.FlightRecorder` dump naming the
   executable and the offending shapes.  This promotes the test-only
-  "zero new jit traces" bench guards into a runtime sentinel.
+  "zero new jit traces" assertions into a runtime sentinel.
 - :class:`GoodputLedger` — partitions run wall time into named buckets
   (productive step, compile, host-blocked, data-starved, checkpoint,
   watchdog rebuild, preemption loss).  Buckets plus the explicit

@@ -47,14 +47,14 @@ MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def auto_blocks(S: int) -> tuple:
-    """Shape-aware default tiling, encoding the measured-on-silicon best
-    (v5e round-4 sweep, ``experiments/bench_runs.jsonl``): blocks
-    512/1024 ran GPT-2 at 0.459 MFU where the old fixed 128/128 default
-    measured 0.223 — silicon knowledge belongs in the library, not a
-    bench tune dict (VERDICT r4 next #5).  Picks the largest measured
-    block sizes that tile ``S`` exactly; when none divide, falls back to
-    ``min(256, S)`` / ``min(512, S)`` — the pre-round-5 config defaults,
-    so flash-eligible irregular shapes keep the kernel instead of
+    """Shape-aware default tiling: the largest of 512/256 query rows
+    and 1024/512/256 key rows that tile ``S`` exactly.  512/1024 at
+    S=1024 is what ``gpt2m-train-1chip`` runs, and its
+    ``train_flash_fwd_roofline``, ``train_flash_dq_roofline`` and
+    ``train_flash_dkv_roofline`` metrics measure the three kernels.
+    (Keep this file's line count: ``tools/hlo_hashes.py`` hashes line numbers.)
+    When none divide, falls back to ``min(256, S)`` / ``min(512, S)``, so
+    flash-eligible irregular shapes keep the kernel instead of
     rerouting to dot attention: ViT-B/16's S=197 runs it as one 197-row
     block, which Mosaic compiles although 197 is not a multiple of 8 and
     which matches ``dot_attention`` on a v5e (``chip_smoke.py``)."""

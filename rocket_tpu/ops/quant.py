@@ -1,8 +1,8 @@
 """Int8 weight-only quantization for bandwidth-bound decode.
 
 KV-cache decode re-reads every weight matrix once per emitted token, so
-single-chip decode throughput is HBM-bandwidth-bound (see
-``bench.bench_gpt2_decode``'s MBU metric).  Storing weights as int8 with a
+single-chip decode throughput is HBM-bandwidth-bound (no benchmark
+cell runs the int8 path yet).  Storing weights as int8 with a
 per-output-channel scale halves the bytes the matmuls pull per token —
 the serving-world W8A16 recipe, done the TPU way:
 

@@ -16,8 +16,7 @@ the caller's loss.  This module is the scaled form from the MPMD paper:
   retrace sentinel covers them (the edges are shape-polymorphic across
   configs, so they are exempt from the zero-retrace assertion);
 - **explicit transport**: boundary activations/cotangents move as tagged
-  messages over a :class:`QueueTransport` (in-process, for tests and the
-  bench) or a :class:`SocketEndpoint` (TCP loopback for the real
+  messages over a :class:`QueueTransport` (in-process, for tests) or a :class:`SocketEndpoint` (TCP loopback for the real
   2-process test; the same framing serves DCN between pod slices —
   ``multihost.stage_process_groups`` maps processes to stages);
 - **per-stage 1F1B scheduler**: :func:`stage_schedule` emits each
@@ -27,8 +26,9 @@ the caller's loss.  This module is the scaled form from the MPMD paper:
   ``max_live`` and asserted by the tests, not just derived;
 - **goodput attribution**: every second a stage spends blocked on a recv
   lands in the goodput ledger as a ``pipeline/bubble/stage<p>`` bucket —
-  bubble fraction becomes a measured, guardable number per stage (the
-  bench guard asserts interleaved(v=2) < gpipe on the same config).
+  bubble fraction becomes a measured, guardable number per stage
+  (``tests/test_mpmd.py`` asserts interleaved(v=2) < gpipe on the same
+  config).
 
 Bit-equality contract: a run accumulates each chunk's parameter-gradient
 contributions in ascending microbatch order and divides the loss/grad
@@ -585,8 +585,8 @@ def run_lockstep(
     A stage's wait seconds are ``idle_rounds × mean measured item
     seconds`` — structural idleness priced at that stage's own measured
     compute rate — and are routed to the goodput ledger's
-    ``pipeline/bubble/stage<p>`` bucket, which is what the bench guard
-    compares across schedules.  Loss/grads follow the same order
+    ``pipeline/bubble/stage<p>`` bucket, which is what
+    ``tests/test_mpmd.py`` compares across schedules.  Loss/grads follow the same order
     contract as the other drivers (bit-equal to :func:`run_reference`).
     """
     leaves = jax.tree_util.tree_flatten(micros)[0]

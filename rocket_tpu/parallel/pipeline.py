@@ -129,8 +129,7 @@ def schedule_plan(
     micro_act_bytes: int = 0,
 ) -> dict:
     """Analytic tick/residency accounting for a pipeline schedule — the
-    ``memory_plan()``-style numbers the bench records and the residency
-    guard asserts on (bytes from shapes and schedule structure, not
+    ``memory_plan()``-style numbers the residency test asserts on (bytes from shapes and schedule structure, not
     measured allocations).
 
     Returns ``ticks_forward`` (stage-granularity forward ticks — an

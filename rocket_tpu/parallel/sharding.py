@@ -18,7 +18,7 @@ explicit, composable GSPMD shardings.  Two layers of naming:
 :func:`specs_for_state` combines both into a :class:`ShardingPlan`: the
 single source of truth consumed by ``core/module.py`` (materialization),
 the ``engine/step.py`` train step (ZeRO constraints), ``persist/integrity``
-(manifest stamps + ``check_reshard`` restore targets) and ``bench.py`` /
+(manifest stamps + ``check_reshard`` restore targets) and
 ``Module.memory_plan()`` (per-device byte accounting).  Optimizer-state
 subtrees that are *structural mirrors* of the params (Adam ``mu``/``nu``,
 Muon momenta, EMA shadows) inherit the param specs positionally — this
@@ -66,7 +66,7 @@ moments into 3.1 GB per device — 40.3 GB of step arguments (provably over
 a 32 GB v4 chip) down to 15.7 GB (AOT-compiles within the envelope); the
 worked example lives in ``docs/performance.md`` and is pinned by
 ``tests/test_ladder_shapes.py::test_llama2_7b_full_finetune_zero1_fits_v4_hbm``
-and ``tests/test_bench_guard.py::TestZeroGuard``.
+and ``tests/test_overhead_counts.py::TestZeroGuard``.
 
 **ZeRO stages 2 and 3** extend the same composition through the rest of
 the state:
@@ -642,7 +642,7 @@ def specs_for_state(
     construction (the plan's ``*_shardings`` fields are ``None``) so the
     spec/byte arithmetic also runs against a *hypothetical* mesh — any
     object with a ``.shape`` mapping of axis sizes, e.g. a pod shape this
-    host doesn't have.  ``bench.py``'s 30B memory-plan rows use this.
+    host doesn't have.
     """
     if zero_stage not in ZERO_STAGES:
         raise ValueError(

@@ -10,7 +10,7 @@ iteration boundary.  This module closes that gap with a two-phase design:
    leaves the device→host copy is started with ``copy_to_host_async()`` —
    the same zero-sync readback primitive the async metrics loop uses — and
    the arrays themselves are kept by reference.  No device sync, no jit
-   retrace (asserted by ``TestElasticGuard`` in the bench guard).  When
+   retrace (``tests/test_overhead_counts.py::TestElasticGuard``).  When
    buffer donation is live (non-CPU backends: the next step's dispatch
    invalidates the old state's buffers) the staged leaves are materialized
    to numpy at capture instead — that is the one configuration where

@@ -136,13 +136,10 @@ class Runtime:
         self.skip_nonfinite_updates = False
         # Run-level escape hatch for train-state buffer donation: Modules
         # that were not given an explicit ``donate=`` resolve it from here
-        # at step-build time (engine.step donate_argnums).  None = "auto":
-        # a persisted autotune record's ``donate`` knob applies
-        # (rocket_tpu.tune.store.runtime_default), defaulting to True
-        # when no record exists — identical behavior to the old
-        # hardcoded True until a search has actually run.
+        # at step-build time (engine.step donate_argnums).  Told nothing
+        # (None), the runtime says True.
         self.donate_train_state = (
-            None if donate_train_state is None else bool(donate_train_state)
+            True if donate_train_state is None else bool(donate_train_state)
         )
         # Pending resume request (set by Launcher.resume): Attributes with
         # ``path`` and ``load_capsules``.  Capsules with lazily-materialized

@@ -1,57 +1,21 @@
-"""Search-driven autotuner (ROADMAP item 5).
+"""What the programs use at start-up, and nothing else:
 
-The bench ladder measures; this package closes the loop:
-
-- :mod:`rocket_tpu.tune.space` — a declarative tune space (batch, flash
-  block sizes, remat policy, ``scan_layers``, ``fused_qkv``/``fused_ce``,
-  ``ce_chunk``, donation, prefetch depth, mesh layout);
-- :mod:`rocket_tpu.tune.cost_model` — an analytical roofline (FLOPs +
-  HBM bytes over device peaks, the same plumbing ``bench.py`` reports
-  MFU/MBU with) that RANKS candidates before anything is measured;
-- :mod:`rocket_tpu.tune.search` — cost-model-seeded successive halving
-  over short timed probes through ``bench.py``, each probe a fresh
-  subprocess so a bad point (miscompile, OOM, hang) cannot poison the
-  run;
-- :mod:`rocket_tpu.tune.store` — per-(model, device, batch, backend)
-  JSON records under ``experiments/tunes/`` with a :func:`best_tune`
-  lookup that ``bench.py``, ``Module``, and the engine step consult as
-  defaults — a completed search changes real runs with zero re-search;
-- :mod:`rocket_tpu.tune.compile_cache` — the warm-start tier's disk
-  layer: arms JAX's persistent compilation cache at a per-host dir and
-  serializes AOT executables where the backend supports it;
+- :mod:`rocket_tpu.tune.compile_cache` — arms JAX's persistent
+  compilation cache, counts its hits and misses for the start-up record,
+  and serializes AOT executables where the backend supports it;
 - :mod:`rocket_tpu.tune.warmup` — :class:`WarmupPlan`: explicit
   ``lower().compile()`` of the serving hot path's fixed-shape edges
   before the first request (and a built ``Module``'s train step),
   against that cache.
 
-CLI: ``python -m rocket_tpu.tune --help``.
+Nothing here searches, measures or keeps a record: the chip's numbers
+come from ``benchmark/run.py``.
 """
 
 from rocket_tpu.tune.compile_cache import (  # noqa: F401
     cache_dir,
     enable_compile_cache,
     hit_count,
-)
-
-from rocket_tpu.tune.cost_model import (  # noqa: F401
-    device_peak_flops,
-    device_peak_hbm_bytes,
-    gpt2_step_flops,
-    predict_point,
-)
-from rocket_tpu.tune.search import autotune, successive_halving  # noqa: F401
-from rocket_tpu.tune.space import (  # noqa: F401
-    TuneParam,
-    TuneSpace,
-    gpt2_space,
-)
-from rocket_tpu.tune.store import (  # noqa: F401
-    best_tune,
-    canonical_tune_key,
-    load_tunes,
-    runtime_default,
-    save_tune,
-    tune_dir,
 )
 from rocket_tpu.tune.warmup import (  # noqa: F401
     WarmupPlan,

@@ -247,10 +247,14 @@ def save_aot(key: str, compiled: Any) -> bool:
     try:
         from jax.experimental import serialize_executable
         payload = serialize_executable.serialize(compiled)
+        # the ids of the devices it was compiled for: a reload runs on
+        # those, not on every device the process has
+        devices = [d.id for d in
+                   compiled.runtime_executable().local_devices()]
         os.makedirs(os.path.dirname(path), exist_ok=True)
         tmp = path + ".tmp"
         with open(tmp, "wb") as f:
-            pickle.dump(payload, f)
+            pickle.dump((payload, devices), f)
         os.replace(tmp, path)
     except Exception:
         with _lock:
@@ -272,8 +276,11 @@ def load_aot(key: str) -> Optional[Any]:
     try:
         from jax.experimental import serialize_executable
         with open(path, "rb") as f:
-            payload = pickle.load(f)
-        compiled = serialize_executable.deserialize_and_load(*payload)
+            payload, devices = pickle.load(f)
+        by_id = {d.id: d for d in jax.local_devices()}
+        # a KeyError (a device this process lacks) falls through too
+        compiled = serialize_executable.deserialize_and_load(
+            *payload, execution_devices=[by_id[i] for i in devices])
     except Exception:
         with _lock:
             _state["aot_fallthrough"] += 1

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 
 from rocket_tpu.tune import compile_cache
-from rocket_tpu.tune.store import runtime_default
 
 logger = logging.getLogger("rocket_tpu.warmup")
 
@@ -76,18 +75,10 @@ def plan_for_batcher(bat: Any, max_batch: int,
                      prompt_lens: Tuple[int, ...] = (),
                      aot: bool = True) -> WarmupPlan:
     """Derive the plan from a live :class:`ContinuousBatcher`: the
-    configured ``n_draft`` plus any tune-record draft ladder
-    (``runtime_default("n_draft")``) and explicit extras.
+    configured ``n_draft`` plus the explicit ``extra_drafts``.
     ``prompt_lens`` rides through for deployments that know their
     request shapes (the admit edge is per-prompt-length)."""
-    drafts = [int(bat.n_draft)]
-    tuned = runtime_default("n_draft", None)
-    if tuned is not None:
-        try:
-            drafts.append(int(tuned))
-        except (TypeError, ValueError):
-            pass
-    drafts.extend(int(n) for n in extra_drafts)
+    drafts = [int(bat.n_draft), *(int(n) for n in extra_drafts)]
     seen: Dict[int, None] = {}
     for n in drafts:
         if n > 0:

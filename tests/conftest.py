@@ -72,7 +72,7 @@ def pytest_configure(config):
         "markers",
         "kvcache: prefix-cache tier tests (rocket_tpu.serve.kvstore — "
         "page hashing, LRU eviction, cached-prefix bit-equality, session "
-        "affinity; see docs/performance.md \"Prefix cache\")",
+        "affinity)",
     )
     config.addinivalue_line(
         "markers",
@@ -86,9 +86,8 @@ def pytest_configure(config):
         "markers",
         "kvpool: fleet KV page-tier tests (rocket_tpu.serve.kvpool — "
         "binary page codec, pool push/fetch/NACK, cross-process page "
-        "transfer, disaggregated prefill; see docs/performance.md "
-        "\"Fleet KV tier\"; spawn-heavy cases live in "
-        "tests/test_kvpool_proc.py on the heavy tail)",
+        "transfer, disaggregated prefill; spawn-heavy cases live in "
+        "tests/test_kvpool_proc.py)",
     )
     config.addinivalue_line(
         "markers",
@@ -96,71 +95,22 @@ def pytest_configure(config):
         "/ rocket_tpu.serve feed|loop swap path — verified publication, "
         "live hot-swap, rejected torn publish, bounded rollback, "
         "kill-mid-swap heal; see docs/reliability.md \"Live weight "
-        "updates\"; spawn-heavy acceptance cases live on the heavy tail)",
+        "updates\")",
     )
     config.addinivalue_line(
         "markers",
         "tenants: multi-tenant serving tests (rocket_tpu.serve "
-        "queue/loop/loadgen — SLO classes, weighted-fair admission, "
-        "batch preemption with bit-equal resume, trace-replay harness; "
-        "see docs/reliability.md \"Multi-tenant serving\"; spawn-heavy "
-        "cases live in tests/test_tenants_proc.py on the heavy tail)",
+        "queue/loop, driven by serve/loadgen's seeded traces — SLO "
+        "classes, weighted-fair admission, batch preemption with "
+        "bit-equal resume; see docs/reliability.md \"Multi-tenant "
+        "serving\"; spawn-heavy cases live in tests/test_tenants_proc.py)",
     )
     config.addinivalue_line(
         "markers",
-        "warmstart: warm-start tier tests (rocket_tpu.tune "
-        "compile_cache/warmup — persistent compile cache, AOT "
-        "executable reuse, pre-warmed/standby spawns; see "
-        "docs/performance.md \"Warm start & compile cache\"; "
-        "spawn-heavy cases ride the heavy tail of collection ordering)",
+        "warmstart: warm-start tests (rocket_tpu.tune.compile_cache and "
+        "rocket_tpu.tune.warmup, all the package holds — persistent "
+        "compile cache, AOT executable reuse, pre-warmed/standby spawns)",
     )
-
-
-# Fast-first ordering: the handful of files below carry the long
-# compile-heavy tails (full-model forwards, pipeline schedules, real
-# subprocess probes).  Running them LAST means the budgeted tier-1
-# sweep fails fast on the broad cheap coverage, and on a slow shared
-# host a timeout truncates into the heavy tail instead of silently
-# dropping whole subsystems.  Stable sort — relative order inside each
-# group is unchanged, and an untimed run still executes everything.
-_HEAVY_TAIL = (
-    "test_models.py",
-    "test_pipeline_parallel.py",
-    "test_checkpoint.py",
-    "test_tune.py",
-    "test_multi_optimizer.py",
-    "test_ladder_shapes.py",
-    "test_mpmd.py",
-    "test_procfleet.py",
-    "test_kvpool_proc.py",
-    "test_trainserve.py",
-    "test_tenants_proc.py",
-    "test_tracing_proc.py",
-    "test_zero_offload.py",
-)
-
-
-# The newest spawn-heavy file runs LAST of all: when the timed tier-1
-# budget truncates, the cut lands on the newest coverage first and the
-# long-standing seed suite still runs to completion.
-_TAIL_END = ("test_trainserve.py", "test_tenants_proc.py",
-             "test_tracing_proc.py", "test_zero_offload.py")
-
-
-def pytest_collection_modifyitems(config, items):
-    # warmstart-marked items spawn worker subprocesses — heavy-tail them
-    # alongside the listed files so tier-1 truncation behavior holds.
-    def tier(item):
-        name = item.fspath.basename
-        if name in _TAIL_END:
-            # _TAIL_END is newest-last: truncation cuts newest coverage
-            # first regardless of alphabetical collection order.
-            return 2 + _TAIL_END.index(name)
-        if name in _HEAVY_TAIL or item.get_closest_marker("warmstart"):
-            return 1
-        return 0
-
-    items.sort(key=tier)
 
 
 @pytest.fixture(scope="session")

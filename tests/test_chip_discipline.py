@@ -3,13 +3,14 @@
 - ``chip_smoke.py`` refuses anything but a TPU: on the CPU it exits
   non-zero at once, names the platform it found and prints no result —
   also when it is the only file of the repo in its directory;
-- an unknown device kind has no peak: the cost model raises and names it,
-  and the live MFU/MBU gauges emit nothing;
-- a chip belongs to one process: the tune search's parent, whose probe
-  children need the chip, never initialises a JAX backend itself.
+- a chip belongs to one process: ``chip_smoke.py`` touches JAX itself,
+  so it starts no process beside it.
+
+(An unknown device kind has no peak: the benchmark's own table,
+``benchmark/peaks.json``, and its refusal are ``tests/test_benchmark/``'s.)
 """
 
-import json
+import ast
 import os
 import shutil
 import subprocess
@@ -20,9 +21,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from rocket_tpu.tune import cost_model  # noqa: E402
-from rocket_tpu.tune import search  # noqa: E402
-from rocket_tpu.tune.space import TuneParam, TuneSpace  # noqa: E402
 
 
 # -- chip_smoke.py refuses the CPU -------------------------------------------
@@ -54,8 +52,6 @@ def test_chip_smoke_alone_in_a_directory_fails_without_a_result(tmp_path):
 def test_chip_smoke_imports_nothing_of_the_repo_before_the_device_check():
     # alone on a machine WITH a chip it must fail too: everything it does
     # after the device check needs the package beside it
-    import ast
-
     with open(os.path.join(REPO, "chip_smoke.py")) as fh:
         tree = ast.parse(fh.read())
     top_level = {
@@ -64,74 +60,17 @@ def test_chip_smoke_imports_nothing_of_the_repo_before_the_device_check():
         for alias in (node.names if isinstance(node, ast.Import)
                       else [ast.alias(node.module or "")])
     }
-    assert "rocket_tpu" not in top_level and "bench" not in top_level
+    assert "rocket_tpu" not in top_level
     assert "jax" in top_level
 
 
-# -- no peak for a kind the table does not hold ------------------------------
+# -- a chip belongs to one process --------------------------------------------
 
 
-@pytest.mark.parametrize("peak", [cost_model.device_peak_flops,
-                                  cost_model.device_peak_hbm_bytes])
-@pytest.mark.parametrize("kind", ["cpu", "TPU v9 hyper"])
-def test_unknown_device_kind_raises_and_names_it(peak, kind):
-    with pytest.raises(ValueError, match=kind):
-        peak(kind)
-
-
-def test_local_cpu_device_has_no_peak(devices):
-    # device_kind=None asks the local device — a CPU here
-    with pytest.raises(ValueError, match="cpu"):
-        cost_model.device_peak_flops()
-
-
-# -- the tune search's parent stays off the chip ------------------------------
-
-
-def _no_backend(monkeypatch):
-    import jax
-
-    def touched(*args, **kwargs):
-        raise AssertionError("the search parent initialised a JAX backend")
-
-    for name in ("devices", "local_devices", "default_backend",
-                 "device_count"):
-        monkeypatch.setattr(jax, name, touched)
-
-
-def test_search_with_stub_probe_never_touches_jax_in_the_parent(
-        monkeypatch, tmp_path):
-    monkeypatch.setenv("ROCKET_TPU_TUNE_DIR", str(tmp_path / "tunes"))
-    _no_backend(monkeypatch)
-    asked = []
-
-    def identity_child():
-        asked.append(1)
-        return {"device": "TPU v5 lite", "backend": "tpu"}
-
-    monkeypatch.setattr(search, "device_identity", identity_child)
-    space = TuneSpace((TuneParam("p", ({"batch": 8}, {"batch": 16})),))
-    record = search.autotune(
-        space=space, seed_k=2, rung_steps=(2,), save=True,
-        probe=lambda tune, *a: {"value": 100.0 * tune["batch"]},
-        log=lambda s: None,
-    )
-    # one identity child for the whole search, stamped on the record and
-    # used for the roofline seeding — no jax.devices() anywhere
-    assert asked == [1]
-    assert record["device"] == "TPU v5 lite" and record["backend"] == "tpu"
-    assert record["tune"]["batch"] == 16 and record["probes"] == 2
-    # the zero re-search contract holds off-backend too
-    again = search.autotune(space=space)
-    assert again["probes"] == 0 and again["reused"] is True
-
-
-@pytest.mark.parametrize("program", ["bench.py", "chip_smoke.py"])
+@pytest.mark.parametrize("program", ["chip_smoke.py"])
 def test_chip_holding_programs_spawn_no_child(program):
-    # bench.py and chip_smoke.py touch JAX themselves, so they hold the
-    # chip: neither may start a process (a probe, a worker) beside it
-    import ast
-
+    # chip_smoke.py touches JAX itself, so it holds the chip: it may not
+    # start a process (a probe, a worker) beside it
     with open(os.path.join(REPO, program)) as fh:
         source = fh.read()
     imported = {
@@ -143,10 +82,3 @@ def test_chip_holding_programs_spawn_no_child(program):
     }
     assert not imported & {"subprocess", "multiprocessing"}, imported
     assert "ProcReplica" not in source and "os.fork" not in source
-
-
-def test_device_identity_asks_a_child_process(monkeypatch):
-    _no_backend(monkeypatch)
-    ident = search.device_identity()  # the child inherits JAX_PLATFORMS=cpu
-    assert ident == {"device": "cpu", "backend": "cpu"}
-    json.dumps(ident)  # plain data: it is stamped into the tune record

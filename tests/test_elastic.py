@@ -282,6 +282,16 @@ def test_check_reshard_shape_mismatch_is_model_change(devices):
         integrity.check_reshard(manifest, {"module_0": target})
 
 
+class _TargetLeaf:
+    """A hand-built restore target's leaf: the three attributes
+    ``check_reshard`` reads.  ``jax.ShapeDtypeStruct`` cannot stand in for
+    the two illegal cases below — since it validates its sharding at
+    construction it refuses both before ``check_reshard`` is asked."""
+
+    def __init__(self, shape, dtype, sharding):
+        self.shape, self.dtype, self.sharding = shape, dtype, sharding
+
+
 def test_check_reshard_missing_axis_names_leaf_and_remedy(devices):
     import jax
 
@@ -296,8 +306,8 @@ def test_check_reshard_missing_axis_names_leaf_and_remedy(devices):
         def __init__(self, mesh, spec):
             self.mesh, self.spec = mesh, spec
 
-    leaf = jax.ShapeDtypeStruct((8, 4), np.float32)
-    leaf.sharding = FakeSharding(mesh, jax.sharding.PartitionSpec("bogus"))
+    leaf = _TargetLeaf((8, 4), np.float32, FakeSharding(
+        mesh, jax.sharding.PartitionSpec("bogus")))
     with pytest.raises(TopologyMismatch, match=r"w.*'bogus'.*size 1 is"):
         integrity.check_reshard(manifest, {"module_0": {"state": {"w": leaf}}})
 
@@ -307,13 +317,12 @@ def test_check_reshard_rank_overflow(devices):
 
     mesh = _mesh(2)
     manifest = _manifest_for({"w": np.zeros((8,), np.float32)}, mesh)
-    target = {"state": {"w": jax.ShapeDtypeStruct(
-        (8,), np.float32,
-        sharding=jax.sharding.NamedSharding(
-            mesh, jax.sharding.PartitionSpec(None, "data")),
-    )}}
+    # a spec the mesh accepts, with one entry more than the leaf has
+    # dimensions
+    leaf = _TargetLeaf((8,), np.float32, jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(None, "data")))
     with pytest.raises(TopologyMismatch, match=r"w.*rank-1"):
-        integrity.check_reshard(manifest, {"module_0": target})
+        integrity.check_reshard(manifest, {"module_0": {"state": {"w": leaf}}})
 
 
 def test_check_reshard_uneven_division_is_legal(devices):

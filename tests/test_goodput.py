@@ -1,7 +1,7 @@
 """Goodput ledger, retrace sentinel, and metrics export (ISSUE 9).
 
-Covers the tentpole's acceptance criteria beyond the overhead guards in
-tests/test_bench_guard.py::TestGoodputGuard:
+Covers the tentpole's acceptance criteria beyond the trace and read
+counts in tests/test_overhead_counts.py::TestGoodputGuard:
 
 - goodput buckets (plus the explicit ``unattributed`` remainder) sum to
   the measured wall window within 1% on a real instrumented Looper run;

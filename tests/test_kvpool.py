@@ -363,27 +363,28 @@ class TestLoopPoolTier:
         with pytest.raises(ValueError):
             ServingLoop(lambda: None, max_batch=1, kvpool=object())
 
-    def test_armed_but_idle_zero_traces_and_low_overhead(self):
-        import time as _time
+    def test_armed_but_idle_zero_traces_reads_and_dispatches(self):
         from rocket_tpu.testing.workers import B as WB, P as WP
         rng = np.random.default_rng(3)
         prompts8 = rng.integers(1, 60, size=(WB, WP)).astype(np.int32)
         rounds = 8
 
-        def round_times(loop):
+        def round_counts(loop):
+            """Blocking host reads and rounds dispatched over `rounds`
+            decode rounds, as `ServeCounters` has them."""
             for i in range(WB):
                 loop.submit(Request(rid=i, prompt=prompts8[i]))
             loop.run_round()  # admits + settles
-            out = []
+            c = loop.counters
+            before = c.host_fetches, c.rounds
             for _ in range(rounds):
-                t0 = _time.perf_counter()
                 loop.run_round()
-                out.append(_time.perf_counter() - t0)
+            out = c.host_fetches - before[0], c.rounds - before[1]
             loop.run_until_idle()
             return out
 
         bare_loop = _tiny_loop(kvstore_page_tokens=3)
-        bare = round_times(bare_loop)
+        bare = round_counts(bare_loop)
         bare_loop.close()
 
         pool = KVPagePool(page_tokens=3)
@@ -391,18 +392,17 @@ class TestLoopPoolTier:
             traces_before = _spec_round._cache_size()
             armed_loop = _tiny_loop(kvstore_page_tokens=3,
                                     kvpool_addr=pool.address)
-            armed = round_times(armed_loop)
+            armed = round_counts(armed_loop)
             # the pool added ZERO traced step bodies
             assert _spec_round._cache_size() == traces_before
             armed_loop.close()
         finally:
             pool.close()
-        b = float(np.median(bare))
-        w = float(np.median(armed))
-        # <5% relative plus an absolute floor for scheduler noise on
-        # tiny CPU rounds — the pool client is untouched mid-decode
-        assert w <= b * 1.05 + 5e-4, (
-            f"pool-armed round {w * 1e3:.3f}ms vs bare {b * 1e3:.3f}ms")
+        # Was "pool-armed round <= 1.05 x bare" on the clock.  The pool
+        # client is untouched mid-decode: a round that it slowed would
+        # read the device or dispatch once more, and it does neither.
+        assert armed == bare
+        assert armed[1] == rounds
 
 
 # -- export / merge semantics --------------------------------------------

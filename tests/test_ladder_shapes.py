@@ -122,8 +122,8 @@ def test_resnet50_shape_plan(devices):
 
 
 def test_gpt2_124m_fused_bench_layout_plan(devices):
-    """The tuned single-chip bench layout (bench.py GPT2_TUNE with
-    fused_qkv + fused_ce, padded vocab) at REAL scale: correct param count
+    """GPT-2 124M with fused_qkv + fused_ce and a padded vocabulary
+    (a layout no benchmark cell runs) at REAL scale: correct param count
     and a clean sharding plan, traced at zero memory cost."""
     cfg = TransformerConfig.gpt2_124m(
         vocab_size=50304, fused_qkv=True, fused_ce=True,

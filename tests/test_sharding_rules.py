@@ -528,7 +528,7 @@ class TestSpecsForState:
 
     def test_make_shardings_false_prices_hypothetical_mesh(self):
         """Spec arithmetic must run against a mesh this host doesn't have
-        (bench.py's 30B memory-plan rows): any object with a ``.shape``
+        (a pod shape's memory plan): any object with a ``.shape``
         mapping works when NamedSharding construction is skipped."""
         class PodMesh:
             shape = {"data": 64, "tensor": 1}

@@ -218,6 +218,10 @@ class TestHotLoopSpansReachTheProfile:
     def test_trainer_spans_with_the_tracer_disarmed(self, tmp_path, devices):
         launcher = _trainer(tmp_path)
         assert not trace_mod.get_tracer().enabled
+        # what an earlier test of this worker left in the process's ring
+        # is not this test's business (which files share a worker changes
+        # from run to run)
+        trace_mod.get_tracer().clear()
         names = _profiled(tmp_path, launcher.launch)
         iters = names.count("looper/TRAIN/iter")
         assert iters == 2
@@ -494,22 +498,37 @@ class TestGoodputBooksDeviceTime:
         looper.reset(attrs)
         return step
 
-    def test_a_not_ready_leaf_books_productive(self, goodput):
-        step = self._run(ready=False)
-        snap = goodput.snapshot()
-        # only the first iteration (nothing dispatched before it) is dry
-        assert step.leaf.asked == 29
-        assert snap["productive_s"] > 10 * snap["host_blocked_s"] > 0.0
+    @staticmethod
+    def _booked(goodput, monkeypatch):
+        """Iterations that booked time into each bucket: the ledger's
+        ``add`` calls with more than nothing."""
+        import collections
 
-    def test_a_ready_leaf_books_host_blocked_until_the_dispatch(self,
-                                                                goodput):
+        booked = collections.Counter()
+        add = goodput.add
+
+        def spy(bucket, seconds, **kw):
+            booked[bucket] += seconds > 0.0
+            return add(bucket, seconds, **kw)
+
+        monkeypatch.setattr(goodput, "add", spy)
+        return booked
+
+    def test_a_not_ready_leaf_books_productive(self, goodput, monkeypatch):
+        booked = self._booked(goodput, monkeypatch)
+        step = self._run(ready=False)
+        # only the first iteration (nothing dispatched before it) is dry:
+        # it alone books host-blocked time, every one books productive
+        assert step.leaf.asked == 29
+        assert booked["host_blocked"] == 1 and booked["productive"] == 30
+
+    def test_a_ready_leaf_books_host_blocked_until_the_dispatch(
+            self, goodput, monkeypatch):
+        booked = self._booked(goodput, monkeypatch)
         step = self._run(ready=True)
-        snap = goodput.snapshot()
         assert step.leaf.asked == 29
         # dry every iteration: blocked up to the dispatch, productive after
-        assert snap["host_blocked_s"] > 0.0 and snap["productive_s"] > 0.0
-        assert 0.2 < snap["host_blocked_s"] / (
-            snap["host_blocked_s"] + snap["productive_s"]) < 0.8
+        assert booked["host_blocked"] == 30 and booked["productive"] == 30
 
     def test_buckets_still_sum_to_wall_within_1pct(self, goodput):
         self._run(ready=True)

@@ -18,7 +18,7 @@ Four layers, mirroring the tentpole:
   the bursty_arrivals tenant-skew knob).
 
 Spawn-heavy cases (process fleet, kill-between-preempt-and-resume, the
-1.25x interactive-TTFT acceptance) live in tests/test_tenants_proc.py
+batch-flood acceptance) live in tests/test_tenants_proc.py
 on the heavy tail.
 """
 

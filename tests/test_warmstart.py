@@ -8,9 +8,8 @@ behavior (CompileRecord.cache_hit across a ``jax.clear_caches()``,
 AOT serialize/deserialize round-trip), then the batcher/loop warmup
 path, the ``restore_params`` emergency election, and the standby-pool
 control logic against fakes.  The real-subprocess promotion ride lives
-at the bottom under the ``warmstart`` marker (heavy tail); the
-cold-vs-warm spawn ratio itself is guarded in
-``tests/test_bench_guard.py::TestWarmStartGuard``.
+at the bottom under the ``warmstart`` marker.  What start-up costs in
+time is ``setup_s`` of ``benchmark/run.py``, on the chip.
 """
 
 import os
@@ -508,7 +507,18 @@ def test_standby_promotion_real_worker_serves_without_compile(tmp_path):
         assert rep.ready_info.get("warm_stats", {}).get("edges", 0) >= 3
         pre = rep.collect()
         assert pre["goodput"].get("compile_s", 0.0) > 0.0  # real work
-        backend_before = pre["compile_cache"]["backend_compile_s"]
+
+        def compiled_programs():
+            """Names of the programs the backend compiler has been asked
+            for so far: it writes one persistent-cache entry a compile."""
+            return sorted(name.rsplit("-", 2)[0]
+                          for name in os.listdir(str(tmp_path / "cc"))
+                          if name.endswith("-cache"))
+
+        before = compiled_programs()
+        for edge in ("jit__spec_prefill", "jit__spec_round",
+                     "jit__spec_admit"):
+            assert edge in before, (edge, before)   # the plan pre-paid it
         prompt = np.random.default_rng(13).integers(
             1, tw.VOCAB, size=tw.P).astype(np.int32)
         assert rep.submit(Request(rid="r0", prompt=prompt))
@@ -523,15 +533,17 @@ def test_standby_promotion_real_worker_serves_without_compile(tmp_path):
         # stamped with the promoted identity, not the standby's
         assert res.meta.get("replica") == "scale-1"
         post = rep.collect()
-        # the admit edge — the only named compile serving could trigger
-        # — was served from the persistent cache (the plan pre-paid it),
-        # visible per-edge through CompileRecord.cache_hit
-        assert post["ledger"]["cache_hits"] > pre["ledger"]["cache_hits"]
-        assert post["compile_cache"]["hits"] > pre["compile_cache"]["hits"]
-        # backend-compiler residue is op-by-op noise (host-side fold_in
-        # and friends, ~0.1s), nowhere near an un-warmed admit's ~2.4s
-        assert post["compile_cache"]["backend_compile_s"] \
-            - backend_before < 1.0
+        # Serving compiled none of its programs again.  (The test used to
+        # ask for a persistent-cache HIT on the admit edge and for under a
+        # second of backend-compile time: since the plan's
+        # ``lower().compile()`` and the live dispatch share JAX's
+        # in-process executable cache the admit asks the compiler nothing,
+        # hit or miss, and what serving does compile is op-by-op residue —
+        # ``fold_in`` and friends — which is named here, not timed.)
+        after = compiled_programs()
+        for name in before:
+            after.remove(name)
+        assert not [n for n in after if n.startswith("jit__spec_")], after
         assert post["ledger"]["sentinel_dumps"] == 0.0
     finally:
         auto.close()
