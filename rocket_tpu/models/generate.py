@@ -602,6 +602,11 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
     key_draft, key_accept, key_out = jax.random.split(key_in, 3)
     pos = n_tok - 1                                     # [B] frontiers
     pending = jnp.take_along_axis(buf, pos[:, None], axis=1)[:, 0]
+    # Both models are told which rows are finished (``"idle"``): what such
+    # a row emits is dropped below (``keep``), so its attention may read
+    # nothing (``Attention._decode_attend``) and a round's attention
+    # follows the rows in use.  Its chunk is written at its frontier as
+    # ever: no slot below it changes while the row waits to be harvested.
 
     # Draft chain, fused: k+1 single-token steps under ONE scan.
     # Step i processes chunk token C_i at position pos+i and proposes
@@ -612,7 +617,8 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
         i, ki = xs
         out, mut = draft_model.apply(
             {"params": draft_params, "cache": cache_d},
-            {"tokens": tok[:, None], "positions": (pos + i)[:, None]},
+            {"tokens": tok[:, None], "positions": (pos + i)[:, None],
+             "idle": done_in},
             decode=True, mutable=["cache"],
         )
         logits = out["logits"][:, 0].astype(jnp.float32)
@@ -641,7 +647,7 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
     # ONE target forward verifies every row's whole chunk
     out, mut = model.apply(
         {"params": params, "cache": cache_t},
-        {"tokens": chunk, "positions": pos[:, None] + ar},
+        {"tokens": chunk, "positions": pos[:, None] + ar, "idle": done_in},
         decode=True, mutable=["cache"],
     )
     cache_t = mut["cache"]
@@ -1231,6 +1237,11 @@ def _mtp_round(model, draft_model, params, draft_params, state, *,
     ar = jnp.arange(2, dtype=jnp.int32)[None, :]
     pos = n_tok - 1                                     # [B] frontiers
     pending = jnp.take_along_axis(buf, pos[:, None], axis=1)[:, 0]
+    # Both models are told which rows are finished (``"idle"``): what such
+    # a row emits is dropped below (``keep``), so its attention may read
+    # nothing (``Attention._decode_attend``) and a round's attention
+    # follows the rows in use.  Its chunk is written at its frontier as
+    # ever: no slot below it changes while the row waits to be harvested.
     positions = pos[:, None] + ar
     out, mut = model.apply(
         {"params": params, "cache": cache_t},
@@ -1633,6 +1644,29 @@ class ContinuousBatcher:
         self._admits = 0
         self.state = None
         self.reads = HostReads()
+        self._slab = None           # set by start(): _kernel_slab()
+
+    def _kernel_slab(self):
+        """``(slots, key block)`` of a target row's KV slab where the
+        round's attention is the decode kernel — the rule and the block are
+        ``ops.decode_attention``'s own, asked with the verify chunk's shapes
+        and the cache as ``start()`` made it — else ``None``.  What
+        ``ServeCounters.observe_blocks`` counts in."""
+        from rocket_tpu.ops import decode_attention as da
+
+        cfg = self._model.config
+        # a configuration that lacks the field is not ``TransformerConfig``:
+        # its attention is not ``Attention._decode_attend``'s
+        if getattr(cfg, "decode_rolling_cache", True) or _latent(self._model):
+            return None
+        k = next(leaf for leaf in jax.tree_util.tree_leaves(self.state[3])
+                 if leaf.ndim == 4)
+        q = jax.ShapeDtypeStruct(
+            (k.shape[0], self.n_draft + 1, cfg.n_heads, cfg.head_dim),
+            k.dtype)
+        if da.why_not(q, k, impl=cfg.attention) is not None:
+            return None
+        return k.shape[1], da.block_k_for(q, k)
 
     def set_kv_cache_int8(self, enabled: bool) -> None:
         """Flip the int8 KV-cache knob on both decode models.
@@ -1729,6 +1763,7 @@ class ContinuousBatcher:
             self._draft_params, prompts, self._rng, self._temperature,
             max_new_tokens=self.total_len - P, **self._kw(),
         )
+        self._slab = self._kernel_slab()
 
     def step(self):
         """Run ONE speculative round on every live row; returns
@@ -1751,8 +1786,11 @@ class ContinuousBatcher:
                     self._draft_params, self.state, self._temperature,
                     n_draft=self.n_draft, **self._kw(),
                 )
-        return (self.reads(self.state[1], "n_tok"),
-                self.reads(self.state[2], "done"))
+        n_tok = self.reads(self.state[1], "n_tok")
+        done = self.reads(self.state[2], "done")
+        if self._slab is not None and self.reads.counters is not None:
+            self.reads.counters.observe_blocks(n_tok, done, *self._slab)
+        return n_tok, done
 
     def admit(self, row: int, prompt_row, *, preempt: bool = False) -> None:
         """Replace row ``row`` with a fresh request (``[1, P]`` or

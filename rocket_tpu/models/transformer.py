@@ -425,7 +425,7 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, train: bool,
-                 decode: bool = False):
+                 decode: bool = False, idle=None):
         cfg = self.config
         B, S, _ = x.shape
         H, KV, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
@@ -456,7 +456,7 @@ class Attention(nn.Module):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
         if decode:
-            out = self._decode_attend(q, k, v, positions)
+            out = self._decode_attend(q, k, v, positions, idle)
         else:
             out = attend(
                 q,
@@ -483,7 +483,7 @@ class Attention(nn.Module):
             out = nn.Dropout(cfg.dropout, deterministic=False)(out)
         return out
 
-    def _decode_attend(self, q, k, v, positions):
+    def _decode_attend(self, q, k, v, positions, idle=None):
         """KV-cache attention for autoregressive decode (the standard flax
         ``cache`` collection pattern): new K/V are written at the cache
         frontier, q attends against everything written so far.
@@ -494,8 +494,23 @@ class Attention(nn.Module):
         them as ``start + arange(S)``).  Stale cache slots past a row's
         frontier need no rewind: their key positions exceed every live
         query position, so the causal mask hides them until a later
-        chunk overwrites them in place."""
+        chunk overwrites them in place.
+
+        The two caches that keep position == slot (per-row and shared
+        ``cache_index``) attend through
+        :func:`rocket_tpu.ops.decode_attention.cached_attention`: on a
+        TPU a round's chunk reads only the key blocks its row has
+        written, elsewhere ``dot_attention`` over the slab, and the
+        choice is counted.  ``idle`` (``[B]`` bool, the batch's optional
+        ``"idle"`` entry) marks rows whose output the caller drops (the
+        round loop's finished rows): the kernel reads nothing for them.
+        It changes no write: an idle row's chunk lands at its positions
+        like any other's."""
         from rocket_tpu.ops.attention import dot_attention
+        from rocket_tpu.ops.decode_attention import (
+            cached_attention,
+            note_fallback,
+        )
 
         cfg = self.config
         B, S, KV, D = k.shape
@@ -593,6 +608,7 @@ class Attention(nn.Module):
             k_pos = chunk_end[:, None] - (
                 (chunk_end[:, None] - s_idx) % n_slots
             )
+            note_fallback("rolling", q, n_slots)
             return dot_attention(
                 q, k_all, v_all, causal=True, q_offset=starts,
                 window=cfg.attention_window, k_positions=k_pos,
@@ -617,8 +633,10 @@ class Attention(nn.Module):
             )
             q_off = idx
             cache_index.value = idx + S
-        return dot_attention(q, k_all, v_all, causal=True, q_offset=q_off,
-                             window=cfg.attention_window)
+        return cached_attention(
+            q, k_all, v_all, q_off, window=cfg.attention_window,
+            impl=cfg.attention, idle=idle, quantized=quant,
+        )
 
 
 class LatentAttention(nn.Module):
@@ -785,7 +803,7 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, train: bool,
-                 decode: bool = False, prefill: bool = False):
+                 decode: bool = False, prefill: bool = False, idle=None):
         cfg = self.config
         x = constrain(x, "batch", "sequence", "act_embed")
 
@@ -809,7 +827,7 @@ class Block(nn.Module):
         else:
             y = Attention(cfg, name="attn")(
                 pre("ln1", x)[0], positions, segment_ids, train,
-                decode=decode,
+                decode=decode, idle=idle,
             )
         x = x + post("ln1_post", y)
         aux = jnp.zeros((), jnp.float32)
@@ -1047,6 +1065,10 @@ class TransformerLM(nn.Module):
             extra = {} if cfg.remat else {"decode": decode}
             if cfg.mla is not None and decode:
                 extra["prefill"] = prefill
+            if decode and hasattr(batch, "get") \
+                    and batch.get("idle") is not None:
+                # rows whose output the caller drops (``_decode_attend``)
+                extra["idle"] = batch.get("idle")
             for i in range(cfg.n_layers):
                 pattern = {"routed": True} if (
                     cfg.experts is not None and i >= cfg.first_k_dense) else {}

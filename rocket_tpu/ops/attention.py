@@ -2,7 +2,8 @@
 
 The reference contains no attention at all (models are user-supplied,
 SURVEY §5.7); the TPU build makes long-context attention a first-class op
-with three interchangeable implementations behind one signature:
+with three interchangeable implementations behind one signature, and a
+fourth for the decode round:
 
 - ``dot``   — plain einsum softmax attention (XLA-fused; baseline and the
   correctness oracle for the others).
@@ -11,8 +12,14 @@ with three interchangeable implementations behind one signature:
 - ``ring``  — blockwise ring attention over the mesh's ``seq`` axis
   (:mod:`rocket_tpu.ops.ring`): sequence/context parallelism for sequences
   too long for one chip, K/V blocks rotating over ICI via ``ppermute``.
+- ``decode`` — a chunk of a round's few queries against a KV cache
+  (:mod:`rocket_tpu.ops.decode_attention`): a Pallas TPU kernel that reads,
+  for each row, only the key blocks that row has written, the cache as
+  stored.  Not an ``impl`` of :func:`attend`: ``Attention._decode_attend``
+  takes it where backend, dtype, shapes and mesh allow, ``dot`` elsewhere,
+  and counts the choice.
 
-All take ``(q, k, v)`` shaped ``[batch, seq, heads, head_dim]``. K/V may
+The first three take ``(q, k, v)`` shaped ``[batch, seq, heads, head_dim]``. K/V may
 have fewer heads (grouped-query attention): ``dot`` contracts each KV head
 against its group of query heads, so K and V are read once as they are;
 ``flash`` and ``ring`` still repeat K/V up to the query heads first.

@@ -11,6 +11,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
+import numpy as np
+
 from rocket_tpu.observe.trace import Histogram
 from rocket_tpu.serve.types import SLO_CLASSES
 
@@ -75,6 +77,22 @@ class ServeCounters:
         self.round_ms_ema = 0.0
         self.round_gap_ms_ema = 0.0  # last device read -> next dispatch
         self.host_fetches = 0       # blocking device->host reads
+        self.attended_blocks = 0    # key blocks the rows in use hold
+        self.total_blocks = 0       # rows x blocks of the whole slab
+
+    def observe_blocks(self, n_tok: Any, done: Any, n_slots: int,
+                       block_k: int) -> None:
+        """Count, from the host copies of ``n_tok`` and ``done`` a round
+        hands back anyway, the key blocks its attention has to visit (a
+        row in use holds ``ceil(n_tok / block_k)``, a finished row none)
+        beside the blocks of the whole slab — how much of the cache the
+        decode kernel (``ops.decode_attention``) reads.  The batcher calls
+        it only where a round's attention IS that kernel, with the
+        kernel's own block: a ``dot_attention`` round reads every slot and
+        leaves both counts at 0."""
+        held = -(-np.asarray(n_tok, np.int64) // block_k)
+        self.attended_blocks += int(held[~np.asarray(done, bool)].sum())
+        self.total_blocks += int(held.shape[0]) * -(-n_slots // block_k)
 
     def observe_round_gap_ms(self, gap_ms: float, decay: float = 0.8) -> None:
         if self.round_gap_ms_ema == 0.0:
@@ -141,6 +159,10 @@ class ServeCounters:
             "round_ms_ema": float(self.round_ms_ema),
             "round_gap_ms_ema": float(self.round_gap_ms_ema),
             "host_fetches": float(self.host_fetches),
+            "attended_blocks": float(self.attended_blocks),
+            "total_blocks": float(self.total_blocks),
+            "attended_block_share":
+                self.attended_blocks / max(1, self.total_blocks),
         })
         return out
 

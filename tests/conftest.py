@@ -118,3 +118,20 @@ def devices():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 fake cpu devices, got {devs}"
     return devs
+
+
+@pytest.fixture
+def decode_kernel_here(monkeypatch):
+    """``ops.decode_attention`` with its refusal of a backend that is no TPU
+    taken out: a call the kernel would take on the chip takes it here, in
+    interpret mode.  (Patching ``_on_tpu`` instead would ask for Mosaic.)"""
+    from rocket_tpu.ops import decode_attention
+
+    real = decode_attention.why_not
+
+    def why_not(q, k_cache, *, impl):
+        reason = real(q, k_cache, impl=impl)
+        return None if reason == "backend" else reason
+
+    monkeypatch.setattr(decode_attention, "why_not", why_not)
+

@@ -51,9 +51,9 @@ ARCH = dict(
     vocab_padded=VOCAB, max_pos=MAX_SEQ)
 
 
-def _lm(seed):
-    cfg = TransformerConfig(vocab_size=64, hidden=32, n_layers=2, n_heads=4,
-                            max_seq=64)
+def _lm(seed, hidden=32, heads=4, max_seq=64):
+    cfg = TransformerConfig(vocab_size=64, hidden=hidden, n_layers=2,
+                            n_heads=heads, max_seq=max_seq)
     model = TransformerLM(cfg)
     params = model.init(
         jax.random.PRNGKey(seed),
@@ -75,6 +75,16 @@ def dense():
     """Two language models of one structure and other weights."""
     model, params = _lm(1)
     draft, draft_params = _lm(7)
+    return model, draft, params, draft_params
+
+
+@pytest.fixture
+def kernel(decode_kernel_here):
+    """Heads of 128 and the backend's refusal taken out: ``_decode_attend``
+    takes the decode kernel (interpret mode here).  A ``max_seq`` of its
+    own, so that no program traced without the patch is found in its place."""
+    model, params = _lm(1, hidden=256, heads=2, max_seq=56)
+    draft, draft_params = _lm(7, hidden=256, heads=2, max_seq=56)
     return model, draft, params, draft_params
 
 
@@ -156,14 +166,27 @@ def _entry(name, bat, kind):
     ("_spec_round", "dense"), ("_spec_admit", "dense"),
     ("_spec_import_row", "dense"), ("_mtp_round", "latent"),
     ("_mtp_admit", "latent"),
+    # ISSUE 31: the round whose attention is the decode kernel
+    ("_spec_round", "kernel"),
 ])
 def test_the_entry_donates_its_state_and_aliases_every_cache_leaf(
         request, name, kind):
+    from rocket_tpu.observe import trace
+
     bat = _started(request.getfixturevalue(kind), kind)
     fn, args, kw, at = _entry(name, bat, kind)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        compiled = fn.lower(*args, **kw).compile()
+    tracer = trace.arm(1024)
+    tracer.clear()
+    try:
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            compiled = fn.lower(*args, **kw).compile()
+        chose = {e[1] for e in tracer.events()
+                 if e[1].startswith("attention/decode/")}
+    finally:
+        trace.disarm()
+    if kind == "kernel":     # nothing traced this configuration before
+        assert chose == {"attention/decode/kernel"}
     assert not [str(w.message) for w in caught
                 if "donated" in str(w.message)]
 

@@ -293,3 +293,144 @@ class TestHostLoopSatellites:
         row = np.asarray(out)[0]
         assert int(row[9]) == second
         assert np.all(row[10:] == second)  # fixed-length eos fill
+
+
+class TestDecodeKernelInTheBatcher:
+    """ISSUE 31: the round with the decode kernel forced on (the backend
+    test patched, the kernel in interpret mode) serves what the
+    ``dot_attention`` round serves, and the counters say how much of the
+    cache a round has to read."""
+
+    @staticmethod
+    def _lm128(seed, attention):
+        # heads of 128: the width the kernel takes
+        cfg = TransformerConfig(vocab_size=64, hidden=256, n_layers=2,
+                                n_heads=2, max_seq=72, attention=attention)
+        model = TransformerLM(cfg)
+        params = nn.meta.unbox(model.init(
+            jax.random.PRNGKey(seed),
+            {"tokens": jnp.zeros((1, 8), jnp.int32)})["params"])
+        return model, params
+
+    def _serve(self, attention):
+        """Three rows; row 0 retired after two rounds, idle for one, then
+        a newcomer admitted into it; every row's tokens at the end."""
+        model, params = self._lm128(1, attention)
+        draft, draft_params = self._lm128(7, attention)
+        bat = ContinuousBatcher(model, draft, params, draft_params,
+                                total_len=8 + 12, n_draft=4)
+        bat.start(_prompt(B=3))
+        for _ in range(2):
+            bat.step()
+        bat.retire(0)
+        bat.step()                       # row 0 stands idle
+        bat.admit(0, _prompt(B=1, seed=99)[0])
+        steps = 0
+        while not bat.all_done:
+            bat.step()
+            steps += 1
+            assert steps < 100
+        return [np.asarray(bat.row_tokens(r)[0]) for r in range(3)]
+
+    def test_kernel_round_serves_what_the_dot_round_serves(
+            self, devices, request):
+        from rocket_tpu.observe import trace
+
+        # attention="dot" is a refusal the rule counts: the reference path
+        want = self._serve("dot")
+        request.getfixturevalue("decode_kernel_here")
+        tracer = trace.arm(4096)
+        tracer.clear()
+        try:
+            got = self._serve("auto")
+            names = [e[1] for e in tracer.events()]
+        finally:
+            trace.disarm()
+        assert "attention/decode/kernel" in names
+        assert "attention/decode/fallback" not in names
+        for w, g in zip(want, got):
+            np.testing.assert_array_equal(w, g)
+
+    def test_attended_block_share_by_hand(self):
+        """Two hand-made rounds: blocks of 512 over 4,100 slots (9 a
+        row).  Round 1: rows holding 1, 512, 513 and 4,096 tokens, the
+        last finished -> 1 + 1 + 2 of 36.  Round 2: 600 and 2,049 in use,
+        two finished -> 2 + 5 of 36."""
+        from rocket_tpu.serve.metrics import ServeCounters
+
+        c = ServeCounters()
+        c.observe_blocks(np.array([1, 512, 513, 4096]),
+                         np.array([False, False, False, True]), 4100, 512)
+        assert (c.attended_blocks, c.total_blocks) == (4, 36)
+        c.observe_blocks(np.array([600, 7, 2049, 4096]),
+                         np.array([False, True, False, True]), 4100, 512)
+        assert (c.attended_blocks, c.total_blocks) == (11, 72)
+        snap = c.snapshot()
+        assert snap["attended_block_share"] == pytest.approx(11 / 72)
+        assert snap["attended_blocks"] == 11.0 and snap["total_blocks"] == 72.0
+        assert ServeCounters().snapshot()["attended_block_share"] == 0.0
+
+    def test_the_share_costs_no_fetch_and_counts_the_kernel_alone(
+            self, devices, request):
+        """``step`` counts the blocks from the ``n_tok`` and ``done`` it
+        reads anyway: two fetches a round with the counters on, as off.
+        And only where the round's attention is the kernel, in its block:
+        a round of ``dot_attention`` reads every slot and counts nothing."""
+        from rocket_tpu.models.generate import HostReads
+        from rocket_tpu.serve.metrics import ServeCounters
+
+        def one_round():
+            model, params = self._lm128(1, "auto")
+            _, draft_params = self._lm128(7, "auto")
+            bat = ContinuousBatcher(model, model, params, draft_params,
+                                    total_len=24, n_draft=4)
+            counters = ServeCounters()
+            bat.reads = HostReads(counters=counters)
+            bat.start(_prompt(B=3))
+            bat.retire(1)
+            before = counters.host_fetches
+            n_tok, done = bat.step()
+            assert counters.host_fetches - before == 2
+            return bat, counters, n_tok, done
+
+        bat, counters, _, _ = one_round()              # CPU: dot_attention
+        assert bat._slab is None
+        assert (counters.attended_blocks, counters.total_blocks) == (0, 0)
+        request.getfixturevalue("decode_kernel_here")
+        bat, counters, n_tok, done = one_round()
+        assert bat._slab == (72, 80)    # the toy slab is one block a row
+        assert counters.total_blocks == 3
+        assert counters.attended_blocks == int((~done).sum()) == 2
+        assert (n_tok <= 72).all()
+
+    def test_an_idle_round_leaves_a_finished_row_what_it_hands_on(
+            self, devices, decode_kernel_here):
+        """A finished row stands idle through further rounds before it is
+        harvested or overwritten: the kernel skips it, and its chunk is
+        written at its frontier as ever, so every slot below the frontier
+        — all that a handoff's receiver reads before it rewrites — is bit
+        for bit what it was, in both models' caches."""
+        from rocket_tpu.models.generate import export_kv_row
+
+        model, params = self._lm128(1, "auto")
+        draft, draft_params = self._lm128(7, "auto")
+        bat = ContinuousBatcher(model, draft, params, draft_params,
+                                total_len=8 + 12, n_draft=4)
+        bat.start(_prompt(B=3))
+        bat.step()
+        bat.retire(0)
+        before = export_kv_row(bat.state, 0)
+        front = int(before.n_tok[0]) - 1
+        assert front >= 8
+        for _ in range(2):
+            bat.step()
+        after = export_kv_row(bat.state, 0)
+        assert int(after.n_tok[0]) - 1 == front and bool(after.done[0])
+        np.testing.assert_array_equal(np.asarray(before.buf),
+                                      np.asarray(after.buf))
+        leaves = lambda h: [  # noqa: E731
+            np.asarray(x) for x in jax.tree_util.tree_leaves(
+                (h.cache_t, h.cache_d)) if x.ndim == 4]
+        assert len(leaves(before)) == 8
+        for was, now in zip(leaves(before), leaves(after)):
+            np.testing.assert_array_equal(was[:, :front], now[:, :front])

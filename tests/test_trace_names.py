@@ -100,6 +100,8 @@ def test_library_emits_trace_events():
     names = {name for _p, _l, name in _all_sites()}
     assert {"serve/submit", "ledger/compile",
             "quant/int8_matmul/fallback", "attention/flash/fallback",
+            # ISSUE 31: which attention a compiled decode round holds
+            "attention/decode/kernel", "attention/decode/fallback",
             # multi-tenant serving: preemption lifecycle markers
             "serve/preempt", "serve/resume",
             # distributed request tracing: the stitched-timeline and
@@ -170,6 +172,9 @@ KNOWN_JIT_SITES = {
         "exempt: offline eval utility (perplexity chunks)",
     ("ops/quant.py", "_int8_matmul_kernel_call"):
         "exempt: kernel micro-dispatch, traced via quant/* instants",
+    ("ops/decode_attention.py", "decode_attention"):
+        "exempt: inner edge of the ledgered round, so that its layers "
+        "share one trace and one lowered kernel",
     ("observe/meter.py", "_launch_in_step"):
         "exempt: MFU meter's own probe, must not perturb the ledger",
     ("parallel/mpmd.py", "__init__"):
