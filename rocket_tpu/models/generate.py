@@ -140,6 +140,46 @@ def zero_cache(model: Any, params: Any, prompt: jax.Array,
     )
 
 
+def _admission_chunk(model: Any) -> Optional[int]:
+    """Queries of a prompt a model asks to be admitted at a time
+    (``config.select.chunk``), or ``None``: the whole prompt at once."""
+    select = getattr(model.config, "select", None)
+    return None if select is None else int(select.chunk)
+
+
+def _prefill_in_chunks(model, params, cache, prompt, chunk):
+    """The prompt through the decode path ``chunk`` queries at a time,
+    inside ONE compiled loop over the whole chunks (the cache is the
+    loop's carry and is written where it lies) and one more pass for a
+    ragged rest: a prompt of 16,384 tokens is 32 turns of one body, not 32
+    bodies, and the scores alive at once are one chunk's."""
+    B, P = prompt.shape
+    n_full, rest = divmod(P, chunk)
+
+    def piece(cache, toks, c0):
+        pos = jnp.broadcast_to(
+            c0 + jnp.arange(toks.shape[1], dtype=jnp.int32),
+            (B, toks.shape[1]))
+        out, mutated = model.apply(
+            {"params": params, "cache": cache},
+            {"tokens": toks, "positions": pos},
+            decode=True, mutable=["cache"], **_prefill_kw(model),
+        )
+        return mutated["cache"], out["logits"][:, -1].astype(jnp.float32)
+
+    def turn(i, carry):
+        c0 = i * chunk
+        toks = jax.lax.dynamic_slice_in_dim(prompt, c0, chunk, axis=1)
+        return piece(carry[0], toks, c0)
+
+    last = jnp.zeros((B, model.config.vocab_size), jnp.float32)
+    cache, last = jax.lax.fori_loop(0, n_full, turn, (cache, last))
+    if rest:
+        cache, last = piece(cache, prompt[:, n_full * chunk:],
+                            jnp.int32(n_full * chunk))
+    return cache, last
+
+
 def _chunked_prefill(model, params, cache, prompt):
     """Run the prompt through the decode path and return
     ``(cache, last-position f32 logits)``.
@@ -147,8 +187,14 @@ def _chunked_prefill(model, params, cache, prompt):
     One forward for a plain cache; slack-sized chunks for a rolling
     cache (``decode_rolling_cache``) — a single chunk's writes must not
     clobber keys still inside a live query's window, and only the final
-    chunk's last-position logits matter to any caller."""
+    chunk's last-position logits matter to any caller.  A model that
+    declares an admission chunk (:func:`_admission_chunk`) runs a longer
+    prompt in chunks of that many queries inside one compiled loop
+    (:func:`_prefill_in_chunks`)."""
     B, P = prompt.shape
+    chunk = _admission_chunk(model)
+    if chunk is not None and P > chunk:
+        return _prefill_in_chunks(model, params, cache, prompt, chunk)
     step_len = (
         model.config.decode_rolling_slack
         if getattr(model.config, "decode_rolling_cache", False) else P
@@ -167,6 +213,19 @@ def _chunked_prefill(model, params, cache, prompt):
         )
         cache = mutated["cache"]
     return cache, out["logits"][:, -1].astype(jnp.float32)
+
+
+def _row_prefill(model, params, prompt_row):
+    """One request's prefill at batch 1 from an empty cache, for an
+    admission: ``(cache, last logits)``.  A model that admits in chunks
+    prefills into a cache of the prompt's own length (its chunks then score
+    the slab's prefix, not the slots no prompt token can see);
+    :func:`_scatter_row` puts it at the head of the row's slab."""
+    if _admission_chunk(model) is not None:
+        model = model.clone(config=dataclasses.replace(
+            model.config, max_seq=int(prompt_row.shape[1])))
+    return _chunked_prefill(
+        model, params, zero_cache(model, params, prompt_row), prompt_row)
 
 
 def generate(
@@ -525,11 +584,18 @@ def _scatter_row(batch_cache: Any, one_cache: Any, row) -> Any:
     C]``) take the fresh row; the scalar ``cache_index`` is bookkeeping
     only under per-row frontiers — kept monotone so rolling-cache chunk
     math stays conservative."""
-    return jax.tree_util.tree_map(
-        lambda a, b: a.at[row].set(b[0]) if _is_cache_payload(a)
-        else jnp.maximum(a, b),
-        batch_cache, one_cache,
-    )
+    def put(a, b):
+        if not _is_cache_payload(a):
+            return jnp.maximum(a, b)
+        if b.shape[1] == a.shape[1]:
+            return a.at[row].set(b[0])
+        # a prefill into a cache of the prompt's own length
+        # (:func:`_row_prefill`): the head of the row's slab; what the
+        # previous occupant left past it is hidden causally like the rest
+        return jax.lax.dynamic_update_slice(
+            a, b, (row,) + (0,) * (a.ndim - 1))
+
+    return jax.tree_util.tree_map(put, batch_cache, one_cache)
 
 
 def _spec_prefill_impl(model, draft_model, params, draft_params, prompt,
@@ -573,7 +639,12 @@ def _spec_prefill_impl(model, draft_model, params, draft_params, prompt,
     stats0 = (jnp.zeros((), jnp.int32),      # rounds
               jnp.zeros((B,), jnp.int32),    # drafted per row
               jnp.zeros((B,), jnp.int32))    # accepted per row
-    return buf, n_tok, done, cache_t, cache_d, key, stats0
+    state = (buf, n_tok, done, cache_t, cache_d, key, stats0)
+    if _round_counts(model, draft_model):
+        # an eighth entry, only for models with experts or a selection:
+        # every other model's state, and so its compiled round, is as it was
+        state += (_zero_counters(model, draft_model),)
+    return state
 
 
 def _spec_round_impl(model, draft_model, params, draft_params, state,
@@ -595,7 +666,13 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
     request's leftover K/V beyond the fresh prompt are invisible to it.
     """
     (buf, n_tok, done_in, cache_t, cache_d, key_in,
-     (rounds, drafted, accepted)) = state
+     (rounds, drafted, accepted)) = state[:7]
+    # the device counters of a model with experts or a selection
+    # (:func:`_zero_counters`); both models then sow what they routed and
+    # chose, and the round adds it up over its live rows
+    counters = state[7] if len(state) > 7 else None
+    mutable = ["cache"] if counters is None \
+        else ["cache", "routing", "selection"]
     B, total = buf.shape
     k = n_draft
     ar = jnp.arange(k + 1, dtype=jnp.int32)[None, :]
@@ -619,7 +696,7 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
             {"params": draft_params, "cache": cache_d},
             {"tokens": tok[:, None], "positions": (pos + i)[:, None],
              "idle": done_in},
-            decode=True, mutable=["cache"],
+            decode=True, mutable=mutable,
         )
         logits = out["logits"][:, 0].astype(jnp.float32)
         if sampled:
@@ -634,9 +711,12 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
         else:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             q_row = jnp.zeros((B, 0), jnp.float32)  # unused
-        return (mut["cache"], nxt), (tok, q_row)
+        if counters is None:
+            return (mut["cache"], nxt), (tok, q_row)
+        return (mut["cache"], nxt), (tok, q_row, _count_pass(
+            draft_model, mut, ~done_in, counters))
 
-    (cache_d, _), (chunk_t, q_t) = jax.lax.scan(
+    (cache_d, _), (chunk_t, q_t, *d_count) = jax.lax.scan(
         draft_step, (cache_d, pending),
         (jnp.arange(k + 1, dtype=jnp.int32),
          jax.random.split(key_draft, k + 1)),
@@ -648,7 +728,7 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
     out, mut = model.apply(
         {"params": params, "cache": cache_t},
         {"tokens": chunk, "positions": pos[:, None] + ar, "idle": done_in},
-        decode=True, mutable=["cache"],
+        decode=True, mutable=mutable,
     )
     cache_t = mut["cache"]
     t_logits = out["logits"].astype(jnp.float32)        # [B, k+1, V]
@@ -708,7 +788,20 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
     stats = (rounds + 1,
              drafted + jnp.where(active, jnp.minimum(k, remaining), 0),
              accepted + jnp.where(active, jnp.minimum(j, acc), 0))
-    return buf, n_tok, done, cache_t, cache_d, key_out, stats
+    out_state = (buf, n_tok, done, cache_t, cache_d, key_out, stats)
+    if counters is None:
+        return out_state
+    # (written out again rather than shared with ``stats`` above: a dense
+    # pair's jaxpr keeps the order of operations it always had)
+    n_drafted = jnp.where(active, jnp.minimum(k, remaining), 0)
+    n_accepted = jnp.where(active, jnp.minimum(j, acc), 0)
+    # the draft chain's steps (a leading axis from the scan) summed; its
+    # experts' rows follow the target's, as in a hidden-state draft's round
+    d_count = jax.tree_util.tree_map(lambda x: jnp.sum(x, axis=0),
+                                     d_count[0])
+    return out_state + (_add_counts(
+        counters, _count_pass(model, mut, active, counters), d_count,
+        jnp.sum(n_drafted), jnp.sum(n_accepted)),)
 
 
 def _spec_eos_fill(buf, n_tok, eos_token):
@@ -762,8 +855,8 @@ def _spec_batched_run(model, draft_model, params, draft_params, prompt,
             top_k=top_k, top_p=top_p,
         )
 
-    buf, n_tok, done, _, _, _, stats = jax.lax.while_loop(cond, body, state)
-    return _spec_eos_fill(buf, n_tok, eos_token), stats
+    out = jax.lax.while_loop(cond, body, state)
+    return _spec_eos_fill(out[0], out[1], eos_token), out[6]
 
 
 def _spec_batched_call(model, draft_model, params, draft_params, prompt,
@@ -973,19 +1066,14 @@ def _spec_admit(model, draft_model, params, draft_params, state, row,
     place (the same no-rewind argument as :func:`_spec_round_impl`).
     """
     (buf, n_tok, done, cache_t, cache_d, key_st,
-     (rounds, drafted, accepted)) = state
+     (rounds, drafted, accepted)) = state[:7]
     total = buf.shape[1]
     if key is None:
         key = jax.random.PRNGKey(0)
     P_new = prompt_row.shape[1]
 
-    c1_t, last = _chunked_prefill(
-        model, params, zero_cache(model, params, prompt_row), prompt_row
-    )
-    c1_d, _ = _chunked_prefill(
-        draft_model, draft_params,
-        zero_cache(draft_model, draft_params, prompt_row), prompt_row
-    )
+    c1_t, last = _row_prefill(model, params, prompt_row)
+    c1_d, _ = _row_prefill(draft_model, draft_params, prompt_row)
     if sampled:
         key, kg = jax.random.split(key)
         g = jax.random.categorical(
@@ -1009,7 +1097,7 @@ def _spec_admit(model, draft_model, params, draft_params, state, row,
     drafted = drafted.at[row].set(0)
     accepted = accepted.at[row].set(0)
     return (buf, n_tok, done, cache_t, cache_d, key_st,
-            (rounds, drafted, accepted))
+            (rounds, drafted, accepted)) + tuple(state[7:])
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -1022,9 +1110,10 @@ def _spec_import_row(state, row, buf1, n1, d1, c1_t, c1_d):
     prefilled cache rows, so importing a row is a cheap scatter dispatch
     instead of a full prompt forward.  Stale K/V the previous occupant
     left beyond the fresh prompt are hidden by the per-row causal mask,
-    the same no-rewind argument as :func:`_spec_admit`."""
+    the same no-rewind argument as :func:`_spec_admit`.  A state that
+    keeps device counters keeps them as they are."""
     (buf, n_tok, done, cache_t, cache_d, key_st,
-     (rounds, drafted, accepted)) = state
+     (rounds, drafted, accepted)) = state[:7]
     buf = buf.at[row].set(buf1[0])
     n_tok = n_tok.at[row].set(n1[0])
     done = done.at[row].set(d1[0])
@@ -1034,7 +1123,7 @@ def _spec_import_row(state, row, buf1, n1, d1, c1_t, c1_d):
     drafted = drafted.at[row].set(0)
     accepted = accepted.at[row].set(0)
     return (buf, n_tok, done, cache_t, cache_d, key_st,
-            (rounds, drafted, accepted))
+            (rounds, drafted, accepted)) + tuple(state[7:])
 
 
 @functools.partial(
@@ -1129,19 +1218,94 @@ def _routed_layers(model) -> int:
     return cfg.n_layers - getattr(cfg, "first_k_dense", 0)
 
 
+def _selects(model) -> bool:
+    """Whether ``model``'s attention chooses its keys (``config.select``)."""
+    return getattr(model.config, "select", None) is not None
+
+
+def _round_counts(model, draft_model) -> bool:
+    """Whether a two-model round keeps device counters: where a model has
+    routed experts or a selection to count.  Every other pair's round
+    state, and so its compiled programs, hold none."""
+    return any(_routed_layers(m) or _selects(m)
+               for m in (model, draft_model))
+
+
 def _zero_counters(model, draft_model):
     """Totals of the rounds since the last fetch: tokens each held expert
     got (a row a routed layer, the target's then the draft's), top-k slots
     routed in all and those that fell on a held expert, drafts proposed and
-    accepted, rounds."""
+    accepted, rounds; and, where a model chooses its keys, the keys its
+    live rows' queries kept and the keys they could see, summed over
+    queries and selecting layers."""
     experts = [ex for ex in (getattr(m.config, "experts", None)
                              for m in (model, draft_model)) if ex is not None]
     held = max((ex.held for ex in experts), default=0)
     layers = _routed_layers(model) + _routed_layers(draft_model)
     zero = jnp.zeros((), jnp.int32)
-    return {"rounds": zero, "drafted": zero, "accepted": zero,
-            "routed_slots": zero, "held_slots": zero,
-            "expert_tokens": jnp.zeros((layers, held), jnp.int32)}
+    counters = {"rounds": zero, "drafted": zero, "accepted": zero,
+                "routed_slots": zero, "held_slots": zero,
+                "expert_tokens": jnp.zeros((layers, held), jnp.int32)}
+    if _selects(model) or _selects(draft_model):
+        # a round of 16 rows near 20,000 keys sees millions of keys, and
+        # 32 bits are full after a few hundred rounds: (high, low) pairs,
+        # :func:`_wide_add`, made one number when they are fetched
+        counters.update({name: jnp.zeros((2,), jnp.int32)
+                         for name in _WIDE_COUNTERS})
+    return counters
+
+
+_WIDE_COUNTERS = ("selected_keys", "live_keys")
+_WIDE_BITS = 20
+
+
+def _wide_add(pair, x):
+    """``pair`` ``[2]`` int32 ``(high, low)`` standing for ``high * 2**20 +
+    low``, plus ``x`` (int32, under ``2**31 - 2**20``): 51 bits of count
+    where the device has no 64-bit integers."""
+    low = pair[1] + x
+    return jnp.stack([pair[0] + (low >> _WIDE_BITS),
+                      low & ((1 << _WIDE_BITS) - 1)])
+
+
+def _narrow(counters):
+    """Fetched counters with every ``(high, low)`` pair as one number."""
+    return {k: (np.int64(v[0]) << _WIDE_BITS) + np.int64(v[1])
+            if k in _WIDE_COUNTERS else v
+            for k, v in counters.items()}
+
+
+def _count_pass(model, mutated, live, counters):
+    """What one pass of ``model`` sowed, over the ``live`` rows ``[B]``:
+    ``(tokens a held expert [layers, held], slots routed, (keys kept, keys
+    seen))``."""
+    tokens, slots = _count_routing(
+        model, mutated.get("routing"), live,
+        counters["expert_tokens"].shape[1])
+    keys = jnp.zeros((2,), jnp.int32)
+    for leaf in jax.tree_util.tree_leaves(mutated.get("selection")):
+        keys = keys + jnp.sum(                       # leaf: [B, S, 2]
+            jnp.where(live[:, None, None], leaf, 0), axis=(0, 1))
+    return tokens, slots, keys
+
+
+def _add_counts(counters, target, draft, n_drafted, n_accepted):
+    """``counters`` after one more round: ``target`` and ``draft`` are the
+    round's :func:`_count_pass` of each model."""
+    tokens = jnp.concatenate([target[0], draft[0]], axis=0)
+    out = {
+        "rounds": counters["rounds"] + 1,
+        "drafted": counters["drafted"] + n_drafted,
+        "accepted": counters["accepted"] + n_accepted,
+        "routed_slots": counters["routed_slots"] + target[1] + draft[1],
+        "held_slots": counters["held_slots"] + jnp.sum(tokens),
+        "expert_tokens": counters["expert_tokens"] + tokens,
+    }
+    if "selected_keys" in counters:
+        keys = target[2] + draft[2]
+        out["selected_keys"] = _wide_add(counters["selected_keys"], keys[0])
+        out["live_keys"] = _wide_add(counters["live_keys"], keys[1])
+    return out
 
 
 def _count_routing(model, routing, live, held):
@@ -1276,19 +1440,10 @@ def _mtp_round(model, draft_model, params, draft_params, state, *,
     live = ~done_in
     n_drafted = jnp.where(live, jnp.minimum(1, total - n_tok), 0)
     n_accepted = jnp.where(live, jnp.minimum(j, acc), 0)
-    held = counters["expert_tokens"].shape[1]
-    t_tokens, t_slots = _count_routing(model, mut.get("routing"), live, held)
-    d_tokens, d_slots = _count_routing(draft_model, d_mut.get("routing"),
-                                       live, held)
-    tokens = jnp.concatenate([t_tokens, d_tokens], axis=0)
-    counters = {
-        "rounds": counters["rounds"] + 1,
-        "drafted": counters["drafted"] + jnp.sum(n_drafted),
-        "accepted": counters["accepted"] + jnp.sum(n_accepted),
-        "routed_slots": counters["routed_slots"] + t_slots + d_slots,
-        "held_slots": counters["held_slots"] + jnp.sum(tokens),
-        "expert_tokens": counters["expert_tokens"] + tokens,
-    }
+    counters = _add_counts(
+        counters, _count_pass(model, mut, live, counters),
+        _count_pass(draft_model, d_mut, live, counters),
+        jnp.sum(n_drafted), jnp.sum(n_accepted))
     stats = (rounds + 1, drafted + n_drafted, accepted + n_accepted)
     return (buf, n_new, done, mut["cache"], d_mut["cache"], key, stats,
             d_next, counters)
@@ -1504,11 +1659,13 @@ def export_kv_row(state, row: int) -> KVHandoff:
     Used by :meth:`ContinuousBatcher.prefill_handoff` (row 0 of a fresh
     batch-1 prefill) and available for migrating a live row between
     replicas."""
-    if len(state) != 7:
+    if len(state) > 8:
         raise ValueError(
             "KVHandoff cannot carry the state of a hidden-state draft's "
             "round (its pending draft token and counters) yet")
-    (buf, n_tok, done, cache_t, cache_d, _key, _stats) = state
+    # a two-model round's device counters (an eighth entry) stay behind:
+    # they are the batch's, not the row's
+    (buf, n_tok, done, cache_t, cache_d, _key, _stats) = state[:7]
     sl = lambda a: (a[row:row + 1] if _is_cache_payload(a)  # noqa: E731
                     else jnp.array(a, copy=True))
     return KVHandoff(
@@ -1657,7 +1814,8 @@ class ContinuousBatcher:
         cfg = self._model.config
         # a configuration that lacks the field is not ``TransformerConfig``:
         # its attention is not ``Attention._decode_attend``'s
-        if getattr(cfg, "decode_rolling_cache", True) or _latent(self._model):
+        if getattr(cfg, "decode_rolling_cache", True) \
+                or _latent(self._model) or _selects(self._model):
             return None
         k = next(leaf for leaf in jax.tree_util.tree_leaves(self.state[3])
                  if leaf.ndim == 4)
@@ -1717,15 +1875,17 @@ class ContinuousBatcher:
     def _movable(self) -> bool:
         """Whether a row's state is what :class:`KVHandoff` and
         :class:`KVPage` carry: K/V caches of two language models."""
-        return not (self._hidden_draft or _latent(self._model)
-                    or _latent(self._draft_model))
+        return not (self._hidden_draft
+                    or any(_latent(m) or _selects(m)
+                           for m in (self._model, self._draft_model)))
 
     def _refuse_handoff(self, what: str) -> None:
         if not self._movable():
             raise ValueError(
-                f"{what}: KVHandoff and KVPage cannot carry a latent cache "
-                f"or the state of a draft that reads the target's hidden "
-                f"state yet")
+                f"{what}: KVHandoff and KVPage cannot carry a latent cache, "
+                f"the cache of an attention that chooses its keys (select: "
+                f"its prefix pages and the prefix store are untested) or the "
+                f"state of a draft that reads the target's hidden state yet")
 
     def start(self, prompts) -> None:
         """Prefill the first group (``[B, P]`` int32) and build the
@@ -1790,6 +1950,9 @@ class ContinuousBatcher:
         done = self.reads(self.state[2], "done")
         if self._slab is not None and self.reads.counters is not None:
             self.reads.counters.observe_blocks(n_tok, done, *self._slab)
+        if self.reads.counters is not None and _selects(self._model):
+            self.reads.counters.observe_selection(
+                n_tok, done, self._model.config.select.top_k)
         return n_tok, done
 
     def admit(self, row: int, prompt_row, *, preempt: bool = False) -> None:
@@ -1837,13 +2000,17 @@ class ContinuousBatcher:
             )
             return
         key = jax.random.fold_in(self._rng, self._admits)
-        self.state = ledger_call(
-            _spec_admit, "generate/spec_admit",
-            self._model, self._draft_model, self._params,
-            self._draft_params, self.state, jnp.int32(row), prompt_row,
-            key, self._temperature, _shape=int(prompt_row.shape[1]),
-            **self._kw(),
-        )
+        P = int(prompt_row.shape[1])
+        chunk = _admission_chunk(self._model)
+        with self.reads.tracer.span(
+                "generate/spec_admit", prompt_len=P,
+                chunks=1 if chunk is None else -(-P // chunk)):
+            self.state = ledger_call(
+                _spec_admit, "generate/spec_admit",
+                self._model, self._draft_model, self._params,
+                self._draft_params, self.state, jnp.int32(row), prompt_row,
+                key, self._temperature, _shape=P, **self._kw(),
+            )
 
     def prefill_handoff(self, prompt_row, *, key=None) -> "KVHandoff":
         """Run ONE request's prefill at batch 1 and package the result as
@@ -2051,20 +2218,21 @@ class ContinuousBatcher:
                 "accepted": np.asarray(accepted)}
 
     def publish_counters(self) -> None:
-        """Fetch the totals the rounds accumulated on the device (a
-        hidden-state draft's round keeps them: tokens each held expert got,
-        routed and held slots, drafted and accepted), add them to the
+        """Fetch the totals the rounds accumulated on the device (the round
+        of a hidden-state draft, or of models with experts or a selection,
+        keeps them: tokens each held expert got, routed and held slots,
+        drafted and accepted, keys kept and seen), add them to the
         process-wide record (:func:`rocket_tpu.observe.trace.get_rounds`)
         and start them again from nought.  One device-to-host read, made
         when someone asks — :meth:`stats`, a closing ``ServingLoop`` — and
         never by a round."""
-        if self.state is None or len(self.state) < 9:
+        if self.state is None or not isinstance(self.state[-1], dict):
             return
         from rocket_tpu.observe.trace import get_rounds
 
-        get_rounds().add(jax.device_get(self.state[8]))
-        self.state = self.state[:8] + (jax.tree_util.tree_map(
-            jnp.zeros_like, self.state[8]),)
+        get_rounds().add(_narrow(jax.device_get(self.state[-1])))
+        self.state = self.state[:-1] + (jax.tree_util.tree_map(
+            jnp.zeros_like, self.state[-1]),)
 
 
 @functools.partial(jax.jit, static_argnums=0, static_argnames=("temperature",))
@@ -2220,6 +2388,11 @@ def _validate_beam_lm(model, P, max_new_tokens, beam_size):
             "beam search cannot run a latent-attention (mla) model yet: "
             "its cache is written at each row's positions, which the beam "
             "gather does not track")
+    if _selects(model):
+        raise ValueError(
+            "beam search cannot run a model whose attention chooses its "
+            "keys (select) yet: the beam gather has not been shown to carry "
+            "the indexer's cache")
     if not model.config.causal:
         raise ValueError(
             "beam search requires a causal decoder "

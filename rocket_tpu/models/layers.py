@@ -242,13 +242,35 @@ class Embed(nn.Module):
 
 
 def rotary_embedding(
-    positions: jax.Array, head_dim: int, theta: float = 10000.0, dtype=jnp.float32
+    positions: jax.Array, head_dim: int, theta: float = 10000.0,
+    dtype=jnp.float32, mrope_section: Optional[Sequence[int]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """cos/sin tables for RoPE; positions ``[B, S]`` -> ``[B, S, 1, D/2]``."""
+    """cos/sin tables for RoPE; positions ``[B, S]`` -> ``[B, S, 1, D/2]``.
+
+    ``mrope_section`` (multimodal RoPE) cuts the ``D/2`` rotary frequencies
+    into three contiguous runs, each turned by a position stream of its
+    own: ``positions`` is then ``[3, B, S]`` (temporal, height, width) and
+    frequency ``i`` of run ``r`` turns by ``positions[r]``.  Positions of
+    rank 2 stand for three equal streams (text), which is plain RoPE: the
+    same products in the same order, so the tables are bit for bit those
+    of the call without ``mrope_section``."""
     freqs = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
-    angles = positions.astype(jnp.float32)[..., None] * freqs  # [B, S, D/2]
+    if mrope_section is not None and positions.ndim == 3:
+        if positions.shape[0] != 3 or sum(mrope_section) != head_dim // 2 \
+                or len(mrope_section) != 3:
+            raise ValueError(
+                f"mrope_section {tuple(mrope_section)} needs three position "
+                f"streams and runs that sum to {head_dim // 2} frequencies; "
+                f"got positions {tuple(positions.shape)}")
+        by_stream = positions.astype(jnp.float32)[..., None] * freqs
+        edges = [sum(mrope_section[:r]) for r in range(4)]
+        angles = jnp.concatenate(                        # [B, S, D/2]
+            [by_stream[r, ..., edges[r]:edges[r + 1]] for r in range(3)],
+            axis=-1)
+    else:
+        angles = positions.astype(jnp.float32)[..., None] * freqs
     return (
         jnp.cos(angles)[:, :, None, :].astype(dtype),
         jnp.sin(angles)[:, :, None, :].astype(dtype),

@@ -228,7 +228,9 @@ class ExpertsConfig:
     whatever chip it lives.  ``n_held`` of them, from ``held_start`` on,
     are this program's (``None``: all).  ``scale`` is the published
     ``routed_scaling_factor``; ``n_shared`` shared experts of the same
-    width see every token."""
+    width see every token (0: none).  ``router`` is how a token's scores
+    are made of the router's outputs: ``"sigmoid"`` of each (DeepSeek-V3),
+    or a ``"softmax"`` over all ``n_routed`` of them (Mixtral, Qwen-MoE)."""
 
     n_routed: int
     top_k: int
@@ -238,9 +240,15 @@ class ExpertsConfig:
     norm_topk: bool = True
     held_start: int = 0
     n_held: Optional[int] = None
+    router: str = "sigmoid"
 
     def __post_init__(self) -> None:
         held = self.held
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"router {self.router!r} is neither 'sigmoid' nor 'softmax'")
+        if self.n_shared < 0:
+            raise ValueError(f"n_shared {self.n_shared} is negative")
         if not 0 < self.top_k <= self.n_routed:
             raise ValueError(
                 f"top_k {self.top_k} must lie in 1..n_routed {self.n_routed}")
@@ -256,12 +264,13 @@ class ExpertsConfig:
 
 
 class RoutedExperts(nn.Module):
-    """SwiGLU experts behind a sigmoid router, of which this program holds
-    a share; nothing is dropped.
+    """SwiGLU experts behind a sigmoid or softmax router, of which this
+    program holds a share; nothing is dropped.
 
     The router scores every token against all ``n_routed`` experts in
-    float32 (``sigmoid``, no groups, no bias), keeps the ``top_k`` and
-    weighs them ``s / sum(s) * scale``.  Of a token's slots those that fell
+    float32 (``config.router``: the ``sigmoid`` of each output, or the
+    ``softmax`` over all of them; no groups, no bias), keeps the ``top_k``
+    and weighs them ``s / sum(s) * scale``.  Of a token's slots those that fell
     on a held expert are computed here, ``sum_e w_e * Expert_e(x)``; what
     the experts held elsewhere would add is left out (their chips add it
     in a deployment; on one chip the layer runs without its exchange).
@@ -295,10 +304,12 @@ class RoutedExperts(nn.Module):
             (D, cfg.n_routed))
         # float32 end to end: a top-k over scores that a bf16 product
         # rounded picks other experts for the near ties
-        scores = jax.nn.sigmoid(jnp.einsum(
+        logits = jnp.einsum(
             "nd,de->ne", scored.astype(jnp.float32),
             router.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
+            precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits) if cfg.router == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
         top_s, top_i = jax.lax.top_k(scores, K)                 # [N, K]
         if cfg.norm_topk:
             top_s = top_s / jnp.sum(top_s, axis=-1, keepdims=True)

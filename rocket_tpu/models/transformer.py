@@ -76,12 +76,38 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SelectConfig:
+    """Attention that chooses its keys (:mod:`rocket_tpu.ops.
+    select_attention`): an indexer of ``index_heads`` query heads of
+    ``index_dim`` numbers over one cached key of ``index_dim`` a token
+    scores every key a query may see, and the query attends the ``top_k``
+    best alone.  ``chunk`` is how many queries of a prompt are admitted at
+    a time (a tile size: it changes no result, it bounds the scores that
+    are alive at once)."""
+
+    index_heads: int
+    index_dim: int
+    top_k: int
+    chunk: int = 512
+
+    def __post_init__(self) -> None:
+        if min(self.index_heads, self.index_dim, self.top_k, self.chunk) < 1 \
+                or self.index_dim % 2:
+            raise ValueError(
+                f"SelectConfig needs positive sizes and an even index_dim "
+                f"(it is rotated), got {self}")
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     hidden: int = 512
     n_layers: int = 4
     n_heads: int = 8
     n_kv_heads: Optional[int] = None  # None -> n_heads (MHA)
+    # Numbers a head, where the heads are not ``hidden // n_heads`` wide
+    # (32 heads of 128 over a hidden size of 2048).  None -> that quotient.
+    head_width: Optional[int] = None
     ffn_dim: Optional[int] = None  # None -> 4*hidden (gelu) / 8/3*hidden (swiglu)
     max_seq: int = 2048
     norm: str = "rmsnorm"  # 'rmsnorm' | 'layernorm'
@@ -211,8 +237,49 @@ class TransformerConfig:
     # [tokens, hidden] more; the sums that decide a router's near ties stop
     # being rounded to bfloat16 layer after layer.
     residual_float32: bool = False
+    # Attention that chooses its keys (None = every key at or before the
+    # query): a learned indexer beside the q/k/v heads and one more cache
+    # leaf a layer, ``cached_index_k`` ``[rows, slots, 1, index_dim]``.
+    select: Optional[SelectConfig] = None
+    # RMSNorm with a learned scale over each query and key head, before
+    # the rotation (Qwen3-style).
+    qk_norm: bool = False
+    # Multimodal RoPE: the head's rotary frequencies in three contiguous
+    # runs of these sizes, each turned by a position stream of its own
+    # (``batch['mrope_positions']`` ``[3, B, S]``); text, which has one
+    # position, gives the same to all three and gets plain RoPE.
+    mrope_section: Optional[tuple] = None
 
     def __post_init__(self) -> None:
+        if self.select is not None:
+            refused = [name for name, on in (
+                ("mla", self.mla is not None),
+                ("kv_cache_int8", self.kv_cache_int8),
+                ("decode_rolling_cache", self.decode_rolling_cache),
+                ("attention_window", self.attention_window is not None),
+                ("fused_qkv", self.fused_qkv),
+                ("scan_layers", self.scan_layers),
+                ("pipeline_microbatches", self.pipeline_microbatches > 0),
+                ("pipeline_microbatch_size",
+                 self.pipeline_microbatch_size > 0),
+                ("causal=False", not self.causal),
+                ("positions='learned'", self.positions != "rope"),
+            ) if on]
+            if refused:
+                raise ValueError(
+                    f"attention that chooses its keys (select) cannot run "
+                    f"with {', '.join(refused)} yet")
+        if self.mrope_section is not None and (
+                self.positions != "rope" or self.mla is not None
+                or len(self.mrope_section) != 3
+                or 2 * sum(self.mrope_section) != self.head_dim):
+            raise ValueError(
+                f"mrope_section {self.mrope_section} needs positions='rope', "
+                f"the q/k/v heads, and three runs that sum to half a head "
+                f"({self.head_dim // 2})")
+        if self.qk_norm and self.mla is not None:
+            raise ValueError("qk_norm is the q/k/v heads'; latent attention "
+                             "(mla) norms its latents")
         if self.mla is not None or self.experts is not None:
             what = "latent attention (mla)" if self.mla is not None \
                 else "routed experts (experts)"
@@ -320,7 +387,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden // self.n_heads
+        return self.head_width or self.hidden // self.n_heads
 
     @property
     def mlp_dim(self) -> int:
@@ -425,7 +492,14 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, train: bool,
-                 decode: bool = False, idle=None):
+                 decode: bool = False, idle=None, mrope_positions=None):
+        """With ``config.select`` the layer also holds the indexer
+        (``index_q``, ``index_k`` with its LayerNorm, ``index_w``) and
+        attends each query's ``top_k`` keys alone, through the cache and
+        without it.  ``mrope_positions`` (``[3, B, S]``) turn the heads
+        where ``config.mrope_section`` is set; ``positions`` stay each
+        token's place in its row (cache slot, causal order, the indexer's
+        rotation)."""
         cfg = self.config
         B, S, _ = x.shape
         H, KV, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
@@ -451,11 +525,21 @@ class Attention(nn.Module):
         q = constrain(q, "batch", "sequence", "heads", None)
         k = constrain(k, "batch", "sequence", "heads", None)
         v = constrain(v, "batch", "sequence", "heads", None)
+        if cfg.qk_norm:
+            q = RMSNorm(eps=cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(eps=cfg.norm_eps, name="k_norm")(k)
         if cfg.positions == "rope":
-            cos, sin = rotary_embedding(positions, D, cfg.rope_theta, x.dtype)
+            cos, sin = rotary_embedding(
+                positions if mrope_positions is None else mrope_positions,
+                D, cfg.rope_theta, x.dtype, mrope_section=cfg.mrope_section)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-        if decode:
+        if cfg.select is not None:
+            if segment_ids is not None:
+                raise ValueError("attention that chooses its keys (select) "
+                                 "cannot run over packed sequences yet")
+            out = self._select_attend(x, q, k, v, positions, decode, idle)
+        elif decode:
             out = self._decode_attend(q, k, v, positions, idle)
         else:
             out = attend(
@@ -483,7 +567,47 @@ class Attention(nn.Module):
             out = nn.Dropout(cfg.dropout, deterministic=False)(out)
         return out
 
-    def _decode_attend(self, q, k, v, positions, idle=None):
+    def _select_attend(self, x, q, k, v, positions, decode, idle):
+        """The indexer and the attention over what it chooses
+        (:mod:`rocket_tpu.ops.select_attention`).  ``qI = RoPE(W_qI x)``
+        (``index_heads`` of ``index_dim``), ``kI = RoPE(LN(W_kI x))`` (one
+        a token, cached as ``cached_index_k`` where K and V are),
+        ``w = W_w x / sqrt(index_heads * index_dim)``; both rotate over
+        all their numbers by the token's own position.  The kept and the
+        live keys of every query are sown (``selection/keys``
+        ``[B, S, 2]``) for the serving round's counters."""
+        from rocket_tpu.ops.select_attention import (
+            index_scores,
+            selected_attention,
+        )
+
+        cfg, sel = self.config, self.config.select
+        B, S, _ = x.shape
+        J, d = sel.index_heads, sel.index_dim
+        dense = lambda feat, name: PDense(  # noqa: E731
+            feat, logical_axes=("embed", None), name=name)
+        q_idx = dense(J * d, "index_q")(x).reshape(B, S, J, d)
+        k_idx = nn.LayerNorm(epsilon=cfg.norm_eps, name="index_k_norm")(
+            dense(d, "index_k")(x)).astype(x.dtype).reshape(B, S, 1, d)
+        w_idx = dense(J, "index_w")(x).astype(jnp.float32) * (J * d) ** -0.5
+        cos, sin = rotary_embedding(positions, d, cfg.rope_theta, x.dtype)
+        q_idx, k_idx = apply_rope(q_idx, cos, sin), apply_rope(k_idx, cos, sin)
+
+        def attend_selected(k_all, v_all, k_idx_all, q_pos):
+            scores = index_scores(q_idx, w_idx, k_idx_all, q_pos, idle)
+            out, kept = selected_attention(q, k_all, v_all, scores, q_pos,
+                                           sel.top_k)
+            live = jnp.sum(scores > -jnp.inf, axis=-1)
+            self.sow("selection", "keys",
+                     jnp.stack([kept, live], axis=-1).astype(jnp.int32))
+            return out
+
+        if not decode:
+            return attend_selected(k, v, k_idx, positions)
+        return self._decode_attend(q, k, v, positions, idle,
+                                   index=(k_idx, attend_selected))
+
+    def _decode_attend(self, q, k, v, positions, idle=None, index=None):
         """KV-cache attention for autoregressive decode (the standard flax
         ``cache`` collection pattern): new K/V are written at the cache
         frontier, q attends against everything written so far.
@@ -505,15 +629,24 @@ class Attention(nn.Module):
         ``"idle"`` entry) marks rows whose output the caller drops (the
         round loop's finished rows): the kernel reads nothing for them.
         It changes no write: an idle row's chunk lands at its positions
-        like any other's."""
+        like any other's.
+
+        ``index`` (a selecting layer's ``(k_idx, attend_selected)``) adds
+        the indexer's cache leaf ``cached_index_k`` ``[B, slots, 1,
+        index_dim]``, written where K and V are, and attends through
+        ``attend_selected`` over the written caches instead; that the
+        decode kernel is not taken is counted as a fallback with reason
+        ``selected``."""
         from rocket_tpu.ops.attention import dot_attention
         from rocket_tpu.ops.decode_attention import (
+            MAX_CHUNK,
             cached_attention,
             note_fallback,
         )
 
         cfg = self.config
         B, S, KV, D = k.shape
+        index_k, attend_selected = index if index is not None else (None,) * 2
         is_filled = self.has_variable("cache", "cached_k")
         n_slots = (
             cfg.attention_window + cfg.decode_rolling_slack
@@ -545,10 +678,19 @@ class Attention(nn.Module):
         cache_index = self.variable(
             "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
         )
+        if index is not None:
+            # rank 4 like K and V, so that every caller that moves cache
+            # rows by rank (scatter, export, import) carries it
+            cached_index_k = self.variable(
+                "cache", "cached_index_k", jnp.zeros,
+                (B, n_slots, 1, index_k.shape[-1]), index_k.dtype,
+            )
         if not is_filled:
             # init pass: create the cache shapes, attend normally (the
             # window still applies — a user init_with_output(decode=True)
             # must see the same masking as every other path)
+            if index is not None:
+                return attend_selected(k, v, index_k, positions)
             return attend(q, k, v, impl="dot", causal=cfg.causal,
                           window=cfg.attention_window)
         if quant:
@@ -563,6 +705,8 @@ class Attention(nn.Module):
                       (k_scale, k_s), (v_scale, v_s)]
         else:
             writes = [(cached_k, k), (cached_v, v)]
+        if index is not None:
+            writes.append((cached_index_k, index_k))
 
         def write_all(write_fn):
             # Apply one write op uniformly to every cache leaf (payload
@@ -633,6 +777,29 @@ class Attention(nn.Module):
             )
             q_off = idx
             cache_index.value = idx + S
+        if index is not None:
+            from rocket_tpu.observe.trace import counter
+            from rocket_tpu.ops.select_attention import (
+                gathers,
+                why_not_masked,
+            )
+
+            sel = cfg.select
+            note_fallback("selected", q, n_slots)
+            if S <= MAX_CHUNK:          # a round's chunk, as the kernel's
+                counter("attention/select/decode", 1, S=S, T=n_slots,
+                        top_k=sel.top_k, index_heads=sel.index_heads,
+                        path="gather" if gathers(S, n_slots, sel.top_k)
+                        else "mask")
+            else:
+                reason = why_not_masked(q, k_all)
+                counter("attention/select/prefill", 1, chunk=S, T=n_slots,
+                        path="mask" if reason else "kernel",
+                        **({"reason": reason} if reason else {}))
+            q_pos = jnp.asarray(q_off)[..., None] \
+                + jnp.arange(S, dtype=jnp.int32)
+            return attend_selected(k_all, v_all, cached_index_k.value,
+                                   jnp.broadcast_to(q_pos, (B, S)))
         return cached_attention(
             q, k_all, v_all, q_off, window=cfg.attention_window,
             impl=cfg.attention, idle=idle, quantized=quant,
@@ -803,7 +970,8 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, train: bool,
-                 decode: bool = False, prefill: bool = False, idle=None):
+                 decode: bool = False, prefill: bool = False, idle=None,
+                 mrope_positions=None):
         cfg = self.config
         x = constrain(x, "batch", "sequence", "act_embed")
 
@@ -827,7 +995,7 @@ class Block(nn.Module):
         else:
             y = Attention(cfg, name="attn")(
                 pre("ln1", x)[0], positions, segment_ids, train,
-                decode=decode, idle=idle,
+                decode=decode, idle=idle, mrope_positions=mrope_positions,
             )
         x = x + post("ln1_post", y)
         aux = jnp.zeros((), jnp.float32)
@@ -1069,6 +1237,9 @@ class TransformerLM(nn.Module):
                     and batch.get("idle") is not None:
                 # rows whose output the caller drops (``_decode_attend``)
                 extra["idle"] = batch.get("idle")
+            if cfg.mrope_section is not None and hasattr(batch, "get") \
+                    and batch.get("mrope_positions") is not None:
+                extra["mrope_positions"] = batch.get("mrope_positions")
             for i in range(cfg.n_layers):
                 pattern = {"routed": True} if (
                     cfg.experts is not None and i >= cfg.first_k_dense) else {}

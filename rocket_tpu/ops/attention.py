@@ -64,6 +64,7 @@ def dot_attention(
     kv_mask: Optional[Array] = None,
     window: Optional[int] = None,
     k_positions: Optional[Array] = None,
+    key_mask: Optional[Array] = None,
 ) -> Array:
     """Reference einsum attention. Computes logits in f32 for stability
     regardless of the compute dtype (bf16 inputs stay bf16 on the matmuls —
@@ -97,6 +98,11 @@ def dot_attention(
     negative, not ``-inf``: a fully-masked row (an all-padding dummy
     input in a wrap-around batch) then degrades to uniform weights
     instead of a batch-poisoning softmax NaN.
+
+    ``key_mask`` (``[B, S, S_k]`` bool, True = attend) gives every query
+    a mask of its own over the keys — attention that chooses its keys
+    (:mod:`rocket_tpu.ops.select_attention`), whose mask holds causality
+    already, so it goes with ``causal=False``.
 
     ``k_positions`` (``[B, S_k]`` int) gives each key slot an EXPLICIT
     sequence position instead of its array index — the rolling-KV-cache
@@ -161,6 +167,8 @@ def dot_attention(
         logits = jnp.where(
             kv_mask[:, None, None, None, :].astype(bool), logits, neg
         )
+    if key_mask is not None:
+        logits = jnp.where(key_mask[:, None, None], logits, neg)
     if v is None:
         if v_width is None:
             raise ValueError("v=None needs v_width (values read from k)")

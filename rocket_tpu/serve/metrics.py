@@ -79,6 +79,8 @@ class ServeCounters:
         self.host_fetches = 0       # blocking device->host reads
         self.attended_blocks = 0    # key blocks the rows in use hold
         self.total_blocks = 0       # rows x blocks of the whole slab
+        self.selected_keys = 0      # keys the rows' frontier queries keep
+        self.live_keys = 0          # keys those queries could see
 
     def observe_blocks(self, n_tok: Any, done: Any, n_slots: int,
                        block_k: int) -> None:
@@ -93,6 +95,17 @@ class ServeCounters:
         held = -(-np.asarray(n_tok, np.int64) // block_k)
         self.attended_blocks += int(held[~np.asarray(done, bool)].sum())
         self.total_blocks += int(held.shape[0]) * -(-n_slots // block_k)
+
+    def observe_selection(self, n_tok: Any, done: Any, top_k: int) -> None:
+        """Count, from the same host copies, what an attention that
+        chooses its keys keeps: the query at a row's frontier sees
+        ``n_tok`` keys and keeps ``min(n_tok, top_k)``; a finished row
+        none.  One query a row and round stands for the round's (the
+        device's own count of every query and layer is the rounds'
+        ``selected_keys`` / ``live_keys``, ``observe.trace.get_rounds``)."""
+        seen = np.asarray(n_tok, np.int64)[~np.asarray(done, bool)]
+        self.selected_keys += int(np.minimum(seen, top_k).sum())
+        self.live_keys += int(seen.sum())
 
     def observe_round_gap_ms(self, gap_ms: float, decay: float = 0.8) -> None:
         if self.round_gap_ms_ema == 0.0:
@@ -163,6 +176,8 @@ class ServeCounters:
             "total_blocks": float(self.total_blocks),
             "attended_block_share":
                 self.attended_blocks / max(1, self.total_blocks),
+            "selected_key_share":
+                self.selected_keys / max(1, self.live_keys),
         })
         return out
 

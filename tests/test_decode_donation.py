@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.archs import keye_moe as select_family
 from benchmark.archs import pangu_moe as family
 from rocket_tpu.models.generate import (ContinuousBatcher, export_kv_row,
                                         speculative_generate_batched)
@@ -100,15 +101,39 @@ def latent():
     return model, draft, params, draft_params
 
 
+# tests/test_select_attention.py's toy widths of the selecting model
+SELECT_ARCH = dict(
+    hidden=32, layers=2, heads=4, kv_heads=2, head_dim=16, expert_ffn=16,
+    router=16, held=4, held_start=8, top_k=4, norm_topk=True, index_heads=2,
+    index_dim=8, select_top_k=4, chunk=4, mrope=(2, 3, 3), eps=1e-6,
+    rope_theta=10000.0, vocab=VOCAB, vocab_padded=VOCAB, max_pos=MAX_SEQ)
+
+
+@pytest.fixture(scope="module")
+def select():
+    """The toy model whose attention chooses its keys (an indexer's cache
+    leaf beside K and V, device counters in the round state) and a draft
+    of the same kind."""
+    tokens = {"tokens": jnp.zeros((1, 4), jnp.int32)}
+    model = select_family.program(SELECT_ARCH, max_seq=MAX_SEQ)
+    params = _seeded(model.init(jax.random.PRNGKey(0), tokens)["params"], 1)
+    draft = select_family.program(
+        select_family.draft(SELECT_ARCH, {"draft_layers": 1}),
+        max_seq=MAX_SEQ)
+    draft_params = _seeded(
+        draft.init(jax.random.PRNGKey(0), tokens)["params"], 2)
+    return model, draft, params, draft_params
+
+
 def _batcher(models, kind):
-    if kind == "latent":
+    if kind in ("latent", "select"):
         return ContinuousBatcher(*models, total_len=30, n_draft=1)
     return ContinuousBatcher(*models, total_len=TOTAL, n_draft=NDRAFT,
                              eos_token=None)
 
 
 def _prompts(kind, seed=13, rows=8):
-    high = VOCAB if kind == "latent" else 64
+    high = VOCAB if kind in ("latent", "select") else 64
     return np.random.default_rng(seed).integers(
         1, high, size=(rows, P)).astype(np.int32)
 
@@ -150,7 +175,12 @@ def _entry(name, bat, kind):
                            jax.random.PRNGKey(3), bat._temperature),
                 bat._kw(), 2)
     if name == "_spec_import_row":
-        h = bat.prefill_handoff(prompt_row)
+        # a selecting model's batcher refuses handoffs; the row of a fresh
+        # batch-1 prefill is what ``prefill_handoff`` would have exported
+        h = bat.prefill_handoff(prompt_row) if kind != "select" \
+            else export_kv_row(generate_mod._spec_prefill(
+                *modules, prompt_row, bat._rng, bat._temperature,
+                max_new_tokens=bat.total_len - P, **bat._kw()), 0)
         return (generate_mod._spec_import_row,
                 (bat.state, row, h.buf, h.n_tok, h.done, h.cache_t,
                  h.cache_d), {}, 0)
@@ -168,6 +198,9 @@ def _entry(name, bat, kind):
     ("_mtp_admit", "latent"),
     # ISSUE 31: the round whose attention is the decode kernel
     ("_spec_round", "kernel"),
+    # ISSUE 33: the indexer's cache leaf and the round's device counters
+    ("_spec_round", "select"), ("_spec_admit", "select"),
+    ("_spec_import_row", "select"),
 ])
 def test_the_entry_donates_its_state_and_aliases_every_cache_leaf(
         request, name, kind):
@@ -205,6 +238,10 @@ def test_the_entry_donates_its_state_and_aliases_every_cache_leaf(
     # and it cannot pass the state's bytes, since nothing else is donated.
     state = bat.state
     payload = _payload(state)
+    if kind == "select":      # a third leaf a layer, rank 4 like K and V
+        index = [p for p in payload
+                 if p.shape[2:] == (1, SELECT_ARCH["index_dim"])]
+        assert len(index) == 3 and len(payload) == 9
     assert _nbytes(state) - _nbytes(payload) < min(p.nbytes for p in payload)
     aliased = compiled.memory_analysis().alias_size_in_bytes
     assert _nbytes(payload) <= aliased <= _nbytes(state), name
@@ -221,6 +258,7 @@ def _live(tree):
 @pytest.mark.parametrize("kind,call", [
     ("dense", "step"), ("dense", "admit"), ("dense", "admit_prefilled"),
     ("latent", "step"), ("latent", "admit"),
+    ("select", "step"), ("select", "admit"),
 ])
 def test_the_previous_state_is_gone_and_the_rest_lives(request, kind, call):
     bat = _started(request.getfixturevalue(kind), kind)

@@ -102,6 +102,9 @@ def test_library_emits_trace_events():
             "quant/int8_matmul/fallback", "attention/flash/fallback",
             # ISSUE 31: which attention a compiled decode round holds
             "attention/decode/kernel", "attention/decode/fallback",
+            # ISSUE 33: attention that chooses its keys, chunked admission
+            "attention/select/decode", "attention/select/prefill",
+            "generate/spec_admit",
             # multi-tenant serving: preemption lifecycle markers
             "serve/preempt", "serve/resume",
             # distributed request tracing: the stitched-timeline and
@@ -175,6 +178,9 @@ KNOWN_JIT_SITES = {
     ("ops/decode_attention.py", "decode_attention"):
         "exempt: inner edge of the ledgered round, so that its layers "
         "share one trace and one lowered kernel",
+    ("ops/select_attention.py", "masked_attention"):
+        "exempt: inner edge of the ledgered admission, so that its layers "
+        "and chunks share one trace and one lowered kernel",
     ("observe/meter.py", "_launch_in_step"):
         "exempt: MFU meter's own probe, must not perturb the ledger",
     ("parallel/mpmd.py", "__init__"):
