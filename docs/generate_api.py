@@ -55,6 +55,8 @@ MODULES = {
     "rocket_tpu.ops.attention": "Attention dispatch",
     "rocket_tpu.ops.flash": "Pallas flash attention (TPU kernel)",
     "rocket_tpu.ops.decode_attention": "Pallas decode attention over a KV cache (TPU kernel)",
+    "rocket_tpu.ops.latent_attention":
+        "Pallas absorbed latent attention over its cache (TPU kernel)",
     "rocket_tpu.ops.select_attention":
         "Attention that chooses its keys (indexer, top-k, gather or mask)",
     "rocket_tpu.ops.fused_ce": "Fused logits-free linear cross-entropy",

@@ -1198,13 +1198,16 @@ def _spec_suffix_prefill(model, draft_model, params, draft_params, prompt,
 
 
 def _draft_apply(draft_model, draft_params, params, cache_d, tokens,
-                 positions, hidden, **kw):
+                 positions, hidden, idle=None, **kw):
     """One pass of the hidden-state draft over ``(hidden_i, tokens_i =
-    t_{i+1})`` pairs, with the target's embedding and head handed in."""
+    t_{i+1})`` pairs, with the target's embedding and head handed in
+    (and, in a round, which rows are finished: ``idle``)."""
+    batch = {"tokens": tokens, "positions": positions, "hidden": hidden,
+             **draft_model.tied(params)}
+    if idle is not None:
+        batch["idle"] = idle
     return draft_model.apply(
-        {"params": draft_params, "cache": cache_d},
-        {"tokens": tokens, "positions": positions, "hidden": hidden,
-         **draft_model.tied(params)},
+        {"params": draft_params, "cache": cache_d}, batch,
         decode=True, mutable=["cache", "routing"], **kw,
     )
 
@@ -1403,14 +1406,15 @@ def _mtp_round(model, draft_model, params, draft_params, state, *,
     pending = jnp.take_along_axis(buf, pos[:, None], axis=1)[:, 0]
     # Both models are told which rows are finished (``"idle"``): what such
     # a row emits is dropped below (``keep``), so its attention may read
-    # nothing (``Attention._decode_attend``) and a round's attention
-    # follows the rows in use.  Its chunk is written at its frontier as
-    # ever: no slot below it changes while the row waits to be harvested.
+    # nothing (``LatentAttention``, ``Attention._decode_attend``) and a
+    # round's attention follows the rows in use.  Its chunk is written at
+    # its frontier as ever: no slot below it changes while the row waits to
+    # be harvested.
     positions = pos[:, None] + ar
     out, mut = model.apply(
         {"params": params, "cache": cache_t},
         {"tokens": jnp.stack([pending, d_tok], axis=1),
-         "positions": positions},
+         "positions": positions, "idle": done_in},
         decode=True, mutable=["cache", "routing"],
     )
     y = jnp.argmax(out["logits"].astype(jnp.float32), axis=-1) \
@@ -1432,7 +1436,7 @@ def _mtp_round(model, draft_model, params, draft_params, state, *,
         done = done | jnp.any((y == eos_token) & keep, axis=1)
 
     d_out, d_mut = _draft_apply(draft_model, draft_params, params, cache_d,
-                                y, positions, out["hidden"])
+                                y, positions, out["hidden"], idle=done_in)
     proposals = jnp.argmax(d_out["logits"].astype(jnp.float32), axis=-1) \
         .astype(jnp.int32)
     d_next = jnp.take_along_axis(proposals, j[:, None], axis=1)[:, 0]
@@ -1804,21 +1808,32 @@ class ContinuousBatcher:
         self._slab = None           # set by start(): _kernel_slab()
 
     def _kernel_slab(self):
-        """``(slots, key block)`` of a target row's KV slab where the
-        round's attention is the decode kernel — the rule and the block are
-        ``ops.decode_attention``'s own, asked with the verify chunk's shapes
-        and the cache as ``start()`` made it — else ``None``.  What
-        ``ServeCounters.observe_blocks`` counts in."""
+        """``(slots, key block)`` of a target row's slab where the round's
+        attention is a decode kernel — the rule and the block are the
+        kernel's own (``ops.decode_attention``'s for K and V caches,
+        ``ops.latent_attention``'s for a latent one), asked with the verify
+        chunk's shapes and the cache as ``start()`` made it — else
+        ``None``.  What ``ServeCounters.observe_blocks`` counts in."""
         from rocket_tpu.ops import decode_attention as da
+        from rocket_tpu.ops import latent_attention as la
 
         cfg = self._model.config
+        leaves = jax.tree_util.tree_leaves(self.state[3])
+        if _latent(self._model):
+            cache = next(leaf for leaf in leaves if leaf.ndim == 3)
+            q = jax.ShapeDtypeStruct(
+                (cache.shape[0], self.n_draft + 1, cfg.n_heads,
+                 cache.shape[2]), cache.dtype)
+            C = cfg.mla.kv_lora_rank
+            if la.why_not(q, cache, C) is not None:
+                return None
+            return cache.shape[1], la.block_k_for(q, cache, C)
         # a configuration that lacks the field is not ``TransformerConfig``:
         # its attention is not ``Attention._decode_attend``'s
         if getattr(cfg, "decode_rolling_cache", True) \
-                or _latent(self._model) or _selects(self._model):
+                or _selects(self._model):
             return None
-        k = next(leaf for leaf in jax.tree_util.tree_leaves(self.state[3])
-                 if leaf.ndim == 4)
+        k = next(leaf for leaf in leaves if leaf.ndim == 4)
         q = jax.ShapeDtypeStruct(
             (k.shape[0], self.n_draft + 1, cfg.n_heads, cfg.head_dim),
             k.dtype)
@@ -1916,13 +1931,13 @@ class ContinuousBatcher:
                 self._draft_params, prompts, self._rng,
                 max_new_tokens=self.total_len - P, eos_token=self.eos_token,
             )
-            return
-        self.state = ledger_call(
-            _spec_prefill, "generate/spec_prefill",
-            self._model, self._draft_model, self._params,
-            self._draft_params, prompts, self._rng, self._temperature,
-            max_new_tokens=self.total_len - P, **self._kw(),
-        )
+        else:
+            self.state = ledger_call(
+                _spec_prefill, "generate/spec_prefill",
+                self._model, self._draft_model, self._params,
+                self._draft_params, prompts, self._rng, self._temperature,
+                max_new_tokens=self.total_len - P, **self._kw(),
+            )
         self._slab = self._kernel_slab()
 
     def step(self):

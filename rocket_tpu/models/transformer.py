@@ -828,13 +828,19 @@ class LatentAttention(nn.Module):
     written at each row's own ``positions[:, 0]`` (contiguous positions a
     row, as every caller in ``models.generate`` builds them); stale slots
     past a row's frontier are hidden causally, as in
-    :meth:`Attention._decode_attend`."""
+    :meth:`Attention._decode_attend`.  The absorbed path attends as
+    :func:`rocket_tpu.ops.latent_attention.takes` says: on a TPU through a
+    kernel that reads only the cache blocks each row has written,
+    elsewhere through ``dot_attention`` over the slab, the choice counted.
+    ``idle`` (``[B]`` bool, the batch's optional ``"idle"`` entry) marks
+    rows whose output the caller drops: the kernel reads nothing of them.
+    It changes no write."""
 
     config: TransformerConfig
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, train: bool,
-                 decode: bool = False, prefill: bool = False):
+                 decode: bool = False, prefill: bool = False, idle=None):
         cfg, m = self.config, self.config.mla
         B, S, _ = x.shape
         H, C = cfg.n_heads, m.kv_lora_rank
@@ -892,9 +898,16 @@ class LatentAttention(nn.Module):
                 q_lat = jnp.concatenate(
                     [jnp.einsum("bshd,chd->bshc", q_nope, w_kvb[..., :dn]),
                      q_rope], axis=-1)
-                ctx = dot_attention(
-                    q_lat, cached.value[:, :, None, :], v_width=C,
-                    causal=True, q_offset=starts, scale=scale)
+                from rocket_tpu.ops import latent_attention
+
+                if latent_attention.takes(q_lat, cached.value, C):
+                    ctx = latent_attention.latent_decode_attention(
+                        q_lat, cached.value, starts, v_width=C, scale=scale,
+                        idle=idle)
+                else:
+                    ctx = dot_attention(
+                        q_lat, cached.value[:, :, None, :], v_width=C,
+                        causal=True, q_offset=starts, scale=scale)
                 out = jnp.einsum("bshc,chd->bshd", ctx, w_kvb[..., dn:])
         out = dense(cfg.hidden, ("heads", "embed"), "o")(
             out.reshape(B, S, H * dv))
@@ -990,7 +1003,7 @@ class Block(nn.Module):
         if cfg.mla is not None:
             y = LatentAttention(cfg, name="attn")(
                 pre("ln1", x)[0], positions, segment_ids, train,
-                decode=decode, prefill=prefill,
+                decode=decode, prefill=prefill, idle=idle,
             )
         else:
             y = Attention(cfg, name="attn")(
@@ -1346,6 +1359,8 @@ class MTPDraft(nn.Module):
                  _Norm(cfg, name="hnorm")(hidden.astype(wide))],
                 axis=-1).astype(act)).astype(wide)
         extra = {"prefill": prefill} if cfg.mla is not None and decode else {}
+        if decode and batch.get("idle") is not None:
+            extra["idle"] = batch.get("idle")
         for i in range(cfg.n_layers):
             x, _ = Block(cfg, routed=cfg.experts is not None,
                          name=f"block_{i}", **stream)(

@@ -178,6 +178,9 @@ KNOWN_JIT_SITES = {
     ("ops/decode_attention.py", "decode_attention"):
         "exempt: inner edge of the ledgered round, so that its layers "
         "share one trace and one lowered kernel",
+    ("ops/latent_attention.py", "latent_decode_attention"):
+        "exempt: inner edge of the ledgered round, so that its layers and "
+        "the MTP module share one trace and one lowered kernel",
     ("ops/select_attention.py", "masked_attention"):
         "exempt: inner edge of the ledgered admission, so that its layers "
         "and chunks share one trace and one lowered kernel",
