@@ -59,6 +59,9 @@ MODULES = {
         "Pallas absorbed latent attention over its cache (TPU kernel)",
     "rocket_tpu.ops.select_attention":
         "Attention that chooses its keys (indexer, top-k, gather or mask)",
+    "rocket_tpu.ops.ssm":
+        "Selective scan (chunked and step forms) and the round's state "
+        "update (TPU kernel)",
     "rocket_tpu.ops.fused_ce": "Fused logits-free linear cross-entropy",
     "rocket_tpu.ops.ring": "Ring attention (sequence parallel)",
     "rocket_tpu.ops.quant": "Int8 weight-only quantization (W8A16 decode)",
@@ -69,6 +72,7 @@ MODULES = {
     "rocket_tpu.persist.checkpoint": "Checkpointer capsule",
     "rocket_tpu.persist.orbax_io": "Orbax checkpoint IO",
     "rocket_tpu.models.transformer": "Transformer LM family",
+    "rocket_tpu.models.mamba": "State-space (Mamba-2) mixer and its cache",
     "rocket_tpu.models.resnet": "ResNet family",
     "rocket_tpu.models.vit": "ViT family",
     "rocket_tpu.models.lenet": "LeNet (MNIST example model)",

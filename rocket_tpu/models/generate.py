@@ -89,12 +89,52 @@ def _sample(logits: jax.Array, rng: jax.Array, temperature: float,
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
-def _is_cache_payload(leaf: Any) -> bool:
-    """A cache leaf that holds rows of tokens — K/V ``[B, slots, KV, D]``,
-    their int8 scales ``[B, slots, KV, 1]``, a latent ``[B, slots, C]`` —
-    as against the scalar ``cache_index``: what row scatters, exports and
-    beam gathers move."""
-    return getattr(leaf, "ndim", 0) >= 3
+# Cache leaves that hold a row's recurrent state (a state-space layer's,
+# :mod:`rocket_tpu.models.mamba`): a row's is replaced whole, never sliced.
+_STATE_LEAVES = frozenset({"ssm_state", "conv_state", "dt_state",
+                           "state_pos"})
+
+
+def _leaf_name(path) -> str:
+    last = path[-1]
+    return str(getattr(last, "key", getattr(last, "name", last)))
+
+
+def _is_cache_payload(path, leaf) -> bool:
+    """Whether the cache leaf at ``path`` holds rows of tokens slot by slot
+    — K/V ``[B, slots, KV, D]``, their int8 scales ``[B, slots, KV, 1]``, a
+    latent ``[B, slots, C]``, the indexer's key — as against the scalar
+    ``cache_index`` and a row's recurrent state: what row scatters,
+    exports, pages and beam gathers slice.  A state is told by its name
+    (``_STATE_LEAVES``): its ``[B, heads, P, N]`` has K's rank."""
+    return getattr(leaf, "ndim", 0) >= 3 and not _is_row_state(path)
+
+
+def _is_row_state(path) -> bool:
+    return _leaf_name(path) in _STATE_LEAVES
+
+
+def _keeps_state(model: Any) -> bool:
+    """Whether ``model`` has state-space layers (``config.mamba``): what
+    handoffs, pages, the prefix store, beam search, the host loops and a
+    hidden-state draft cannot move or rewind yet."""
+    return bool(getattr(model.config, "keeps_state", False))
+
+
+def _commit_kw(model: Any, n: int) -> dict:
+    """``commit=n`` for a model with state-space layers (how many of a
+    decode pass's tokens their state takes in); nothing for any other."""
+    return {"commit": n} if _keeps_state(model) else {}
+
+
+def _pending_kw(model: Any, n_draft: int) -> dict:
+    """For a model with state-space layers, its config's ``mamba`` with a
+    cache that holds a round's ``n_draft`` unconfirmed tokens pending;
+    nothing for any other."""
+    if not _keeps_state(model):
+        return {}
+    return {"mamba": dataclasses.replace(model.config.mamba,
+                                         pending=int(n_draft))}
 
 
 def _latent(model: Any) -> bool:
@@ -405,6 +445,12 @@ def _speculative_loop(
             f"{caller} requires batch=1 (got {B}): acceptance length is "
             f"data-dependent per row"
         )
+    if _keeps_state(model) or _keeps_state(draft_model):
+        raise ValueError(
+            f"{caller} cannot run state-space layers (mamba) yet: it rewinds "
+            f"caches by their cache_index, which a recurrent state cannot "
+            f"follow (speculative_generate_batched and ContinuousBatcher "
+            f"hold the unaccepted tokens pending instead)")
     if n_draft < 1:
         raise ValueError(f"{caller} needs n_draft >= 1, got {n_draft}")
     total = P + max_new_tokens
@@ -581,11 +627,15 @@ def _accept_resample_rows(p_rows: jax.Array, q_rows: jax.Array,
 def _scatter_row(batch_cache: Any, one_cache: Any, row) -> Any:
     """Put a batch-1 cache into row ``row`` of a batch cache: payload
     leaves (K/V ``[B, slots, KV, D]``, int8 scales, a latent ``[B, slots,
-    C]``) take the fresh row; the scalar ``cache_index`` is bookkeeping
+    C]``) take the fresh row; a recurrent state's leaves replace the row's
+    whole (a state is not masked by position: what the previous occupant
+    left must not survive); the scalar ``cache_index`` is bookkeeping
     only under per-row frontiers — kept monotone so rolling-cache chunk
     math stays conservative."""
-    def put(a, b):
-        if not _is_cache_payload(a):
+    def put(path, a, b):
+        if _is_row_state(path):
+            return a.at[row].set(b[0])
+        if not _is_cache_payload(path, a):
             return jnp.maximum(a, b)
         if b.shape[1] == a.shape[1]:
             return a.at[row].set(b[0])
@@ -595,7 +645,7 @@ def _scatter_row(batch_cache: Any, one_cache: Any, row) -> Any:
         return jax.lax.dynamic_update_slice(
             a, b, (row,) + (0,) * (a.ndim - 1))
 
-    return jax.tree_util.tree_map(put, batch_cache, one_cache)
+    return jax.tree_util.tree_map_with_path(put, batch_cache, one_cache)
 
 
 def _spec_prefill_impl(model, draft_model, params, draft_params, prompt,
@@ -664,6 +714,15 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
     anything can attend to it.  The same masking argument admits a NEW
     request into a retired row mid-batch (:func:`_spec_admit`): the old
     request's leftover K/V beyond the fresh prompt are invisible to it.
+
+    A recurrent state has no positions to mask by, so a model with
+    state-space layers commits only what is certain (the chunk's first
+    token, always accepted) and holds the rest of the chunk's inputs
+    pending; its next pass applies the ones its frontier says were
+    accepted first (:mod:`rocket_tpu.models.mamba`).  The draft's chain
+    commits its first step alone (the step index is the traced ``commit``)
+    and holds its later ones pending the same way, so both models' states
+    end each round after exactly the ``j + 1`` tokens the row accepted.
     """
     (buf, n_tok, done_in, cache_t, cache_d, key_in,
      (rounds, drafted, accepted)) = state[:7]
@@ -692,11 +751,16 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
     def draft_step(carry, xs):
         cache_d, tok = carry
         i, ki = xs
+        # a state-space draft commits its first step (the row's pending
+        # token, always accepted) and holds the later ones pending until
+        # the round knows how many of them it accepted
         out, mut = draft_model.apply(
             {"params": draft_params, "cache": cache_d},
             {"tokens": tok[:, None], "positions": (pos + i)[:, None],
              "idle": done_in},
             decode=True, mutable=mutable,
+            **({"commit": (i == 0).astype(jnp.int32)}
+               if _keeps_state(draft_model) else {}),
         )
         logits = out["logits"][:, 0].astype(jnp.float32)
         if sampled:
@@ -728,7 +792,7 @@ def _spec_round_impl(model, draft_model, params, draft_params, state,
     out, mut = model.apply(
         {"params": params, "cache": cache_t},
         {"tokens": chunk, "positions": pos[:, None] + ar, "idle": done_in},
-        decode=True, mutable=mutable,
+        decode=True, mutable=mutable, **_commit_kw(model, 1),
     )
     cache_t = mut["cache"]
     t_logits = out["logits"].astype(jnp.float32)        # [B, k+1, V]
@@ -893,7 +957,8 @@ def _spec_batched_call(model, draft_model, params, draft_params, prompt,
                 f"region"
             )
     per_row = lambda m: type(m)(  # noqa: E731
-        dataclasses.replace(m.config, decode_per_row=True)
+        dataclasses.replace(m.config, decode_per_row=True,
+                            **_pending_kw(m, n_draft))
     )
     buf, (rounds, drafted, accepted) = _spec_batched_run(
         per_row(model), per_row(draft_model), params, draft_params, prompt,
@@ -1577,10 +1642,10 @@ class KVHandoff:
         cache_t, cache_d = jax.tree_util.tree_map(
             np.asarray, (self.cache_t, self.cache_d))
 
-        def page_slice(a, lo, hi):
+        def page_slice(path, a, lo, hi):
             # owned copies: a view would retain the whole parent buffer
             # and break the store's byte accounting
-            if _is_cache_payload(a):
+            if _is_cache_payload(path, a):
                 return np.ascontiguousarray(a[:, lo:hi])
             return np.asarray(a).copy()
 
@@ -1589,10 +1654,10 @@ class KVHandoff:
             lo, hi = i * page_tokens, (i + 1) * page_tokens
             pages.append(KVPage(
                 tokens=buf[0, lo:hi].copy(),
-                cache_t=jax.tree_util.tree_map(
-                    lambda a: page_slice(a, lo, hi), cache_t),
-                cache_d=jax.tree_util.tree_map(
-                    lambda a: page_slice(a, lo, hi), cache_d),
+                cache_t=jax.tree_util.tree_map_with_path(
+                    lambda p, a: page_slice(p, a, lo, hi), cache_t),
+                cache_d=jax.tree_util.tree_map_with_path(
+                    lambda p, a: page_slice(p, a, lo, hi), cache_d),
             ))
         return pages
 
@@ -1625,9 +1690,9 @@ class KVHandoff:
                     f"only {slots} slots"
                 )
 
-            def leaf_join(*leaves):
+            def leaf_join(path, *leaves):
                 a0 = np.asarray(leaves[0])
-                if not _is_cache_payload(a0):
+                if not _is_cache_payload(path, a0):
                     return np.asarray(covered, a0.dtype)  # cache_index
                 cat = np.concatenate(
                     [np.asarray(leaf) for leaf in leaves], axis=1)
@@ -1637,7 +1702,7 @@ class KVHandoff:
                 )
                 return np.concatenate([cat, pad], axis=1)
 
-            return jax.tree_util.tree_map(leaf_join, *trees)
+            return jax.tree_util.tree_map_with_path(leaf_join, *trees)
 
         buf = np.zeros((1, total_len), np.int32)
         buf[0, :covered] = np.concatenate(
@@ -1654,9 +1719,10 @@ class KVHandoff:
 def export_kv_row(state, row: int) -> KVHandoff:
     """Slice one row of a batched round state into a :class:`KVHandoff`.
 
-    Rank-4 cache leaves (K/V payload and int8 scales alike) slice to
+    Payload leaves (K/V and int8 scales alike) slice to
     batch 1; scalar leaves (``cache_index``) copy whole — the exact
     inverse discrimination :func:`_spec_import_row` applies on import.
+    A recurrent state is refused: a handoff carries slots, not states.
     Every leaf of the handoff is a buffer of its own: the next round or
     admission donates ``state``, and a handoff may outlive it (the prefix
     store, a parked preemption, a peer).
@@ -1670,14 +1736,18 @@ def export_kv_row(state, row: int) -> KVHandoff:
     # a two-model round's device counters (an eighth entry) stay behind:
     # they are the batch's, not the row's
     (buf, n_tok, done, cache_t, cache_d, _key, _stats) = state[:7]
-    sl = lambda a: (a[row:row + 1] if _is_cache_payload(a)  # noqa: E731
-                    else jnp.array(a, copy=True))
+    if any(_is_row_state(path) for path, _ in
+           jax.tree_util.tree_flatten_with_path((cache_t, cache_d))[0]):
+        raise ValueError("KVHandoff cannot carry a state-space layer's "
+                         "recurrent state yet")
+    sl = lambda p, a: (a[row:row + 1] if _is_cache_payload(p, a)  # noqa: E731
+                       else jnp.array(a, copy=True))
     return KVHandoff(
         buf=buf[row:row + 1],
         n_tok=n_tok[row:row + 1],
         done=done[row:row + 1],
-        cache_t=jax.tree_util.tree_map(sl, cache_t),
-        cache_d=jax.tree_util.tree_map(sl, cache_d),
+        cache_t=jax.tree_util.tree_map_with_path(sl, cache_t),
+        cache_d=jax.tree_util.tree_map_with_path(sl, cache_d),
     )
 
 
@@ -1759,7 +1829,12 @@ class ContinuousBatcher:
         # then one pass of the draft over the target's hidden states.
         self._hidden_draft = bool(getattr(draft_model, "reads_hidden", False))
         self.n_draft, self.sampled = int(n_draft), bool(sampled)
-        self._check_hidden_draft()
+        self._base_models = (model, draft_model)  # for set_kv_cache_int8
+        # a state-space layer's cache holds a round's unconfirmed drafts
+        # pending: room for the first n_draft (the loop only lowers it)
+        from rocket_tpu.ops.ssm import MAX_CHUNK
+        self._pending = min(self.n_draft, MAX_CHUNK - 1)
+        self._check_refusals()
         if sampled and temperature <= 0.0:
             raise ValueError(
                 "sampled=True needs temperature > 0; use sampled=False "
@@ -1787,11 +1862,11 @@ class ContinuousBatcher:
         if kv_cache_int8 is not None:
             overrides["kv_cache_int8"] = bool(kv_cache_int8)
         per_row = lambda m: m.clone(  # noqa: E731
-            config=dataclasses.replace(m.config, **overrides)
+            config=dataclasses.replace(m.config, **overrides,
+                                       **_pending_kw(m, self._pending))
         )
         self._model = per_row(model)
         self._draft_model = per_row(draft_model)
-        self._base_models = (model, draft_model)  # for set_kv_cache_int8
         self._params = params
         self._draft_params = draft_params
         self.total_len = int(total_len)
@@ -1818,9 +1893,12 @@ class ContinuousBatcher:
         from rocket_tpu.ops import latent_attention as la
 
         cfg = self._model.config
-        leaves = jax.tree_util.tree_leaves(self.state[3])
+        leaves = {}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                self.state[3])[0]:
+            leaves.setdefault(_leaf_name(path), leaf)
         if _latent(self._model):
-            cache = next(leaf for leaf in leaves if leaf.ndim == 3)
+            cache = leaves["cached_latent"]
             q = jax.ShapeDtypeStruct(
                 (cache.shape[0], self.n_draft + 1, cfg.n_heads,
                  cache.shape[2]), cache.dtype)
@@ -1831,9 +1909,9 @@ class ContinuousBatcher:
         # a configuration that lacks the field is not ``TransformerConfig``:
         # its attention is not ``Attention._decode_attend``'s
         if getattr(cfg, "decode_rolling_cache", True) \
-                or _selects(self._model):
+                or _selects(self._model) or "cached_k" not in leaves:
             return None
-        k = next(leaf for leaf in leaves if leaf.ndim == 4)
+        k = leaves["cached_k"]
         q = jax.ShapeDtypeStruct(
             (k.shape[0], self.n_draft + 1, cfg.n_heads, cfg.head_dim),
             k.dtype)
@@ -1861,7 +1939,7 @@ class ContinuousBatcher:
         rebuilt = lambda m: m.clone(  # noqa: E731
             config=dataclasses.replace(
                 m.config, decode_per_row=True,
-                kv_cache_int8=bool(enabled),
+                kv_cache_int8=bool(enabled), **_pending_kw(m, self._pending),
             )
         )
         self._model = rebuilt(model)
@@ -1871,10 +1949,23 @@ class ContinuousBatcher:
         return dict(eos_token=self.eos_token, sampled=self.sampled,
                     top_k=self._top_k, top_p=self._top_p)
 
-    def _check_hidden_draft(self) -> None:
-        """What a draft that reads the target's hidden state cannot do yet,
-        refused by name (``n_draft`` is the serving loop's to set between
-        rounds, so a round checks again)."""
+    def _check_refusals(self) -> None:
+        """What a draft that reads the target's hidden state, and a model
+        with state-space layers, cannot do yet, refused by name
+        (``n_draft`` is the serving loop's to set between rounds, so a
+        round checks again)."""
+        if any(_keeps_state(m) for m in self._base_models):
+            refused = [what for what, on in (
+                ("a draft that reads the target's hidden state (_mtp_*)",
+                 self._hidden_draft),
+                (f"n_draft={self.n_draft} (more than the {self._pending} "
+                 f"tokens its caches hold pending)",
+                 self.n_draft > self._pending),
+            ) if on]
+            if refused:
+                raise ValueError(
+                    f"state-space layers (mamba) cannot run with "
+                    f"{', '.join(refused)} yet")
         if not self._hidden_draft:
             return
         refused = [what for what, on in (
@@ -1891,7 +1982,7 @@ class ContinuousBatcher:
         """Whether a row's state is what :class:`KVHandoff` and
         :class:`KVPage` carry: K/V caches of two language models."""
         return not (self._hidden_draft
-                    or any(_latent(m) or _selects(m)
+                    or any(_latent(m) or _selects(m) or _keeps_state(m)
                            for m in (self._model, self._draft_model)))
 
     def _refuse_handoff(self, what: str) -> None:
@@ -1899,7 +1990,8 @@ class ContinuousBatcher:
             raise ValueError(
                 f"{what}: KVHandoff and KVPage cannot carry a latent cache, "
                 f"the cache of an attention that chooses its keys (select: "
-                f"its prefix pages and the prefix store are untested) or the "
+                f"its prefix pages and the prefix store are untested), the "
+                f"recurrent state of state-space layers (mamba) or the "
                 f"state of a draft that reads the target's hidden state yet")
 
     def start(self, prompts) -> None:
@@ -1946,8 +2038,8 @@ class ContinuousBatcher:
         if self.state is None:
             raise ValueError("call start() before step()")
         with self.reads.tracer.span("serve/dispatch", n_draft=self.n_draft):
+            self._check_refusals()
             if self._hidden_draft:
-                self._check_hidden_draft()
                 self.state = ledger_call(
                     _mtp_round, "generate/spec_round",
                     self._model, self._draft_model, self._params,
@@ -2398,6 +2490,10 @@ def _accept_resample(p_rows: "np.ndarray", q_rows: "np.ndarray",
 
 def _validate_beam_lm(model, P, max_new_tokens, beam_size):
     """Shared loud validation for the decoder-only beam entry points."""
+    if _keeps_state(model):
+        raise ValueError(
+            "beam search cannot run state-space layers (mamba) yet: the "
+            "beam gather moves cache slots, not a recurrent state")
     if _latent(model):
         raise ValueError(
             "beam search cannot run a latent-attention (mla) model yet: "
@@ -2532,8 +2628,8 @@ def beam_search_cached(
     )
     # tile [B, slots, KV, D] -> [B*K, ...] matching buf.reshape(B*K, ...)
     # row order; the scalar cache_index stays shared (uniform frontiers)
-    cache = jax.tree_util.tree_map(
-        lambda a: jnp.repeat(a, K, axis=0) if _is_cache_payload(a)
+    cache = jax.tree_util.tree_map_with_path(
+        lambda p, a: jnp.repeat(a, K, axis=0) if _is_cache_payload(p, a)
         else a,
         cache,
     )
@@ -2541,8 +2637,8 @@ def beam_search_cached(
 
     def gather_cache(cache, src_beam):
         flat = (row0 + src_beam).reshape(-1)
-        return jax.tree_util.tree_map(
-            lambda a: a[flat] if _is_cache_payload(a) else a, cache
+        return jax.tree_util.tree_map_with_path(
+            lambda p, a: a[flat] if _is_cache_payload(p, a) else a, cache
         )
 
     scores = jnp.full((B, K), -jnp.inf).at[:, 0].set(0.0)

@@ -54,6 +54,7 @@ from rocket_tpu.models.layers import (
     apply_rope,
     rotary_embedding,
 )
+from rocket_tpu.models.mamba import MambaConfig, MambaMixer
 from rocket_tpu.models.moe import ExpertsConfig, RoutedExperts
 from rocket_tpu.ops.attention import attend, dot_attention
 from rocket_tpu.parallel.context import constrain
@@ -249,8 +250,30 @@ class TransformerConfig:
     # (``batch['mrope_positions']`` ``[3, B, S]``); text, which has one
     # position, gives the same to all three and gets plain RoPE.
     mrope_section: Optional[tuple] = None
+    # The kind of each layer's mixer, one a layer: "attention" (the q/k/v
+    # heads above) or "mamba" (a state-space layer of ``mamba``'s sizes,
+    # :mod:`rocket_tpu.models.mamba`).  None: every layer attends.
+    layer_types: Optional[tuple] = None
+    mamba: Optional[MambaConfig] = None
+    # Granite's scalars; None emits no operation.  The embeddings times
+    # ``embedding_multiplier``; each sublayer's output times
+    # ``residual_multiplier`` before it joins the stream; the logits over
+    # ``logits_scaling``; the attention's softmax scale
+    # ``attention_multiplier`` in place of 1/sqrt(head_dim).
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
+    attention_multiplier: Optional[float] = None
 
     def __post_init__(self) -> None:
+        if self.positions not in ("rope", "learned", "none"):
+            raise ValueError(f"positions={self.positions!r}: 'rope', "
+                             f"'learned' or 'none'")
+        if self.layer_types is not None or self.mamba is not None:
+            self._check_pattern()
+        if self.fused_ce and self.logits_scaling is not None:
+            raise ValueError("fused_ce reads the tied table's logits as they "
+                             "are: it cannot run with logits_scaling yet")
         if self.select is not None:
             refused = [name for name, on in (
                 ("mla", self.mla is not None),
@@ -362,6 +385,48 @@ class TransformerConfig:
                 "(scan_layers=False): scan stacks kernels to rank 3, "
                 "which quantize_params rejects"
             )
+
+    def _check_pattern(self) -> None:
+        """``layer_types`` names a kind for every layer; state-space layers
+        need ``mamba`` (and ``mamba`` a layer to size), and what they cannot
+        run with yet is refused by name."""
+        kinds = tuple(self.layer_types or ())
+        if len(kinds) != self.n_layers \
+                or set(kinds) - {"attention", "mamba"}:
+            raise ValueError(
+                f"layer_types needs 'attention' or 'mamba' for each of the "
+                f"{self.n_layers} layers, got {self.layer_types}")
+        if ("mamba" in kinds) != (self.mamba is not None):
+            raise ValueError("state-space layers need mamba= (their sizes), "
+                             "and mamba= a 'mamba' layer in layer_types")
+        if self.mamba is None:
+            return
+        if self.mamba.inner != self.mamba.expand * self.hidden:
+            raise ValueError(
+                f"mamba: n_heads x head_dim ({self.mamba.inner}) must be "
+                f"expand x hidden ({self.mamba.expand * self.hidden})")
+        refused = [name for name, on in (
+            ("mla", self.mla is not None),
+            ("select", self.select is not None),
+            ("experts", self.experts is not None),
+            ("n_experts", self.n_experts > 0),
+            ("kv_cache_int8", self.kv_cache_int8),
+            ("decode_rolling_cache", self.decode_rolling_cache),
+            ("attention_window", self.attention_window is not None),
+            ("scan_layers", self.scan_layers),
+            ("pipeline_microbatches", self.pipeline_microbatches > 0),
+            ("pipeline_microbatch_size", self.pipeline_microbatch_size > 0),
+            ("causal=False", not self.causal),
+            ("fused_qkv", self.fused_qkv),
+        ) if on]
+        if refused:
+            raise ValueError(f"state-space layers (mamba) cannot run with "
+                             f"{', '.join(refused)} yet")
+
+    @property
+    def keeps_state(self) -> bool:
+        """Whether a layer keeps a recurrent state (a ``mamba`` layer)."""
+        return self.mamba is not None
 
     @property
     def pipelined(self) -> bool:
@@ -534,6 +599,9 @@ class Attention(nn.Module):
                 D, cfg.rope_theta, x.dtype, mrope_section=cfg.mrope_section)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
+        if cfg.attention_multiplier is not None:
+            # every path below scales the scores by 1/sqrt(D)
+            q = q * jnp.asarray(cfg.attention_multiplier * D ** 0.5, q.dtype)
         if cfg.select is not None:
             if segment_ids is not None:
                 raise ValueError("attention that chooses its keys (select) "
@@ -974,19 +1042,27 @@ class Block(nn.Module):
     """Returns ``(x, aux)`` — aux is the MoE load-balancing loss
     contribution (0.0 for dense blocks).  ``routed`` makes this layer one
     of ``config.experts`` (shared experts plus the routed ones held here)
-    where the stack's leading layers keep the dense MLP."""
+    where the stack's leading layers keep the dense MLP.  ``mixer`` is the
+    layer's kind in ``config.layer_types``: ``"mamba"`` puts a state-space
+    mixer where attention is (``commit`` is its decode pass's)."""
 
     config: TransformerConfig
     routed: bool = False
     # with config.residual_float32: the type the sublayers compute in
     act_dtype: Any = None
+    mixer: str = "attention"
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, train: bool,
                  decode: bool = False, prefill: bool = False, idle=None,
-                 mrope_positions=None):
+                 mrope_positions=None, commit=None):
         cfg = self.config
         x = constrain(x, "batch", "sequence", "act_embed")
+        if cfg.residual_multiplier is not None:
+            res = lambda t: t * jnp.asarray(  # noqa: E731
+                cfg.residual_multiplier, t.dtype)
+        else:
+            res = lambda t: t  # noqa: E731
 
         def pre(name, t):
             """The norm a sublayer reads through: cast for its matrix
@@ -1000,7 +1076,15 @@ class Block(nn.Module):
                 t = t.astype(x.dtype)
             return _Norm(cfg, name=name)(t) if cfg.sandwich_norm else t
 
-        if cfg.mla is not None:
+        if self.mixer == "mamba":
+            if segment_ids is not None:
+                raise ValueError("state-space layers (mamba) cannot run over "
+                                 "packed sequences yet")
+            y = MambaMixer(cfg, name="mamba")(
+                pre("ln1", x)[0], positions, train, decode=decode, idle=idle,
+                commit=commit,
+            )
+        elif cfg.mla is not None:
             y = LatentAttention(cfg, name="attn")(
                 pre("ln1", x)[0], positions, segment_ids, train,
                 decode=decode, prefill=prefill, idle=idle,
@@ -1010,7 +1094,7 @@ class Block(nn.Module):
                 pre("ln1", x)[0], positions, segment_ids, train,
                 decode=decode, idle=idle, mrope_positions=mrope_positions,
             )
-        x = x + post("ln1_post", y)
+        x = x + res(post("ln1_post", y))
         aux = jnp.zeros((), jnp.float32)
         h, h_wide = pre("ln2", x)
         if self.routed:
@@ -1035,7 +1119,7 @@ class Block(nn.Module):
             )(h, train)
         else:
             y = MLP(cfg, name="mlp")(h, train)
-        x = x + post("ln2_post", y)
+        x = x + res(post("ln2_post", y))
         return constrain(x, "batch", "sequence", "act_embed"), aux
 
 
@@ -1164,11 +1248,14 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, batch, train: bool = False, decode: bool = False,
-                 prefill: bool = False):
+                 prefill: bool = False, commit: Optional[int] = None):
         """``prefill`` (with ``decode``) says the cache holds nothing before
         this chunk and its positions start at 0: a latent-attention model
         then expands keys and values over the chunk instead of scoring it
-        against every cache slot.  Other models ignore it."""
+        against every cache slot.  Other models ignore it.  ``commit``
+        (with ``decode``) is how many of the chunk's tokens a state-space
+        layer takes into its state (:mod:`rocket_tpu.models.mamba`; None:
+        all); a model without one ignores it."""
         cfg = self.config
         if decode and (cfg.scan_layers or cfg.remat or cfg.pipelined):
             raise ValueError(
@@ -1188,6 +1275,8 @@ class TransformerLM(nn.Module):
         embed = Embed(cfg.vocab_size, cfg.hidden,
                       weights_int8=cfg.weights_int8, name="embed")
         x = embed(tokens)
+        if cfg.embedding_multiplier is not None:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
         if cfg.positions == "learned":
             pos_table = self.param(
                 "pos_embedding",
@@ -1253,9 +1342,14 @@ class TransformerLM(nn.Module):
             if cfg.mrope_section is not None and hasattr(batch, "get") \
                     and batch.get("mrope_positions") is not None:
                 extra["mrope_positions"] = batch.get("mrope_positions")
+            if decode and cfg.keeps_state:
+                extra["commit"] = commit
             for i in range(cfg.n_layers):
                 pattern = {"routed": True} if (
                     cfg.experts is not None and i >= cfg.first_k_dense) else {}
+                if cfg.layer_types is not None \
+                        and cfg.layer_types[i] != "attention":
+                    pattern["mixer"] = cfg.layer_types[i]
                 x, aux = block_cls(cfg, name=f"block_{i}", **pattern,
                                    **stream)(
                     x, positions, segment_ids, train, **extra
@@ -1294,6 +1388,9 @@ class TransformerLM(nn.Module):
                     cfg.vocab_size, logical_axes=("embed", "vocab"),
                     weights_int8=cfg.weights_int8, name="head"
                 )(x)
+            if cfg.logits_scaling is not None:
+                logits = logits / jnp.asarray(cfg.logits_scaling,
+                                              logits.dtype)
             logits = constrain(logits, "batch", "sequence", "vocab")
             out[self.logits_key] = logits
         if cfg.n_experts > 0:
@@ -1339,6 +1436,9 @@ class MTPDraft(nn.Module):
     def __call__(self, batch, train: bool = False, decode: bool = False,
                  prefill: bool = False):
         cfg = self.config
+        if cfg.keeps_state:
+            raise ValueError("a draft that reads the target's hidden state "
+                             "(MTPDraft) cannot hold state-space layers yet")
         tokens = batch["tokens"]
         B, S = tokens.shape
         positions = batch.get("positions")

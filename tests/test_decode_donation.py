@@ -148,8 +148,8 @@ def _started(models, kind):
 
 
 def _payload(state):
-    return [leaf for leaf in jax.tree_util.tree_leaves((state[3], state[4]))
-            if generate_mod._is_cache_payload(leaf)]
+    return [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
+        (state[3], state[4]))[0] if generate_mod._is_cache_payload(path, leaf)]
 
 
 def _nbytes(tree):

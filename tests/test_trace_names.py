@@ -105,6 +105,9 @@ def test_library_emits_trace_events():
             # ISSUE 33: attention that chooses its keys, chunked admission
             "attention/select/decode", "attention/select/prefill",
             "generate/spec_admit",
+            # which state update a compiled round holds, and the chunked
+            # scan of a prompt through a state-space layer
+            "ssm/decode/kernel", "ssm/decode/fallback", "ssm/prefill",
             # multi-tenant serving: preemption lifecycle markers
             "serve/preempt", "serve/resume",
             # distributed request tracing: the stitched-timeline and
@@ -184,6 +187,9 @@ KNOWN_JIT_SITES = {
     ("ops/select_attention.py", "masked_attention"):
         "exempt: inner edge of the ledgered admission, so that its layers "
         "and chunks share one trace and one lowered kernel",
+    ("ops/ssm.py", "ssm_decode"):
+        "exempt: inner edge of the ledgered round, so that its state-space "
+        "layers share one trace and one lowered kernel",
     ("observe/meter.py", "_launch_in_step"):
         "exempt: MFU meter's own probe, must not perturb the ledger",
     ("parallel/mpmd.py", "__init__"):
