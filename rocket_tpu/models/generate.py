@@ -93,6 +93,10 @@ def _sample(logits: jax.Array, rng: jax.Array, temperature: float,
 # :mod:`rocket_tpu.models.mamba`): a row's is replaced whole, never sliced.
 _STATE_LEAVES = frozenset({"ssm_state", "conv_state", "dt_state",
                            "state_pos"})
+# Payload leaves stored slots last (the indexer's keys ``[B, index_dim,
+# slots]``, the layout a TPU keeps for their score product); every other
+# payload leaf holds its slots on axis 1.
+_SLOTS_LAST_LEAVES = frozenset({"cached_index_k"})
 
 
 def _leaf_name(path) -> str:
@@ -100,10 +104,16 @@ def _leaf_name(path) -> str:
     return str(getattr(last, "key", getattr(last, "name", last)))
 
 
+def _slot_axis(path) -> int:
+    """The axis of a payload leaf's slots."""
+    return -1 if _leaf_name(path) in _SLOTS_LAST_LEAVES else 1
+
+
 def _is_cache_payload(path, leaf) -> bool:
     """Whether the cache leaf at ``path`` holds rows of tokens slot by slot
     — K/V ``[B, slots, KV, D]``, their int8 scales ``[B, slots, KV, 1]``, a
-    latent ``[B, slots, C]``, the indexer's key — as against the scalar
+    latent ``[B, slots, C]``, the indexer's keys ``[B, index_dim, slots]``
+    (:func:`_slot_axis`) — as against the scalar
     ``cache_index`` and a row's recurrent state: what row scatters,
     exports, pages and beam gathers slice.  A state is told by its name
     (``_STATE_LEAVES``): its ``[B, heads, P, N]`` has K's rank."""
@@ -627,7 +637,8 @@ def _accept_resample_rows(p_rows: jax.Array, q_rows: jax.Array,
 def _scatter_row(batch_cache: Any, one_cache: Any, row) -> Any:
     """Put a batch-1 cache into row ``row`` of a batch cache: payload
     leaves (K/V ``[B, slots, KV, D]``, int8 scales, a latent ``[B, slots,
-    C]``) take the fresh row; a recurrent state's leaves replace the row's
+    C]``, the indexer's keys ``[B, index_dim, slots]``) take the fresh
+    row; a recurrent state's leaves replace the row's
     whole (a state is not masked by position: what the previous occupant
     left must not survive); the scalar ``cache_index`` is bookkeeping
     only under per-row frontiers — kept monotone so rolling-cache chunk
@@ -637,7 +648,8 @@ def _scatter_row(batch_cache: Any, one_cache: Any, row) -> Any:
             return a.at[row].set(b[0])
         if not _is_cache_payload(path, a):
             return jnp.maximum(a, b)
-        if b.shape[1] == a.shape[1]:
+        ax = _slot_axis(path)
+        if b.shape[ax] == a.shape[ax]:
             return a.at[row].set(b[0])
         # a prefill into a cache of the prompt's own length
         # (:func:`_row_prefill`): the head of the row's slab; what the
@@ -1548,8 +1560,9 @@ def _mtp_admit(model, draft_model, params, draft_params, state, row,
 class KVPage:
     """One fixed-granularity slice of a prefilled row: ``page_tokens``
     consecutive token ids plus both models' K/V cache slots for exactly
-    those positions.  Rank-4 cache leaves (int8 payload and its rank-4
-    scales alike) are sliced along the slot axis; scalar leaves
+    those positions.  Payload leaves (int8 payload and its rank-4 scales
+    alike) are sliced along their slot axis (:func:`_slot_axis`); scalar
+    leaves
     (``cache_index``) ride along so :meth:`KVHandoff.from_pages` can
     rebuild a tree with the original structure.  Leaves are OWNED copies
     (never views), so a page's ``nbytes`` is its true retained size —
@@ -1646,7 +1659,9 @@ class KVHandoff:
             # owned copies: a view would retain the whole parent buffer
             # and break the store's byte accounting
             if _is_cache_payload(path, a):
-                return np.ascontiguousarray(a[:, lo:hi])
+                cut = [slice(None)] * a.ndim
+                cut[_slot_axis(path)] = slice(lo, hi)
+                return np.ascontiguousarray(a[tuple(cut)])
             return np.asarray(a).copy()
 
         pages = []
@@ -1694,13 +1709,12 @@ class KVHandoff:
                 a0 = np.asarray(leaves[0])
                 if not _is_cache_payload(path, a0):
                     return np.asarray(covered, a0.dtype)  # cache_index
+                ax = _slot_axis(path)
                 cat = np.concatenate(
-                    [np.asarray(leaf) for leaf in leaves], axis=1)
-                pad = np.zeros(
-                    (cat.shape[0], slots - cat.shape[1]) + cat.shape[2:],
-                    cat.dtype,
-                )
-                return np.concatenate([cat, pad], axis=1)
+                    [np.asarray(leaf) for leaf in leaves], axis=ax)
+                room = [(0, 0)] * cat.ndim
+                room[ax] = (0, slots - cat.shape[ax])
+                return np.pad(cat, room)
 
             return jax.tree_util.tree_map_with_path(leaf_join, *trees)
 
@@ -1719,8 +1733,8 @@ class KVHandoff:
 def export_kv_row(state, row: int) -> KVHandoff:
     """Slice one row of a batched round state into a :class:`KVHandoff`.
 
-    Payload leaves (K/V and int8 scales alike) slice to
-    batch 1; scalar leaves (``cache_index``) copy whole — the exact
+    Payload leaves (K/V, int8 scales and the indexer's keys alike) slice
+    to batch 1; scalar leaves (``cache_index``) copy whole — the exact
     inverse discrimination :func:`_spec_import_row` applies on import.
     A recurrent state is refused: a handoff carries slots, not states.
     Every leaf of the handoff is a buffer of its own: the next round or

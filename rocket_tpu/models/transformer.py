@@ -84,7 +84,9 @@ class SelectConfig:
     scores every key a query may see, and the query attends the ``top_k``
     best alone.  ``chunk`` is how many queries of a prompt are admitted at
     a time (a tile size: it changes no result, it bounds the scores that
-    are alive at once)."""
+    are alive at once).  The cached keys are one leaf a layer,
+    ``cached_index_k`` ``[rows, index_dim, slots]``: slots last, as a TPU
+    lays it out for the score product."""
 
     index_heads: int
     index_dim: int
@@ -240,7 +242,7 @@ class TransformerConfig:
     residual_float32: bool = False
     # Attention that chooses its keys (None = every key at or before the
     # query): a learned indexer beside the q/k/v heads and one more cache
-    # leaf a layer, ``cached_index_k`` ``[rows, slots, 1, index_dim]``.
+    # leaf a layer, ``cached_index_k`` ``[rows, index_dim, slots]``.
     select: Optional[SelectConfig] = None
     # RMSNorm with a learned scale over each query and key head, before
     # the rotation (Qwen3-style).
@@ -643,7 +645,10 @@ class Attention(nn.Module):
         ``w = W_w x / sqrt(index_heads * index_dim)``; both rotate over
         all their numbers by the token's own position.  The kept and the
         live keys of every query are sown (``selection/keys``
-        ``[B, S, 2]``) for the serving round's counters."""
+        ``[B, S, 2]``) for the serving round's counters.  The indexer's
+        keys are laid out ``[B, index_dim, S]``, slots last: the layout a
+        TPU keeps for the score product, so the cache is written and
+        scored as stored, with no relayout between."""
         from rocket_tpu.ops.select_attention import (
             index_scores,
             selected_attention,
@@ -660,6 +665,7 @@ class Attention(nn.Module):
         w_idx = dense(J, "index_w")(x).astype(jnp.float32) * (J * d) ** -0.5
         cos, sin = rotary_embedding(positions, d, cfg.rope_theta, x.dtype)
         q_idx, k_idx = apply_rope(q_idx, cos, sin), apply_rope(k_idx, cos, sin)
+        k_idx = k_idx[:, :, 0].swapaxes(1, 2)                 # [B, d, S]
 
         def attend_selected(k_all, v_all, k_idx_all, q_pos):
             scores = index_scores(q_idx, w_idx, k_idx_all, q_pos, idle)
@@ -699,9 +705,10 @@ class Attention(nn.Module):
         It changes no write: an idle row's chunk lands at its positions
         like any other's.
 
-        ``index`` (a selecting layer's ``(k_idx, attend_selected)``) adds
-        the indexer's cache leaf ``cached_index_k`` ``[B, slots, 1,
-        index_dim]``, written where K and V are, and attends through
+        ``index`` (a selecting layer's ``(k_idx [B, index_dim, S],
+        attend_selected)``) adds the indexer's cache leaf
+        ``cached_index_k`` ``[B, index_dim, slots]``, written at the
+        slots K and V are, and attends through
         ``attend_selected`` over the written caches instead; that the
         decode kernel is not taken is counted as a fallback with reason
         ``selected``."""
@@ -747,11 +754,11 @@ class Attention(nn.Module):
             "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
         )
         if index is not None:
-            # rank 4 like K and V, so that every caller that moves cache
-            # rows by rank (scatter, export, import) carries it
+            # slots last: the callers that find a cache leaf's slots on
+            # axis 1 (row scatters, pages) tell this one by its name
             cached_index_k = self.variable(
                 "cache", "cached_index_k", jnp.zeros,
-                (B, n_slots, 1, index_k.shape[-1]), index_k.dtype,
+                (B, index_k.shape[1], n_slots), index_k.dtype,
             )
         if not is_filled:
             # init pass: create the cache shapes, attend normally (the
@@ -773,8 +780,6 @@ class Attention(nn.Module):
                       (k_scale, k_s), (v_scale, v_s)]
         else:
             writes = [(cached_k, k), (cached_v, v)]
-        if index is not None:
-            writes.append((cached_index_k, index_k))
 
         def write_all(write_fn):
             # Apply one write op uniformly to every cache leaf (payload
@@ -852,6 +857,13 @@ class Attention(nn.Module):
                 why_not_masked,
             )
 
+            if cfg.decode_per_row:
+                cached_index_k.value = jax.vmap(
+                    lambda c, u, s: jax.lax.dynamic_update_slice(c, u, (0, s))
+                )(cached_index_k.value, index_k, starts)
+            else:
+                cached_index_k.value = jax.lax.dynamic_update_slice(
+                    cached_index_k.value, index_k, (0, 0, idx))
             sel = cfg.select
             note_fallback("selected", q, n_slots)
             if S <= MAX_CHUNK:          # a round's chunk, as the kernel's

@@ -63,8 +63,9 @@ def index_scores(q_idx: Array, w_idx: Array, k_idx: Array, q_pos: Array,
     the row is ``idle``: its output is dropped, so it scores nothing).
 
     ``q_idx`` ``[B, S, J, d]``, ``w_idx`` ``[B, S, J]`` (already scaled),
-    ``k_idx`` ``[B, T, 1, d]`` (slot == position), ``q_pos`` ``[B, S]``."""
-    dots = jnp.einsum("bsjd,btd->bsjt", q_idx, k_idx[:, :, 0],
+    ``k_idx`` ``[B, d, T]`` (slots last, as the cache stores them; slot ==
+    position), ``q_pos`` ``[B, S]``."""
+    dots = jnp.einsum("bsjd,bdt->bsjt", q_idx, k_idx,
                       preferred_element_type=jnp.float32)
     # weighed and summed on the vector unit in float32: a matrix product
     # would round the weights to bfloat16 on their way into the MXU.  The
@@ -73,7 +74,7 @@ def index_scores(q_idx: Array, w_idx: Array, k_idx: Array, q_pos: Array,
     # compare numbers and bit patterns, order the same.
     scores = jnp.sum(w_idx.astype(jnp.float32)[..., None]
                      * jax.nn.relu(dots), axis=2) + 0.0
-    seen = jnp.arange(k_idx.shape[1])[None, None, :] <= q_pos[:, :, None]
+    seen = jnp.arange(k_idx.shape[-1])[None, None, :] <= q_pos[:, :, None]
     if idle is not None:
         seen &= ~idle[:, None, None]
     return jnp.where(seen, scores, -jnp.inf)

@@ -238,9 +238,9 @@ def test_the_entry_donates_its_state_and_aliases_every_cache_leaf(
     # and it cannot pass the state's bytes, since nothing else is donated.
     state = bat.state
     payload = _payload(state)
-    if kind == "select":      # a third leaf a layer, rank 4 like K and V
+    if kind == "select":      # a third leaf a layer, [rows, index_dim, slots]
         index = [p for p in payload
-                 if p.shape[2:] == (1, SELECT_ARCH["index_dim"])]
+                 if p.shape[1:] == (SELECT_ARCH["index_dim"], MAX_SEQ)]
         assert len(index) == 3 and len(payload) == 9
     assert _nbytes(state) - _nbytes(payload) < min(p.nbytes for p in payload)
     aliased = compiled.memory_analysis().alias_size_in_bytes

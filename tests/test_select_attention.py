@@ -137,7 +137,7 @@ def _prefill_then_decode(model, params, rows, prompts, steps, chunk=2):
         one, last = generate_mod._row_prefill(
             model, params, jnp.asarray(rows[r][None, :p]))
         leaf = one["block_0"]["attn"]["cached_index_k"]
-        assert leaf.shape == (1, p, 1, ARCH["index_dim"])
+        assert leaf.shape == (1, ARCH["index_dim"], p)
         cache = generate_mod._scatter_row(cache, one, r)
         got[r].append(last)
     for step in range(steps):
@@ -202,6 +202,45 @@ def test_a_bfloat16_cache_or_a_lost_index_is_caught(target, monkeypatch,
     got = _prefill_then_decode(model, params, [row], (22,), 4)[0]
     want = reference.full_logits(ARCH, "f32", getter(params), row)[21:]
     assert jnp.max(jnp.abs(got[1:] - want[1:])) > 10 * TOL
+
+
+@pytest.mark.parametrize("p", [ARCH["index_dim"], 13])
+def test_an_admission_into_a_used_row_decodes_as_the_reference(target, p):
+    """A prompt-length cache scattered into a row that a longer request
+    has used takes the head of the row's slab, and the indexer's leaf
+    ``[1, index_dim, p]`` goes there by its slots, also where ``p`` equals
+    ``index_dim`` and the leaf's second axis matches the slab's.  The row
+    then decodes, one token a step beside another row, to the reference's
+    logits: the old occupant's slots past the prompt are hidden
+    causally."""
+    model, params = per_row(target[0]), target[1]
+    old, new, other = rows_of(11, [30, p + 6, 16])
+    cache = generate_mod.zero_cache(
+        model, params, jnp.zeros((2, 1), jnp.int32))
+
+    def admit(cache, row, tokens):
+        one, last = generate_mod._row_prefill(model, params,
+                                              jnp.asarray(tokens[None]))
+        return generate_mod._scatter_row(cache, one, row), last
+
+    cache, _ = admit(cache, 0, old)
+    cache, _ = admit(cache, 1, other[:10])
+    cache, last = admit(cache, 0, new[:p])
+    leaf = cache["block_0"]["attn"]["cached_index_k"]
+    assert leaf.shape == (2, ARCH["index_dim"], MAX_SEQ)
+    assert float(jnp.abs(leaf[0, :, p:30]).max()) > 0    # the old row's
+    got = [last[0]]
+    for step in range(6):
+        starts = np.asarray([p, 10]) + step
+        out, mut = model.apply(
+            {"params": params, "cache": cache},
+            {"tokens": jnp.asarray([[new[starts[0]]], [other[starts[1]]]]),
+             "positions": jnp.asarray(starts[:, None], jnp.int32)},
+            decode=True, mutable=["cache"])
+        cache = mut["cache"]
+        got.append(out["logits"][0, 0])
+    want = reference.full_logits(ARCH, "f32", getter(params), new)[p - 1:]
+    np.testing.assert_allclose(jnp.stack(got), want, atol=TOL)
 
 
 def test_the_two_selections_keep_the_same_keys_ties_included():
@@ -616,9 +655,9 @@ def test_the_batcher_serves_plain_greedy_and_counts_what_it_chose(
 
 
 def test_export_and_import_carry_the_index_leaf(target, draft):
-    """``export_kv_row`` slices the indexer's cache with K and V (rank 4),
-    ``_spec_import_row`` puts it back, and the imported row decodes as the
-    row it was taken from."""
+    """``export_kv_row`` slices the indexer's cache with K and V (``(1,
+    index_dim, slots)``), ``_spec_import_row`` puts it back, and the
+    imported row decodes as the row it was taken from."""
     model, params = target
     prompts = rows_of(9, [13, 13])
     bat = ContinuousBatcher(model, draft[0], params, draft[1], total_len=30,
@@ -626,8 +665,8 @@ def test_export_and_import_carry_the_index_leaf(target, draft):
     bat.start(np.stack(prompts))
     handoff = export_kv_row(bat.state, 0)
     leaf = handoff.cache_t["block_0"]["attn"]["cached_index_k"]
-    assert leaf.shape == (1, MAX_SEQ, 1, ARCH["index_dim"])
-    assert float(jnp.abs(leaf[0, :13]).max()) > 0
+    assert leaf.shape == (1, ARCH["index_dim"], MAX_SEQ)
+    assert float(jnp.abs(leaf[0, :, :13]).max()) > 0
     while not bat.all_done:
         bat.step()
     want = np.asarray(bat.row_tokens(0)[0])
@@ -638,3 +677,83 @@ def test_export_and_import_carry_the_index_leaf(target, draft):
     while not bat.all_done:
         bat.step()
     np.testing.assert_array_equal(np.asarray(bat.row_tokens(1)[0]), want)
+
+
+def test_pages_cut_the_index_leaf_on_its_slots(target, draft):
+    """``split_pages`` and ``from_pages`` cut and join the indexer's leaf
+    on its last axis, K on its second: three pages of 4 of a 13-token row
+    join to the row's first 12 slots of each, zero past them."""
+    model, params = target
+    bat = ContinuousBatcher(model, draft[0], params, draft[1], total_len=30,
+                            n_draft=1)
+    bat.start(np.stack(rows_of(12, [13, 13])))
+    handoff = export_kv_row(bat.state, 0).to_host()
+    pages = handoff.split_pages(4)
+    assert len(pages) == 3
+    attn = lambda cache: cache["block_0"]["attn"]  # noqa: E731
+    assert attn(pages[0].cache_t)["cached_index_k"].shape \
+        == (1, ARCH["index_dim"], 4)
+    joined = generate_mod.KVHandoff.from_pages(
+        pages, total_len=30, slots_t=MAX_SEQ, slots_d=MAX_SEQ)
+    for got, want in ((attn(joined.cache_t), attn(handoff.cache_t)),
+                      (attn(joined.cache_d), attn(handoff.cache_d))):
+        idx, k = got["cached_index_k"], got["cached_k"]
+        assert idx.shape == want["cached_index_k"].shape
+        np.testing.assert_array_equal(idx[..., :12],
+                                      want["cached_index_k"][..., :12])
+        np.testing.assert_array_equal(k[:, :12], want["cached_k"][:, :12])
+        assert not idx[..., 12:].any() and not k[:, 12:].any()
+
+
+# -- the cell's round compiled for a described v5e, with no chip attached -------
+
+
+@pytest.fixture(scope="module")
+def described_chip():
+    """A described ``v5e`` chip (``benchmark/offchip.py``): only inside a
+    fixture, never at import; skipped where libtpu cannot be had."""
+    import os
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmark import offchip
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described device's executable cannot be read back from the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield offchip.topology_device()
+    except Exception as exc:                       # no libtpu, lock held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+
+def test_the_cells_round_copies_no_index_leaf(described_chip, monkeypatch):
+    """``keye30b-serve-longctx``'s ``_spec_round`` as the chip compiles it
+    (Mosaic kernels on): no ``copy`` has the sizes of an index leaf, in
+    any order of its axes.  A leaf the draft's chain carries in one layout
+    and scores in another is copied in, inside and out of the loop: eight
+    copies a round and 399.6 MB of scratch."""
+    import re
+
+    from benchmark import harness, offchip
+
+    monkeypatch.setattr(select_attention, "_on_tpu", lambda: True)
+    cell = harness.resolve_cell("keye30b-serve-longctx")
+    state = offchip._serving_state(cell)[-1]
+    leaf = state[3]["block_0"]["attn"]["cached_index_k"]
+    rows, d, slots = leaf.shape
+    with offchip.mosaic_kernels():
+        compiled = offchip.compile_spec_round(cell, described_chip)
+    copied = [
+        tuple(int(n) for n in dims.split(",") if n != "1")
+        for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(",
+                               compiled.as_text())]
+    assert copied                   # the pattern finds the program's copies
+    assert not [c for c in copied if sorted(c) == sorted((rows, d, slots))]
+    # four leaves are 168 MB; the round's scratch held 399.6 MB with the
+    # copies and 111.2 MB without
+    four = 4 * rows * d * slots * jnp.dtype(leaf.dtype).itemsize
+    assert offchip.memory(compiled)["temp_size_in_bytes"] < four
