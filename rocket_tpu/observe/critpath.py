@@ -13,18 +13,34 @@ segment             measured from
 ==================  =========================================================
 ``queue_wait``      ``serve/admit`` span's ``queue_wait_ms`` arg
 ``route``           ``fleet/route`` instants' ``route_ms`` arg (summed)
-``prefill``         ``fleet/prefill`` span duration plus the first
-                    ``serve/admit`` span duration (the admit IS the
-                    row's prefill on a decode replica — a handed-off
-                    request's admit is just the cheap KV import, so
-                    the two never double-count the same work)
+``prefill``         ``fleet/prefill`` span duration plus the terminal's
+                    ``prefill_ms``: from the instant the row's first
+                    admission ends ``queue_wait`` to the harvest of its
+                    first token, less its pool fetch (on a TPU an
+                    admission's dispatch returns before its program has
+                    run: the program's time is spent in the wait for
+                    that first harvest, not in the ``serve/admit`` span)
 ``handoff_wire``    ``fleet/handoff`` / ``fleet/pool_handoff`` ``wire_ms``
 ``pool_fetch``      ``serve/pool_fetch`` span durations (summed)
-``decode_rounds``   terminal instant ts − first admit end − parked time
-``preempt_parked``  Σ (``serve/resume`` ts − ``serve/preempt`` ts)
+``decode_rounds``   terminal instant ts − ``serve/first_token`` ts −
+                    parked time − ``admit_stall``
+``admit_stall``     the terminal's ``admit_stall_ms``: what the turns
+                    that admitted OTHER requests cost this row while it
+                    decoded, beyond a clean turn (``ServingLoop``'s book)
+``preempt_parked``  Σ (``serve/resume`` ts − ``serve/preempt`` ts), and
+                    a resumed admission's pool fetch
 ``heal``            ``fleet/requeued`` instants' ``heal_ms`` arg (summed)
 ``delivery``        ``fleet/delivered`` ts − terminal instant ts
 ==================  =========================================================
+
+A terminal from a loop that keeps no book (no ``prefill_ms``: an older
+worker's dump) is read by the earlier rules: ``prefill`` takes the first
+``serve/admit`` span's duration, ``decode_rounds`` runs from that span's
+end, and there is no ``admit_stall``.  :func:`replica_segments` is the
+rules' one statement; ``ServingLoop`` applies it to its own clock's
+instants for the process-wide record
+(:func:`rocket_tpu.observe.trace.get_requests`), where the segments sum
+to the request's e2e.
 
 Terminal instants are ``serve/complete`` / ``serve/evict`` (they carry
 ``cls`` and ``e2e_ms``); ``serve/first_token`` supplies TTFT.  Segments
@@ -51,6 +67,7 @@ SEGMENTS = (
     "handoff_wire",
     "pool_fetch",
     "decode_rounds",
+    "admit_stall",
     "preempt_parked",
     "heal",
     "delivery",
@@ -128,12 +145,36 @@ def _ms(args: Dict[str, Any], key: str) -> float:
         return 0.0
 
 
+def replica_segments(*, queue_wait_ms: float, prefill_ms: float,
+                     first_to_terminal_ms: float, pool_fetch_ms: float = 0.0,
+                     parked_ms: float = 0.0,
+                     admit_stall_ms: float = 0.0) -> Dict[str, float]:
+    """What one serving loop saw of a request, by segment: the rules of
+    the module's table for the loop's own measurements (ms), the fleet's
+    segments 0.  ``prefill_ms`` runs from the end of ``queue_wait`` to
+    the first token's harvest, less ``pool_fetch_ms``;
+    ``first_to_terminal_ms`` from that harvest to the terminal."""
+    segments = dict.fromkeys(SEGMENTS, 0.0)
+    segments.update(
+        queue_wait=queue_wait_ms, prefill=prefill_ms,
+        pool_fetch=pool_fetch_ms, preempt_parked=parked_ms,
+        admit_stall=admit_stall_ms,
+        decode_rounds=max(
+            0.0, first_to_terminal_ms - parked_ms - admit_stall_ms))
+    return segments
+
+
 def _analyze(norm: List[tuple]) -> List[RequestPath]:
     norm.sort(key=lambda e: e[1])
     paths: Dict[Any, RequestPath] = {}
     admit_end: Dict[Any, float] = {}       # first admit's end ts (us)
+    admit_ms: Dict[Any, float] = {}        # first admit's duration (ms)
+    first_at: Dict[Any, float] = {}        # first token's ts (us)
     preempt_at: Dict[Any, float] = {}      # open preempt's ts (us)
+    resumed = set()                        # rids resumed at least once
+    resume_fetch: Dict[Any, float] = {}    # pool fetches after a resume
     terminal_at: Dict[Any, float] = {}     # terminal instant ts (us)
+    booked: Dict[Any, Dict[str, Any]] = {}  # terminal args with the book
 
     def path(rid: Any) -> RequestPath:
         if rid not in paths:
@@ -159,17 +200,19 @@ def _analyze(norm: List[tuple]) -> List[RequestPath]:
             path(rid).segments["handoff_wire"] += _ms(args, "wire_ms")
         elif name == "serve/pool_fetch":
             path(rid).segments["pool_fetch"] += dur_us / 1e3
+            if rid in resumed:
+                resume_fetch[rid] = resume_fetch.get(rid, 0.0) \
+                    + dur_us / 1e3
         elif name == "serve/admit":
             p = path(rid)
             if rid not in admit_end:
                 p.segments["queue_wait"] = _ms(args, "queue_wait_ms")
                 admit_end[rid] = ts_us + dur_us
-                # the admit span IS the row's prefill work (full
-                # prefill on a decode replica, KV import for a handoff)
-                p.segments["prefill"] += dur_us / 1e3
+                admit_ms[rid] = dur_us / 1e3
         elif name == "serve/preempt":
             preempt_at[rid] = ts_us
         elif name == "serve/resume":
+            resumed.add(rid)
             t0 = preempt_at.pop(rid, None)
             if t0 is not None:
                 path(rid).segments["preempt_parked"] += \
@@ -178,11 +221,14 @@ def _analyze(norm: List[tuple]) -> List[RequestPath]:
             path(rid).segments["heal"] += _ms(args, "heal_ms")
         elif name == "serve/first_token":
             path(rid).ttft_ms = _ms(args, "ttft_ms")
+            first_at.setdefault(rid, ts_us)
         elif name in _TERMINALS:
             p = path(rid)
             p.cls = str(args.get("cls", p.cls))
             p.e2e_ms = _ms(args, "e2e_ms")
             terminal_at[rid] = ts_us
+            if "prefill_ms" in args:
+                booked[rid] = args
         elif name == "fleet/delivered":
             t_term = terminal_at.get(rid)
             if t_term is not None:
@@ -192,10 +238,32 @@ def _analyze(norm: List[tuple]) -> List[RequestPath]:
     for rid, p in paths.items():
         t_term = terminal_at.get(rid)
         t_admit = admit_end.get(rid)
-        if t_term is not None and t_admit is not None:
-            decode = (t_term - t_admit) / 1e3 \
-                - p.segments["preempt_parked"]
-            p.segments["decode_rounds"] = max(0.0, decode)
+        s = p.segments
+        if t_term is not None and rid in booked:
+            # the loop's book: its own prefill and stall, the rules of
+            # replica_segments over the rest
+            t_first = first_at.get(rid, t_admit)
+            fetched = resume_fetch.get(rid, 0.0)
+            local = replica_segments(
+                queue_wait_ms=s["queue_wait"],
+                prefill_ms=_ms(booked[rid], "prefill_ms"),
+                first_to_terminal_ms=(
+                    (t_term - t_first) / 1e3 if t_first is not None
+                    else 0.0),
+                pool_fetch_ms=s["pool_fetch"] - fetched,
+                parked_ms=s["preempt_parked"] + fetched,
+                admit_stall_ms=_ms(booked[rid], "admit_stall_ms"))
+            for seg in ("prefill", "admit_stall"):
+                s[seg] += local[seg]
+            for seg in ("pool_fetch", "preempt_parked", "decode_rounds"):
+                s[seg] = local[seg]
+        elif t_term is not None and t_admit is not None:
+            # an older loop's dump: the admit span IS the row's prefill
+            # work (full prefill on a decode replica, KV import for a
+            # handoff), decode runs from its end
+            s["prefill"] += admit_ms[rid]
+            s["decode_rounds"] = max(
+                0.0, (t_term - t_admit) / 1e3 - s["preempt_parked"])
         if p.e2e_ms == 0.0:
             p.e2e_ms = p.accounted_ms
     return [p for p in paths.values() if terminal_at.get(p.rid) is not None]

@@ -284,12 +284,17 @@ class Tracer:
         }
 
     def dump_json(self, path: str) -> str:
+        """Write the ring as a Chrome-trace document.  The file appears
+        whole or not at all (written aside, then renamed over ``path``):
+        a process killed mid-dump leaves no half document behind."""
         doc = self.to_chrome()
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        with open(path, "w") as f:
+        partial = f"{path}.{os.getpid()}.partial"
+        with open(partial, "w") as f:
             # default=str: span fields are arbitrary user values (rids,
             # enums) — a dump must never fail on an unserializable field.
             json.dump(doc, f, default=str)
+        os.replace(partial, path)
         return path
 
     def tail_text(self, n: int = 48) -> str:
@@ -539,6 +544,44 @@ _ROUNDS = RoundRecord()
 def get_rounds() -> RoundRecord:
     """The process-wide record of the serving rounds' device counters."""
     return _ROUNDS
+
+
+class RequestRecord:
+    """Process-wide record of the requests serving loops terminated
+    (``serve/complete``, ``serve/evict``), the newest ``capacity`` kept.
+    An entry is a dict: ``rid``, ``outcome`` (``complete`` or ``evict``),
+    ``first_s`` and ``end_s`` (the first token's harvest and the
+    terminal, seconds on the loop's own clock), ``out`` (output tokens),
+    ``stalled_turns`` (other requests' admission turns it sat through
+    while decoding), ``e2e_ms`` and ``segments`` (ms by
+    :data:`rocket_tpu.observe.critpath.SEGMENTS`, as critpath's rules
+    split what that loop saw).  ``ServingLoop`` adds an entry at each
+    terminal; a reader takes a :meth:`snapshot`, also after the loop is
+    gone.  An entry holds about 750 bytes: the default keeps 12 MB."""
+
+    def __init__(self, capacity: int = 16384) -> None:
+        self._lock = threading.Lock()
+        self._entries: deque = deque(maxlen=int(capacity))
+
+    def add(self, entry: Dict[str, Any]) -> None:
+        with self._lock:
+            self._entries.append(entry)
+
+    def snapshot(self) -> List[Dict[str, Any]]:
+        with self._lock:
+            return list(self._entries)
+
+    def reset(self) -> None:
+        with self._lock:
+            self._entries.clear()
+
+
+_REQUESTS = RequestRecord()
+
+
+def get_requests() -> RequestRecord:
+    """The process-wide record of terminated requests' latency books."""
+    return _REQUESTS
 
 
 # -- distributed request tracing --------------------------------------------

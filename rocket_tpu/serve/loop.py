@@ -42,16 +42,20 @@ from __future__ import annotations
 
 import logging
 import os
+import statistics
 import time
+from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from rocket_tpu.models.generate import (HostReads, KVHandoff,
                                          export_kv_row)
+from rocket_tpu.observe.critpath import replica_segments
 from rocket_tpu.observe.ledger import expect_compile, get_goodput
 from rocket_tpu.observe.recorder import active_recorder
-from rocket_tpu.observe.trace import TraceContext, get_startup, get_tracer
+from rocket_tpu.observe.trace import (TraceContext, get_requests,
+                                      get_startup, get_tracer)
 from rocket_tpu.serve.kvstore import page_hashes
 from rocket_tpu.serve.metrics import (
     ClassLatency,
@@ -73,17 +77,23 @@ from rocket_tpu.serve.watchdog import DispatchWatchdog
 
 LOG = logging.getLogger("rocket_tpu.serve")
 
+# Clean turns (no admission) whose median is a turn's cost without one.
+CLEAN_TURNS = 32
+
 
 class _Row:
     """Host-side bookkeeping for one occupied batcher row."""
 
     __slots__ = ("req", "admitted_at", "submitted_at", "first_tok_at",
                  "prompt_len", "budget", "requested", "demoted",
-                 "rounds_seen")
+                 "rounds_seen", "origin", "pool_fetch_ms", "parked_ms",
+                 "admit_stall_ms", "stalled_turns")
 
     def __init__(self, req: Request, admitted_at: float, prompt_len: int,
                  budget: int, requested: int, demoted: bool,
-                 submitted_at: Optional[float] = None) -> None:
+                 submitted_at: Optional[float] = None,
+                 resumes: Optional["_Row"] = None,
+                 parked_ms: float = 0.0, pool_fetch_ms: float = 0.0) -> None:
         self.req = req
         self.admitted_at = admitted_at
         # submit() stamps the request; direct-admitted requests (tests)
@@ -97,6 +107,22 @@ class _Row:
         self.requested = requested    # what the caller asked for
         self.demoted = demoted        # beam request served greedy
         self.rounds_seen = 0          # carry row valid only after >= 1
+        # The request's book, which critpath splits its time by: the row
+        # of its first admission (``origin``: where queue wait, prefill
+        # and the first token are read), its pool fetch, time parked by
+        # preemption, and what other requests' admissions cost it while
+        # it decoded.  A row that resumes a preempted one carries its book.
+        self.origin: _Row = self
+        self.pool_fetch_ms = pool_fetch_ms
+        self.parked_ms = parked_ms
+        self.admit_stall_ms = 0.0
+        self.stalled_turns = 0
+        if resumes is not None:
+            self.origin = resumes.origin
+            self.pool_fetch_ms = resumes.pool_fetch_ms
+            self.parked_ms += resumes.parked_ms
+            self.admit_stall_ms = resumes.admit_stall_ms
+            self.stalled_turns = resumes.stalled_turns
 
 
 class ServingLoop:
@@ -223,6 +249,12 @@ class ServingLoop:
         self._round_ms: Optional[float] = None  # EMA, shed floor + policy
         self._carry: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self._compiled_drafts: set = set()
+        # The admission book (_book_turn): where the turn in progress
+        # began (the last harvest's instant; None after an idle or failed
+        # turn), the admissions it dispatched, recent clean turns (ms).
+        self._turn_from: Optional[float] = None
+        self._turn_admits = 0
+        self._clean_turns_ms: deque = deque(maxlen=CLEAN_TURNS)
 
         # Warm-start tier (ISSUE 15): ``warmup`` is a WarmupPlan, its
         # wire dict, or ``"auto"`` (derive from the batcher config).
@@ -300,8 +332,10 @@ class ServingLoop:
         "compile" it expects is a disk-cache retrieval."""
         with get_startup().phase("startup/serve_warm_start"):
             self._warm_start_inner(bat)
-        # the first served round's host gap starts at a read of its own
+        # the first served round's host gap starts at a read of its own,
+        # and its turn is no measure of one
         self._reads.returned_at = None
+        self._turn_from = None
 
     def _warm_start_inner(self, bat: Any) -> None:
         if self._warmup is not None:
@@ -686,8 +720,10 @@ class ServingLoop:
             self._preempt_batch(now)
         self._admit_pending(now)
         if not self._live_rows():
-            # idle: the time to the next dispatch is no host gap
+            # idle: the time to the next dispatch is no host gap, nor
+            # the time to the next harvest a turn
             self._reads.returned_at = None
+            self._turn_from = None
             self._flush()
             return False
 
@@ -697,6 +733,8 @@ class ServingLoop:
                 self._harvest(self._clock())
             if self._recover_in > 0:
                 self._recover_in -= 1
+        else:
+            self._turn_from = None
         with self._tracer.span("serve/policy"):
             self._update_policy()
         self._observe_health()
@@ -768,6 +806,7 @@ class ServingLoop:
             self._bat.retire(row)
             self._rows[row] = None
             req = occ.req
+            req._preempted_row = occ    # the resumed row carries its book
             produced = max(0, nt - int(req.prompt.shape[0]))
             self._parked.append(PreemptTicket(
                 req=req, tokens=np.asarray(toks[:nt], np.int32),
@@ -872,18 +911,26 @@ class ServingLoop:
             self.latency.queue_wait_ms.record(wait_ms)
         handoff = getattr(req, "_handoff", None)
         match = None
+        pool_ms = 0.0
         if handoff is None and self.kvstore is not None:
             match = self.kvstore.lookup(prompt)
             if match is None and self.kvpool is not None:
+                fetch_from = self._clock()
                 match = self._pool_fetch(prompt, req)
+                pool_ms = (self._clock() - fetch_from) * 1e3
         self._flow(req, "t", hop="admit")
         # The admit IS the row's prefill (the batcher rebuilds the row's
-        # cache from the prompt) — one span covers admission + prefill.
+        # cache from the prompt).  The span times its DISPATCH: on a CPU
+        # that is the prefill itself, on a TPU the program runs after
+        # the span has closed, and every row waits for it before the
+        # round; the request's book times its prefill to the harvest of
+        # its first token instead (_finish_latency).
         # A handed-off request skips the prefill: its KV rows import as
         # one cheap scatter dispatch (the prefill/decode lane split).
         # A kvstore prefix hit imports the cached pages and prefills
         # only the uncached suffix — same scatter path, same bit-equal
         # outcome as a full prefill.
+        self._turn_admits += 1
         with self._tracer.span(
             "serve/admit", rid=req.rid, row=row,
             prompt_len=int(prompt.shape[0]), queue_wait_ms=wait_ms,
@@ -907,8 +954,22 @@ class ServingLoop:
                 self.counters.kv_hit_tokens += match.tokens
             else:
                 self._bat.admit(row, prompt[None, :])
-        self._rows[row] = _Row(req, now, prompt.shape[0], budget,
-                               requested, demoted, submitted_at=submitted)
+        if resume is None:
+            self._rows[row] = _Row(req, now, prompt.shape[0], budget,
+                                   requested, demoted,
+                                   submitted_at=submitted,
+                                   pool_fetch_ms=pool_ms)
+        else:
+            # parked from the preemption to this turn and through the
+            # resume's pool fetch
+            parked_ms = (now - resume.preempted_at) * 1e3 + pool_ms
+            self._rows[row] = _Row(req, now, prompt.shape[0], budget,
+                                   requested, demoted,
+                                   submitted_at=submitted,
+                                   resumes=getattr(req, "_preempted_row",
+                                                   None),
+                                   parked_ms=parked_ms)
+            req._preempted_row = None
         self.counters.admitted += 1
 
     def _pool_fetch(self, prompt: np.ndarray,
@@ -948,6 +1009,7 @@ class ServingLoop:
         not a batcher row).  Under pressure the ladder flips
         ``beam=False`` and these requests demote to the greedy lane."""
         budget, _ = self._budget(req, req.prompt.shape[0])
+        self._turn_admits += 1          # the rows wait for it too
         with self._tracer.span("serve/beam", rid=req.rid,
                                prompt_len=int(req.prompt.shape[0])):
             toks = self._reads(
@@ -1142,10 +1204,39 @@ class ServingLoop:
             self._warm_start(self._bat)
         self._recover_in = self._recover_rounds
 
+    def _book_turn(self, now: float) -> None:
+        """The admission book, at the harvest that ends a turn.  A turn
+        runs from the previous harvest's ``now`` to this one's, on the
+        loop's clock; a turn that dispatched no admission (a fresh
+        admit, resume, prefilled or prefix import, or beam serve: device
+        work queued before the round) is clean and joins the window of
+        the last ``CLEAN_TURNS`` clean turns.  A turn with admissions
+        books ``max(0, turn - median clean turn)`` on every row that was
+        decoding before it (``rounds_seen`` > 1 once this turn's round
+        has counted), and counts the turn on it; the rows it admitted
+        get nothing.  Before a clean turn has been seen nothing is
+        booked.  Host arithmetic only: no fetch, sync or dispatch."""
+        start, self._turn_from = self._turn_from, now
+        admits, self._turn_admits = self._turn_admits, 0
+        if start is None:
+            return
+        turn_ms = (now - start) * 1e3
+        if not admits:
+            self._clean_turns_ms.append(turn_ms)
+            return
+        if not self._clean_turns_ms:
+            return
+        stall_ms = max(0.0, turn_ms - statistics.median(self._clean_turns_ms))
+        for occ in self._rows.values():
+            if occ is not None and occ.rounds_seen > 1:
+                occ.admit_stall_ms += stall_ms
+                occ.stalled_turns += 1
+
     def _harvest(self, now: float) -> None:
         """Round-boundary accounting: finished rows complete; rows past
         deadline evict with partials; rows at their (possibly degraded)
         budget complete as truncated."""
+        self._book_turn(now)
         n_tok_h = self._reads(self._bat.state[1], "n_tok")
         done_h = self._reads(self._bat.state[2], "done")
         for row, occ in self._rows.items():
@@ -1240,12 +1331,30 @@ class ServingLoop:
             )
         if event == "serve/evict":  # deadline blown mid-decode
             self._promote(occ.req)
-        self._flow(occ.req, "f",
-                   outcome="evict" if event == "serve/evict"
-                   else "complete")
+        outcome = "evict" if event == "serve/evict" else "complete"
+        self._flow(occ.req, "f", outcome=outcome)
+        # The book, split by critpath's rules on this loop's clock: the
+        # segments sum to e2e_ms.
+        origin = occ.origin
+        first = origin.first_tok_at if origin.first_tok_at is not None \
+            else now
+        prefill_ms = (first - origin.admitted_at) * 1e3 \
+            - origin.pool_fetch_ms
+        segments = replica_segments(
+            queue_wait_ms=(origin.admitted_at - occ.submitted_at) * 1e3,
+            prefill_ms=prefill_ms, first_to_terminal_ms=(now - first) * 1e3,
+            pool_fetch_ms=origin.pool_fetch_ms, parked_ms=occ.parked_ms,
+            admit_stall_ms=occ.admit_stall_ms)
+        get_requests().add({
+            "rid": occ.req.rid, "outcome": outcome, "first_s": first,
+            "end_s": now, "out": n_tok - int(occ.req.prompt.shape[0]),
+            "stalled_turns": occ.stalled_turns, "e2e_ms": e2e_ms,
+            "segments": segments})
         self._tracer.instant(event, rid=occ.req.rid, row=row,
                              n_tok=n_tok, rounds=occ.rounds_seen,
-                             cls=occ.req.slo_class, e2e_ms=e2e_ms)
+                             cls=occ.req.slo_class, e2e_ms=e2e_ms,
+                             prefill_ms=prefill_ms,
+                             admit_stall_ms=occ.admit_stall_ms)
 
     def _update_policy(self) -> None:
         before = self.policy.level

@@ -335,11 +335,13 @@ def main(argv: Optional[list] = None) -> int:
                         f"{os.getpid()}.json")
                 tracer.dump_json(os.path.join(trace_dir, name))
         rc = serve(fs, loop, on_shutdown=dump)
-        if tracer is not None:
+        if tracer is not None and rc != 0:
             try:
-                # socket-loss exits (supervisor gone) never saw SHUTDOWN
-                # — dump here too; after an orderly exit this just
-                # rewrites the same file
+                # socket-loss exits (supervisor gone) never saw SHUTDOWN:
+                # dump here.  An orderly exit (rc 0) dumped before its BYE,
+                # and the supervisor reaps this process with SIGKILL as
+                # soon as it reads that BYE: writing the file again would
+                # race the kill and could leave it cut short.
                 dump()
             except Exception:
                 pass  # a failed dump must not turn a clean exit dirty
