@@ -670,7 +670,7 @@ class Attention(nn.Module):
         def attend_selected(k_all, v_all, k_idx_all, q_pos):
             scores = index_scores(q_idx, w_idx, k_idx_all, q_pos, idle)
             out, kept = selected_attention(q, k_all, v_all, scores, q_pos,
-                                           sel.top_k)
+                                           sel.top_k, idle)
             live = jnp.sum(scores > -jnp.inf, axis=-1)
             self.sow("selection", "keys",
                      jnp.stack([kept, live], axis=-1).astype(jnp.int32))
@@ -709,14 +709,17 @@ class Attention(nn.Module):
         attend_selected)``) adds the indexer's cache leaf
         ``cached_index_k`` ``[B, index_dim, slots]``, written at the
         slots K and V are, and attends through
-        ``attend_selected`` over the written caches instead; that the
-        decode kernel is not taken is counted as a fallback with reason
-        ``selected``."""
+        ``attend_selected`` over the written caches instead; a round's
+        chunk the selection masks takes the decode kernel with the mask
+        where its gate allows (``attention/select/decode`` ``path``
+        kernel), and every other call's refusal of the decode kernel is
+        counted as a fallback with reason ``selected``."""
         from rocket_tpu.ops.attention import dot_attention
         from rocket_tpu.ops.decode_attention import (
             MAX_CHUNK,
             cached_attention,
             note_fallback,
+            why_not,
         )
 
         cfg = self.config
@@ -865,13 +868,21 @@ class Attention(nn.Module):
                 cached_index_k.value = jax.lax.dynamic_update_slice(
                     cached_index_k.value, index_k, (0, 0, idx))
             sel = cfg.select
-            note_fallback("selected", q, n_slots)
             if S <= MAX_CHUNK:          # a round's chunk, as the kernel's
+                # selected_attention's choice, asked the same way
+                reason = None
+                if gathers(S, n_slots, sel.top_k):
+                    path = "gather"
+                else:
+                    reason = why_not(q, k_all, masked=True)
+                    path = "mask" if reason else "kernel"
+                if path != "kernel":
+                    note_fallback("selected", q, n_slots)
                 counter("attention/select/decode", 1, S=S, T=n_slots,
                         top_k=sel.top_k, index_heads=sel.index_heads,
-                        path="gather" if gathers(S, n_slots, sel.top_k)
-                        else "mask")
+                        path=path, **({"reason": reason} if reason else {}))
             else:
+                note_fallback("selected", q, n_slots)
                 reason = why_not_masked(q, k_all)
                 counter("attention/select/prefill", 1, chunk=S, T=n_slots,
                         path="mask" if reason else "kernel",

@@ -23,20 +23,23 @@ Two ways to attend over a selection, one result, chosen by
   K and V rows are gathered and ``dot_attention`` runs over ``top_k`` keys
   a query — the cost no longer grows with the slab;
 - **mask** (an admission's chunk of hundreds of queries; a round's chunk
-  against a slab XLA streams faster than it gathers from): the
+  against a slab streamed faster than it is gathered from): the
   ``top_k``-th largest score of each query is found by a search over the
-  bits of its float32 pattern (32 counting passes, no sort), and
-  ``dot_attention`` runs over the slab under the mask ``score >=
-  threshold``.
+  bits of its float32 pattern (32 counting passes, no sort), and the slab
+  is attended under the mask ``score >= threshold``.
 
 Both keep exactly the same set, ties included.  The scores, the selection
-and the gather are XLA's.  Under the mask an admission's chunk attends
-through :func:`masked_attention`, a Pallas kernel (online softmax over key
-blocks, the mask read a block at a time, the blocks past the chunk's last
-position neither fetched nor computed): XLA's masked pass writes and reads
-``f32[heads, chunk, slots]`` scores five times over, a gigabyte each for a
-chunk of 512 queries against 16,384 slots.  A kernel for the round's half
-(the selected rows read in place) is ``ROADMAP.md``'s.
+and the gather are XLA's.  Under the mask a chunk attends through a Pallas
+kernel where one applies, ``dot_attention(key_mask=)`` otherwise: an
+admission's chunk through :func:`masked_attention` (online softmax over
+key blocks, the mask read a block at a time, the blocks past the chunk's
+last position neither fetched nor computed; XLA's masked pass writes and
+reads ``f32[heads, chunk, slots]`` scores five times over, a gigabyte each
+for a chunk of 512 queries against 16,384 slots), a round's chunk through
+``ops.decode_attention``'s kernel with its ``key_mask`` (each row's
+written blocks alone, the mask beside K and V; XLA's pass streams every
+slot of every row).  A kernel that reads the selected rows in place, for
+the gather's half, is ``ROADMAP.md``'s.
 """
 
 from __future__ import annotations
@@ -49,6 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from rocket_tpu.ops import decode_attention
 from rocket_tpu.ops.attention import _group_size, dot_attention
 from rocket_tpu.ops.decode_attention import VMEM_LIMIT
 from rocket_tpu.ops.flash import MASK_VALUE
@@ -300,21 +304,26 @@ def masked_attention(q: Array, k: Array, v: Array, mask: Array,
 
 def selected_attention(q: Array, k: Array, v: Array, scores: Array,
                        q_pos: Array, top_k: int,
-                       scale: Optional[float] = None) -> Tuple[Array, Array]:
+                       idle: Optional[Array] = None) -> Tuple[Array, Array]:
     """Attention of ``q`` ``[B, S, H, D]`` (at positions ``q_pos`` ``[B,
-    S]``, ascending a row) over the ``top_k`` keys of ``k``/``v`` ``[B, T,
-    KV, D]`` that ``scores`` ``[B, S, T]`` (from :func:`index_scores`, dead
-    slots ``-inf``) ranks first for each query.
+    S]``, consecutive a row; slot == position) over the ``top_k`` keys of
+    ``k``/``v`` ``[B, T, KV, D]`` that ``scores`` ``[B, S, T]`` (from
+    :func:`index_scores`, dead slots ``-inf``) ranks first for each query.
     Returns ``(out [B, S, H, D], kept [B, S])``, ``kept`` the keys each
-    query attended.
+    query attended.  ``idle`` (``[B]`` bool, optional) marks rows whose
+    output the caller drops (their scores are all ``-inf``).
 
     Gathers where :func:`gathers` says the gathered rows cost less than
     the slab, masks otherwise (an admission's chunk; a round's chunk
     against a slab under ``GATHER_COST`` times its kept keys; a ``top_k``
     that covers the slab, where the mask is the causal one and the result
     bit for bit plain attention's).  Under the mask a chunk long enough
-    goes through the kernel (:func:`masked_attention`), where
-    :func:`why_not_masked` finds no reason against it."""
+    goes through :func:`masked_attention` where :func:`why_not_masked`
+    finds no reason against it, and a round's chunk through the decode
+    kernel where its gate (``ops.decode_attention.why_not``, the
+    configuration's ``attention`` not asked) finds none: there a query
+    that chose nothing (an idle row's) reads zeros, where
+    ``dot_attention`` averages the slab."""
     B, S, H, D = q.shape
     if gathers(S, k.shape[1], top_k):
         slots, valid = select_slots(scores, top_k)             # [B, S, k]
@@ -325,14 +334,15 @@ def selected_attention(q: Array, k: Array, v: Array, scores: Array,
         v_sel = rows(v, flat).reshape((B * S, n) + v.shape[2:])
         out = dot_attention(
             q.reshape(B * S, 1, H, D), k_sel, v_sel, causal=False,
-            kv_mask=valid.reshape(B * S, n), scale=scale)
+            kv_mask=valid.reshape(B * S, n))
         return out.reshape(B, S, H, v.shape[-1]), jnp.sum(valid, axis=-1)
     chosen = select_mask(scores, top_k)
     if why_not_masked(q, k) is None:
         # no query of the chunk sees a slot past its last query's position
-        out = masked_attention(q, k, v, chosen, q_pos[:, -1] + 1,
-                               scale=scale)
+        out = masked_attention(q, k, v, chosen, q_pos[:, -1] + 1)
+    elif decode_attention.why_not(q, k, masked=True) is None:
+        out = decode_attention.decode_attention(
+            q, k, v, q_pos[:, 0], idle=idle, key_mask=chosen)
     else:
-        out = dot_attention(q, k, v, causal=False, key_mask=chosen,
-                            scale=scale)
+        out = dot_attention(q, k, v, causal=False, key_mask=chosen)
     return out, jnp.sum(chosen, axis=-1)

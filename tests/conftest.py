@@ -129,8 +129,8 @@ def decode_kernel_here(monkeypatch):
 
     real = decode_attention.why_not
 
-    def why_not(q, k_cache, *, impl):
-        reason = real(q, k_cache, impl=impl)
+    def why_not(q, k_cache, **kw):
+        reason = real(q, k_cache, **kw)
         return None if reason == "backend" else reason
 
     monkeypatch.setattr(decode_attention, "why_not", why_not)

@@ -108,38 +108,116 @@ def test_an_idle_row_attends_nothing_and_disturbs_no_neighbour():
                                        block_k=BLOCK)))
 
 
+# -- a key mask: the selecting layer's round chunk -------------------------------
+
+
+def _selection(S, off, seed):
+    """``select_mask`` over random index scores (dead past each query's
+    position), top 6: ties across the threshold in row 1, row 5's choice
+    all in its second block, row 4 idle (chooses nothing)."""
+    from rocket_tpu.ops.select_attention import select_mask
+
+    q_pos = off[:, None] + jnp.arange(S)[None, :]
+    slot = jnp.arange(T)[None, None, :]
+    scores = jax.random.normal(jax.random.PRNGKey(seed), (len(off), S, T))
+    scores = scores.at[1, :, 2:14].set(0.5)                     # ties
+    scores = scores.at[5].set(jnp.where((slot[0] >= BLOCK)
+                                        & (slot[0] < 2 * BLOCK), 9.0, -9.0))
+    scores = jnp.where(slot <= q_pos[:, :, None], scores, -jnp.inf)
+    scores = scores.at[4].set(-jnp.inf)
+    return select_mask(scores, 6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("KV", [4, 8])
+@pytest.mark.parametrize("S", [1, 2, 5])
+def test_a_key_mask_is_dot_attention_under_that_mask(S, KV, dtype):
+    """``key_mask=`` is ``dot_attention(causal=False, key_mask=)`` over a
+    ragged slab (36 slots in blocks of 16) for rows whose chunk starts the
+    cache, ends inside the first block, crosses into the second, ends the
+    slab, is idle, and chose every key of one block only; stale keys past
+    each frontier are loud.  A query that chose nothing (the idle row's)
+    reads zeros."""
+    q, k, v = _operands(S, 2 * KV, KV, dtype, seed=7, rows=6)
+    off = jnp.asarray([0, 9 - S, BLOCK + 3 - S, T - S, 11, T - S], jnp.int32)
+    stale = jnp.arange(T)[None, :, None, None] >= (off + S)[:, None, None,
+                                                            None]
+    k = jnp.where(stale, 40.0, k).astype(dtype)
+    chosen = _selection(S, off, seed=S * KV)
+    idle = jnp.arange(6) == 4
+    assert chosen[5].any() and not chosen[5, :, :BLOCK].any()
+    out = da.decode_attention(q, k, v, off, idle=idle, key_mask=chosen,
+                              block_k=BLOCK)
+    ref = dot_attention(q, k, v, causal=False, key_mask=chosen)
+    live = np.asarray(~idle)
+    _close(out[live], ref[live], dtype)
+    assert not np.asarray(out[4], np.float32).any()
+
+
+def test_the_unmasked_kernel_traces_what_it_traced_before():
+    """With no key mask the kernel is, op for op, the one the Mistral cells
+    compiled before the mask was added (its jaxpr at toy size, a ragged
+    slab, an idle operand and a window; hashes of the tree before)."""
+    import hashlib
+
+    def traced(S, H, KV, dtype, window):
+        q = jax.ShapeDtypeStruct((3, S, H, D), dtype)
+        k = jax.ShapeDtypeStruct((3, T, KV, D), dtype)
+        off = jax.ShapeDtypeStruct((3,), jnp.int32)
+        idle = jax.ShapeDtypeStruct((3,), jnp.bool_)
+        text = str(jax.make_jaxpr(
+            lambda q, k, v, o, i: da.decode_attention(
+                q, k, v, o, idle=i, window=window, block_k=BLOCK))(
+            q, k, k, off, idle))
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    assert traced(2, 32, 4, jnp.bfloat16, None) == "2b3ab8ff7adf84ab"
+    assert traced(5, 8, 2, jnp.float32, 7) == "5a4409e107c7c46d"
+
+
 def _shapes(S, H, KV, Dh, n_slots, dtype, B=8):
     return (jax.ShapeDtypeStruct((B, S, H, Dh), dtype),
             jax.ShapeDtypeStruct((B, n_slots, KV, Dh), dtype))
 
 
-@pytest.mark.parametrize("S,H,KV,n_slots,dtype,block", [
+@pytest.mark.parametrize("S,H,KV,n_slots,dtype,block,masked", [
     # 8 bf16 KV heads of 128 (Mistral): BLOCK_BYTES of keys is 3,072 slots
-    (5, 32, 8, 4100, jnp.bfloat16, 3072),
-    (1, 32, 8, 4100, jnp.bfloat16, 3072),
+    (5, 32, 8, 4100, jnp.bfloat16, 3072, False),
+    (1, 32, 8, 4100, jnp.bfloat16, 3072, False),
     # 32 bf16 KV heads (the llama2_7b preset): a quarter of the slots
-    (5, 32, 32, 4096, jnp.bfloat16, 768),
+    (5, 32, 32, 4096, jnp.bfloat16, 768, False),
     # float32 caches: half
-    (5, 32, 8, 4096, jnp.float32, 1536),
-    (5, 32, 32, 4096, jnp.float32, 384),
+    (5, 32, 8, 4096, jnp.float32, 1536, False),
+    (5, 32, 32, 4096, jnp.float32, 384, False),
     # the widest chunk the rule takes, 8 query heads a KV head: the
     # scores weigh in, the block gives way
-    (8, 64, 8, 4096, jnp.bfloat16, 2688),
+    (8, 64, 8, 4096, jnp.bfloat16, 2688, False),
     # a slab shorter than a block is one block, on the 16-row tile
-    (5, 8, 2, 36, jnp.float32, 48),
-    (5, 8, 2, 64, jnp.bfloat16, 64),
+    (5, 8, 2, 36, jnp.float32, 48, False),
+    (5, 8, 2, 64, jnp.bfloat16, 64, False),
+    # with a key mask, MASKED_BLOCK_BYTES: Keye's verify chunk (4 bf16
+    # heads) 1,024 slots, Mistral's widths 512
+    (2, 32, 4, 20481, jnp.bfloat16, 1024, True),
+    (5, 32, 8, 4100, jnp.bfloat16, 512, True),
+    (8, 64, 32, 4096, jnp.float32, 128, True),
 ], ids=["mistral-verify", "mistral-draft", "kv32-bf16", "kv8-f32",
-        "kv32-f32", "S8-G8", "toy-36", "toy-64"])
+        "kv32-f32", "S8-G8", "toy-36", "toy-64", "keye-verify-masked",
+        "mistral-masked", "kv32-f32-masked"])
 def test_the_block_follows_the_bytes_of_the_cache(S, H, KV, n_slots, dtype,
-                                                   block):
+                                                   block, masked):
     """K and V blocks, each double-buffered, and a group's working arrays
     fit the budget whatever the heads and the dtype (a fixed 3,072 slots
     of 32 bf16 heads is 100 MB and Mosaic refuses it)."""
     q, k = _shapes(S, H, KV, D, n_slots, dtype)
-    assert da.block_k_for(q, k) == block
-    need = da.vmem_bytes(block, S, H // KV, KV, D, jnp.dtype(dtype).itemsize)
+    assert da.block_k_for(q, k, masked) == block
+    need = da.vmem_bytes(block, S, H // KV, KV, D, jnp.dtype(dtype).itemsize,
+                         masked)
     assert need <= da.VMEM_BUDGET < da.VMEM_LIMIT
-    assert block * KV * D * jnp.dtype(dtype).itemsize <= da.BLOCK_BYTES
+    size = da.MASKED_BLOCK_BYTES if masked else da.BLOCK_BYTES
+    # a masked block is never cut below MIN_BLOCK_K for its size's sake
+    assert block * KV * D * jnp.dtype(dtype).itemsize <= size \
+        or (masked and block == da.MIN_BLOCK_K)
 
 
 def test_the_default_block_covers_a_short_slab_in_one():

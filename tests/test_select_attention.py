@@ -358,6 +358,76 @@ def test_an_admission_through_the_masked_kernel_is_the_reference(
     np.testing.assert_allclose(got, want, atol=5 * TOL)
 
 
+# -- a round's masked chunk through the decode kernel, in interpret mode -------
+
+
+def test_the_kernel_route_keeps_the_same_keys_and_output(monkeypatch,
+                                                         decode_kernel_here):
+    """A round's chunk on the mask branch (two queries a row, bfloat16
+    heads of 128, an idle row) keeps the same keys through the decode
+    kernel as through ``dot_attention(key_mask=)``, and the live rows'
+    outputs agree within bfloat16's rounding."""
+    from rocket_tpu.ops import decode_attention
+
+    B, S, H, KV, D, T, top_k = 3, 2, 8, 4, 128, 64, 4
+    assert not select_attention.gathers(S, T, top_k)
+    ks = jax.random.split(jax.random.PRNGKey(8), 5)
+    q = jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, T, KV, D), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, T, KV, D), jnp.bfloat16)
+    q_pos = jnp.asarray([[5, 6], [40, 41], [62, 63]], jnp.int32)
+    idle = jnp.asarray([False, True, False])
+    q_idx = jax.random.normal(ks[3], (B, S, 2, 8))
+    k_idx = jax.random.normal(ks[4], (B, 8, T))
+    scores = select_attention.index_scores(
+        q_idx, jnp.ones((B, S, 2)), k_idx, q_pos, idle)
+    seen = []
+    real = decode_attention.decode_attention
+    monkeypatch.setattr(decode_attention, "decode_attention",
+                        lambda *a, **kw: seen.append(kw) or real(*a, **kw))
+    got, kept = select_attention.selected_attention(
+        q, k, v, scores, q_pos, top_k, idle)
+    assert len(seen) == 1 and seen[0]["key_mask"] is not None
+    chosen = select_attention.select_mask(scores, top_k)
+    want = dot_attention(q, k, v, causal=False, key_mask=chosen)
+    np.testing.assert_array_equal(kept, jnp.sum(chosen, axis=-1))
+    assert (np.asarray(kept)[[0, 2]] == top_k).all() and not kept[1].any()
+    np.testing.assert_allclose(np.asarray(got[::2], np.float32),
+                               np.asarray(want[::2], np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+def test_a_round_through_the_decode_kernel_is_the_reference(
+        decode_kernel_here):
+    """Heads of 128: verify chunks of two tokens a row attend through the
+    decode kernel with the selection as its key mask (counted: ``path``
+    kernel, and no ``selected`` fallback for them), and the logits are the
+    reference's."""
+    from rocket_tpu.observe import trace
+
+    arch = dict(ARCH, layers=2, heads=2, kv_heads=1, head_dim=128,
+                mrope=(16, 24, 24))
+    model, params = build(arch, 7)
+    prompts, more = (22, 13), 4
+    rows = rows_of(12, [p + more for p in prompts])
+    tracer = trace.arm(1024)
+    tracer.clear()
+    try:
+        got = _prefill_then_decode(model, params, rows, prompts, more // 2)
+        events = [(e[1], e[5]) for e in tracer.events()]
+    finally:
+        trace.disarm()
+    rounds = [kw for name, kw in events if name == "attention/select/decode"
+              and kw["S"] == 2 and kw["T"] == MAX_SEQ]
+    assert rounds and {kw["path"] for kw in rounds} == {"kernel"}
+    assert not [kw for name, kw in events
+                if name == "attention/decode/fallback" and kw["S"] == 2
+                and kw["T"] == MAX_SEQ]
+    for r, p in enumerate(prompts):
+        want = reference.full_logits(arch, "f32", getter(params), rows[r])
+        np.testing.assert_allclose(got[r], want[p - 1:], atol=5 * TOL)
+
+
 # -- (b) a selection that keeps every key is plain attention -----------------
 
 
@@ -730,30 +800,62 @@ def described_chip():
         jax.config.update("jax_enable_compilation_cache", True)
 
 
-def test_the_cells_round_copies_no_index_leaf(described_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def cells_round(described_chip):
     """``keye30b-serve-longctx``'s ``_spec_round`` as the chip compiles it
-    (Mosaic kernels on): no ``copy`` has the sizes of an index leaf, in
-    any order of its axes.  A leaf the draft's chain carries in one layout
-    and scores in another is copied in, inside and out of the loop: eight
-    copies a round and 399.6 MB of scratch."""
+    (the Mosaic kernels, the decode kernel's among them, on), the round
+    state's leaves of its first layer, and the sizes of what it copies
+    (size-1 axes dropped).  Compiled once for the tests below."""
     import re
 
     from benchmark import harness, offchip
+    from rocket_tpu.ops import decode_attention
 
-    monkeypatch.setattr(select_attention, "_on_tpu", lambda: True)
     cell = harness.resolve_cell("keye30b-serve-longctx")
     state = offchip._serving_state(cell)[-1]
-    leaf = state[3]["block_0"]["attn"]["cached_index_k"]
-    rows, d, slots = leaf.shape
-    with offchip.mosaic_kernels():
-        compiled = offchip.compile_spec_round(cell, described_chip)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(select_attention, "_on_tpu", lambda: True)
+        patch.setattr(decode_attention, "_on_tpu", lambda: True)
+        with offchip.mosaic_kernels():
+            compiled = offchip.compile_spec_round(cell, described_chip)
     copied = [
         tuple(int(n) for n in dims.split(",") if n != "1")
         for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(",
                                compiled.as_text())]
     assert copied                   # the pattern finds the program's copies
+    return compiled, state[3]["block_0"]["attn"], copied
+
+
+def test_the_cells_round_copies_no_index_leaf(cells_round):
+    """No ``copy`` of the round has the sizes of an index leaf, in any
+    order of its axes.  A leaf the draft's chain carries in one layout and
+    scores in another is copied in, inside and out of the loop: eight
+    copies a round and 399.6 MB of scratch."""
+    from benchmark import offchip
+
+    compiled, leaves, copied = cells_round
+    leaf = leaves["cached_index_k"]
+    rows, d, slots = leaf.shape
     assert not [c for c in copied if sorted(c) == sorted((rows, d, slots))]
     # four leaves are 168 MB; the round's scratch held 399.6 MB with the
     # copies and 111.2 MB without
     four = 4 * rows * d * slots * jnp.dtype(leaf.dtype).itemsize
     assert offchip.memory(compiled)["temp_size_in_bytes"] < four
+
+
+def test_the_cells_verify_pass_reads_k_and_v_as_stored(cells_round):
+    """The target's six selecting layers attend their verify chunk through
+    the decode kernel with the selection as its key mask (by the masked
+    call's name), and no ``copy`` has the sizes of a K or V leaf in any
+    order of its axes: XLA's masked pass relaid each leaf inside its
+    fusions, and the flat view ``[rows, slots * 4, 128]`` of a leaf whose
+    slots are (4, 128) tiles is a copy of it."""
+    compiled, leaves, copied = cells_round
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "selected_decode_attention" in line.split("=")[0]]
+    assert len(calls) == 6
+    kv = leaves["cached_k"].shape
+    assert kv == leaves["cached_v"].shape == (16, 20481, 4, 128)
+    assert not [c for c in copied if sorted(c) == sorted(kv)]
